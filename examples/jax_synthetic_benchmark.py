@@ -8,8 +8,8 @@ output format).  Two modes:
 * default (single process): data-parallel over every local device with
   the in-graph XLA collective path — the TPU performance regime.
 * under ``hvdrun -np N`` (HVD_SIZE > 1): classic Horovod regime — one
-  process per device, eager gradient allreduce through the
-  coordination engine.
+  process per device (``hvdrun`` pins one chip per local rank), eager
+  gradient allreduce through the coordination engine.
 """
 
 from __future__ import annotations
@@ -78,12 +78,14 @@ def log(rank, msg):
 def run_ingraph(args):
     """Single process, all local devices, in-graph collectives."""
     import jax
-    import jax.numpy as jnp
     import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
+    from horovod_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     cfg, size = build_model(args)
     devices = jax.devices()
     mesh = mesh_mod.make_mesh({"dp": len(devices)})
@@ -99,13 +101,20 @@ def run_ingraph(args):
 
     n = len(devices)
     rs = np.random.RandomState(0)
-    images = jnp.asarray(rs.rand(args.batch_size * n, size, size, 3),
-                         jnp.float32)
-    labels = jnp.asarray(rs.randint(0, cfg.num_classes,
-                                    (args.batch_size * n,)))
+    # Each device gets its shard of the batch straight from the host
+    # (the step's own dp sharding), not a copy staged through device 0.
+    batch_sharding = NamedSharding(mesh, P("dp"))
+    images = jax.device_put(
+        rs.rand(args.batch_size * n, size, size, 3).astype(np.float32),
+        batch_sharding)
+    labels = jax.device_put(
+        rs.randint(0, cfg.num_classes,
+                   (args.batch_size * n,)).astype(np.int32),
+        batch_sharding)
 
     log(0, f"Model: {args.model}  Batch size: {args.batch_size} "
-           f"x {n} device(s), in-graph mode")
+           f"x {n} device(s) ({devices[0].platform}, "
+           f"{devices[0].device_kind}), in-graph mode")
     for _ in range(args.num_warmup_batches):
         state, loss = step(state, images, labels)
     jax.block_until_ready(loss)
@@ -130,12 +139,22 @@ def run_eager(args):
 
     import horovod_tpu as hvd
     from horovod_tpu.models import resnet
+    from horovod_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     hvd.init()
     rank, nproc = hvd.rank(), hvd.size()
+    # Every rank, not only rank 0: under hvdrun each must own a different
+    # chip.  A pinned rank sees its chip as device 0 of a one-chip
+    # topology, so the chip is named by the pin, not by the device id.
+    print(f"rank {rank}/{nproc}: TPU_VISIBLE_CHIPS="
+          f"{os.environ.get('TPU_VISIBLE_CHIPS', 'unset')} "
+          f"jax.devices() = {jax.devices()}", flush=True)
     cfg, size = build_model(args)
 
-    params, bstats = resnet.init(jax.random.PRNGKey(0), cfg)
+    # One jitted program, not hundreds of op-by-op compiles.
+    params, bstats = jax.jit(lambda k: resnet.init(k, cfg))(
+        jax.random.PRNGKey(0))
     params = hvd.broadcast_parameters(params, root_rank=0)
 
     grad_fn = jax.jit(jax.grad(

@@ -73,7 +73,9 @@ def main():
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
     from horovod_tpu.utils import checkpoint as ckpt
+    from horovod_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     axes = {k: v for k, v in
             (("dp", args.dp), ("tp", args.tp), ("sp", args.sp)) if v > 1}
     n_mesh = int(np.prod(list(axes.values()))) if axes else 1

@@ -54,13 +54,16 @@ def main():
     import jax
     import jax.numpy as jnp
     import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.models import resnet
     from horovod_tpu.ops.compression import Compression
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import optimizer as opt_mod
     from horovod_tpu.parallel import train as train_mod
+    from horovod_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     all_devices = jax.devices()
     if args.devices:
         counts = [int(c) for c in args.devices.split(",")]
@@ -73,8 +76,7 @@ def main():
         raise SystemExit(f"asked for {max(counts)} devices, "
                          f"have {len(all_devices)}")
 
-    on_tpu = all_devices[0].platform == "tpu"
-    if args.model == "tiny" or not on_tpu:
+    if args.model == "tiny":
         cfg = resnet.ResNetConfig(blocks=(1, 1, 1, 1), width=8,
                                   num_classes=100,
                                   compute_dtype=jnp.float32)
@@ -96,8 +98,15 @@ def main():
         step, init = train_mod.make_resnet_train_step_hvd(cfg, mesh, opt)
         state = init(jax.random.PRNGKey(0))
         batch = args.batch_per_device * n
-        images = jnp.asarray(rs.rand(batch, size, size, 3), jnp.float32)
-        labels = jnp.asarray(rs.randint(0, cfg.num_classes, (batch,)))
+        # Shards go from the host straight to their devices (the step's
+        # dp sharding), not through device 0.
+        batch_sharding = NamedSharding(mesh, P("dp"))
+        images = jax.device_put(
+            rs.rand(batch, size, size, 3).astype(np.float32),
+            batch_sharding)
+        labels = jax.device_put(
+            rs.randint(0, cfg.num_classes, (batch,)).astype(np.int32),
+            batch_sharding)
         for _ in range(args.num_warmup_batches):
             state, _loss = step(state, images, labels)
         jax.block_until_ready(state)
@@ -120,6 +129,8 @@ def main():
         table[n] = round(eff, 4)
         print(f"scaling efficiency {base}->{n}: {eff * 100:.1f}%")
     print(json.dumps({
+        "platform": all_devices[0].platform,
+        "device_kind": all_devices[0].device_kind,
         "metric": "weak_scaling_efficiency",
         "value": table[counts[-1]],
         "unit": f"fraction_of_linear_{base}to{counts[-1]}",

@@ -61,7 +61,9 @@ def main():
     import horovod_tpu as hvd
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.serving import ServingLoop
+    from horovod_tpu.utils.platform import enable_compile_cache
 
+    enable_compile_cache()
     hvd.init()
     cfg = tfm.TransformerConfig(
         vocab_size=args.vocab_size, d_model=args.d_model,

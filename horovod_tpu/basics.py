@@ -128,6 +128,14 @@ def _make_engine(r, s, lr, ls, cr, cs):
         # different engine would hang the whole job.
         from horovod_tpu.runtime_py import PyEngine
 
+        if os.environ.get("HVD_TPU_CORE", "").lower() not in (
+                "py", "python"):
+            # Asked for by name is a choice; anything else is a fallback
+            # the user should see.
+            from horovod_tpu.utils.logging import get_logger
+
+            get_logger(r).warning(
+                "native core unavailable, using the Python engine: %s", e)
         eng = PyEngine(r, s, lr, ls, cr, cs, addr, port)
         eng.native_fallback_reason = str(e)
         return eng

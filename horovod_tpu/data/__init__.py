@@ -9,8 +9,8 @@ reference needs that idiom as a first-class surface, so this module
 provides it framework-neutrally, plus the piece a TPU actually needs
 that GPU loaders get for free from CUDA streams: **asynchronous
 host→device transfer** overlapping the training step
-(:func:`prefetch_to_device`), which hides dispatch/PCIe (or tunnel)
-latency behind compute.
+(:func:`prefetch_to_device`), which hides dispatch/PCIe latency behind
+compute.
 
 Composition::
 

@@ -109,7 +109,10 @@ def _bn_state(c):
 
 def init(rng, config: ResNetConfig) -> Tuple[Params, Params]:
     """Returns ``(params, batch_stats)`` pytrees."""
-    keys = iter(jax.random.split(rng, 512))
+    # Indexed on demand, not iter(array): iterating slices out all 512
+    # keys up front (512 ops traced or dispatched for the ~50 used).
+    all_keys = jax.random.split(rng, 512)
+    keys = (all_keys[i] for i in range(512))
     params: Params = {}
     stats: Params = {}
 

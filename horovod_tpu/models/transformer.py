@@ -23,6 +23,7 @@ experts (ep).  This model is built so that every one of those axes is a
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -51,9 +52,10 @@ class TransformerConfig:
     # "ring": blockwise ring attention via ppermute over the sp ring;
     # "ulysses": all-to-all head exchange (see parallel/ring_attention.py);
     # "flash": Pallas blockwise flash-attention kernel
-    #   (ops/pallas_attention.py) — O(S) memory, MXU-tiled; used when the
-    #   mesh has no tp/sp sharding to partition across (falls back to
-    #   dense under GSPMD sharding, where XLA cannot split a pallas_call).
+    #   (ops/pallas_attention.py) — O(S) memory, MXU-tiled; runs per batch
+    #   shard on any mesh with tp = sp = 1.  With tp or sp > 1 dense
+    #   attention runs instead (XLA cannot split a pallas_call over heads
+    #   or sequence) and the swap is logged: see warn_flash_runs_dense.
     attn_impl: str = "dense"
     # Rematerialize each layer in the backward pass (jax.checkpoint).
     # Costs ~1 extra forward of compute for O(1)-layer activation
@@ -190,6 +192,19 @@ def _rope(x, theta: float, pos=None):
     ).astype(x.dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def warn_flash_runs_dense(axis: str, size: int, where: str) -> None:
+    """Say, once per process and cause, that ``attn_impl="flash"`` was
+    replaced by dense attention while ``where`` was being built, and
+    which mesh axis caused it."""
+    from horovod_tpu.utils.logging import get_logger
+
+    get_logger().warning(
+        "attn_impl='flash' runs as DENSE attention in %s: mesh axis %r "
+        "has size %d and the Pallas kernel is not partitioned over it",
+        where, axis, size)
+
+
 def _attention(x, lp, cfg: TransformerConfig, mesh=None):
     B, S, D = x.shape
     dtype = cfg.compute_dtype
@@ -205,26 +220,30 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None):
             f"got {cfg.attn_impl!r}")
     use_sp = (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
               and mesh.shape.get("sp", 1) > 1)
-    use_flash = (cfg.attn_impl == "flash"
-                 and (mesh is None
-                      or max(mesh.shape.get("tp", 1),
-                             mesh.shape.get("sp", 1)) == 1))
+    use_flash = cfg.attn_impl == "flash"
+    if use_flash and mesh is not None:
+        for ax in ("tp", "sp"):
+            if mesh.shape.get(ax, 1) > 1:
+                warn_flash_runs_dense(ax, mesh.shape[ax], "the model")
+                use_flash = False
     if use_flash:
         from horovod_tpu.ops.pallas_attention import flash_attention
 
-        if mesh is not None and mesh.shape.get("dp", 1) > 1:
-            # A pallas_call has no GSPMD partitioning rule, so under a
-            # dp-sharded batch the kernel must run per-shard: wrap it in
-            # a manual-dp shard_map (tp/sp are 1 here by the guard).
-            from jax.sharding import PartitionSpec as _P
+        if mesh is not None and mesh.size > 1:
+            # A pallas_call has no GSPMD partitioning rule, and Mosaic
+            # refuses a kernel that any automatic mesh axis could split,
+            # so the kernel runs inside a shard_map that is manual over
+            # EVERY axis: the batch split the way the activations are
+            # (ACT_SPEC's batch axes), replicated over the rest
+            # (ep, dcn, ...; tp and sp are 1 here).
+            from horovod_tpu.parallel.mesh import filter_spec
+            from horovod_tpu.parallel.shard import shard_map
 
-            from horovod_tpu.parallel.shard import shard_map as _shmap
-
-            ctx = _shmap(
+            batch = filter_spec(P(ACT_SPEC[0]), mesh)
+            ctx = shard_map(
                 lambda a, b, c: flash_attention(a, b, c, causal=True),
-                mesh, axis_names=frozenset({"dp"}),
-                in_specs=(_P("dp"), _P("dp"), _P("dp")),
-                out_specs=_P("dp"), check_vma=False)(q, kk, v)
+                mesh, in_specs=(batch, batch, batch),
+                out_specs=batch)(q, kk, v)
         else:
             ctx = flash_attention(q, kk, v, causal=True)
     elif use_sp:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from horovod_tpu.ops.collective import _one_axis_size
 
 
 def adasum_pair(a, b, dot, anorm_sq, bnorm_sq):
@@ -96,7 +95,7 @@ def adasum_allreduce(x, axis: Union[str, Sequence[str]] = "dp"):
 
 
 def _adasum_one_axis(x, axis: str):
-    n = _one_axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
     assert n & (n - 1) == 0, "adasum requires power-of-two axis size"

@@ -93,68 +93,17 @@ _MISUSE_MSG = (
     "mesh-axis collectives in horovod_tpu.ops.collective instead")
 
 
-def _check_single_device_trace(*operands) -> None:
+def _check_single_device_trace() -> None:
     """The bridge targets the reference's deployment shape: one process
     per chip, jit on that device.  Inside shard_map/pmap bodies (named
     mesh axes in scope) XLA is the coordinator — ordered host callbacks
     there would submit one enqueue per *shard* under the same tensor
-    name; refuse with a pointer to the mesh-axis collectives.
-
-    Two detection layers so the failure mode is a ``TypeError`` at trace
-    time rather than a hang (tests/test_eager_single.py pins the raise
-    on the shipped jax version):
-
-    1. the axis-env probe (``nonempty_axis_env_DO_NOT_USE``, jax<=0.9);
-    2. if a jax upgrade removes that API: the *operands* themselves —
-       inside shard_map/pmap the arguments are tracers whose trace type
-       lives in the shard_map/pmap interpreter module, which survives
-       private-API churn far better than any probe function.
-    """
+    name and hang; refuse at trace time with a pointer to the mesh-axis
+    collectives (tests/test_eager_single.py pins the raise)."""
     import jax.core
 
-    probe = getattr(jax.core, "nonempty_axis_env_DO_NOT_USE", None)
-    if probe is not None:
-        if probe():
-            raise TypeError(_MISUSE_MSG)
-        return
-    # Probe API gone: read the axis env directly (what the probe wraps).
-    # Modern pmap traces through the ordinary jaxpr machinery, so the
-    # operand tracers below cannot tell it apart from plain jit — the
-    # axis env is the only reliable signal for it.
-    try:
-        from jax._src.core import get_axis_env
-
-        if get_axis_env().axis_sizes:
-            raise TypeError(_MISUSE_MSG)
-        return
-    except (ImportError, AttributeError):
-        pass
-    # Last resort: operand-trace inspection.  A concrete (non-tracer)
-    # operand positively proves there is no surrounding trace, and a
-    # plain-jit tracer is equally conclusive — only the zero-operand
-    # path (barrier) leaves the guard blind.
-    for x in operands:
-        if isinstance(x, jax.core.Tracer):
-            tr = type(getattr(x, "_trace", None))
-            label = f"{tr.__module__}.{tr.__name__}".lower()
-            # pmap tracers live in jax's pxla/batching machinery
-            # (MapTracer / pxla module names) rather than a module
-            # spelled "pmap" — match those too, or pmap misuse would
-            # hang instead of raising on probe-less jax versions.
-            if ("shard_map" in label or "pmap" in label
-                    or "pxla" in label or "maptracer" in label):
-                raise TypeError(_MISUSE_MSG)
-    if not operands:
-        # Nothing to inspect: the guard is blind on this jax version —
-        # warn once rather than fail silently, because the misuse
-        # symptom is a hang.
-        import warnings
-
-        warnings.warn(
-            "horovod_tpu: cannot detect shard_map/pmap context on this "
-            "jax version; engine-bridge collectives called inside "
-            "shard_map bodies will misbehave instead of raising. Use "
-            "ops.collective there.", RuntimeWarning, stacklevel=3)
+    if jax.core.nonempty_axis_env_DO_NOT_USE():
+        raise TypeError(_MISUSE_MSG)
 
 
 def _io_callback(fn, result_spec, *args):
@@ -203,17 +152,6 @@ _FFI_DTYPES = ("float32", "float64", "float16", "bfloat16",
                "uint16", "int32", "int64", "bool")
 
 
-def _ffi_api():
-    # jax < 0.4.38 ships the same surface (register_ffi_target,
-    # pycapsule, ffi_call) under jax.extend.ffi instead of jax.ffi.
-    import jax
-
-    mod = getattr(jax, "ffi", None)
-    if mod is None:
-        from jax.extend import ffi as mod
-    return mod
-
-
 def _native_ffi_ready() -> bool:
     import os
 
@@ -238,10 +176,9 @@ def _native_ffi_ready() -> bool:
             lib = native.load()
             handler = getattr(lib, "HvdGroupedAllreduce", None)
             if handler is not None:
-                ffi = _ffi_api()
-                ffi.register_ffi_target(
+                jax.ffi.register_ffi_target(
                     "hvd_grouped_allreduce",
-                    ffi.pycapsule(handler), platform="cpu")
+                    jax.ffi.pycapsule(handler), platform="cpu")
                 _ffi_state["registered"] = True
         except Exception:
             _ffi_state["registered"] = False
@@ -260,10 +197,12 @@ def _ffi_eligible(leaves, compression) -> bool:
 
 
 def _ffi_grouped_call(leaves, base, op, prescale, postscale, process_set):
+    import jax
+
     ps_id, ps_size = 0, 0
     if process_set is not None:
         ps_id, ps_size = process_set.validate(basics.rank(), basics.size())
-    call = _ffi_api().ffi_call(
+    call = jax.ffi.ffi_call(
         "hvd_grouped_allreduce",
         tuple(_spec_like(l) for l in leaves),
         has_side_effect=True)
@@ -308,7 +247,7 @@ def allreduce(x, name: Optional[str] = None,
     """
     from horovod_tpu.ops.compression import Compression
 
-    _check_single_device_trace(x)
+    _check_single_device_trace()
     _ensure_vjps()
     name = _auto_name("allreduce", name)
     compression = compression or Compression.none
@@ -404,7 +343,7 @@ def grouped_allreduce(tensors, name: Optional[str] = None,
 
     from horovod_tpu.ops.compression import Compression
 
-    _check_single_device_trace(*jax.tree.leaves(tensors))
+    _check_single_device_trace()
     _ensure_vjps()
     base = _auto_name("grouped_allreduce", name)
     compression = compression or Compression.none
@@ -466,7 +405,7 @@ def allgather(x, name: Optional[str] = None, process_set=None):
     Static shapes require every rank to contribute the same shape (the
     ragged-first-dim negotiation is eager-only; in-graph XLA has the same
     restriction, ops/collective.py:153)."""
-    _check_single_device_trace(x)
+    _check_single_device_trace()
     _ensure_vjps()
     name = _auto_name("allgather", name)
     return _allgather_vjp(x, name, process_set)
@@ -529,7 +468,7 @@ def broadcast(x, root_rank: int = 0, name: Optional[str] = None,
               process_set=None):
     """Negotiated broadcast inside ``jit``.  Gradient: sum-allreduce on
     the root, zero elsewhere (reference ``_broadcast_grad``)."""
-    _check_single_device_trace(x)
+    _check_single_device_trace()
     _ensure_vjps()
     name = _auto_name("broadcast", name)
     return _broadcast_vjp(x, name, root_rank, process_set)
@@ -585,7 +524,7 @@ def reducescatter(x, name: Optional[str] = None,
 
     from horovod_tpu.ops.cpu_backend import _chunk_bounds
 
-    _check_single_device_trace(x)
+    _check_single_device_trace()
     if op not in (ReduceOp.AVERAGE, ReduceOp.SUM, ReduceOp.MIN,
                   ReduceOp.MAX, ReduceOp.PRODUCT):
         raise ValueError(f"reducescatter does not support op {op}")
@@ -619,7 +558,7 @@ def alltoall(x, name: Optional[str] = None, process_set=None):
     restriction as the in-graph op, ops/collective.py:232)."""
     import jax
 
-    _check_single_device_trace(x)
+    _check_single_device_trace()
     name = _auto_name("alltoall", name)
     n = _group_size(process_set)
     if x.shape[0] % n:

@@ -34,21 +34,8 @@ def _axes(axis: AxisSpec) -> Tuple[str, ...]:
     return tuple(axis)
 
 
-def _one_axis_size(ax: str) -> int:
-    # lax.axis_size is jax >= 0.5; older jax exposes the same static
-    # sizes through the trace's axis env.
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(ax)
-    from jax._src.core import get_axis_env
-
-    return get_axis_env().axis_sizes[ax]
-
-
 def axis_size(axis: AxisSpec) -> int:
-    n = 1
-    for ax in _axes(axis):
-        n *= _one_axis_size(ax)
-    return n
+    return lax.axis_size(_axes(axis))
 
 
 def axis_index(axis: AxisSpec):
@@ -57,7 +44,7 @@ def axis_index(axis: AxisSpec):
     axes = _axes(axis)
     idx = lax.axis_index(axes[0])
     for ax in axes[1:]:
-        idx = idx * _one_axis_size(ax) + lax.axis_index(ax)
+        idx = idx * lax.axis_size(ax) + lax.axis_index(ax)
     return idx
 
 
@@ -198,7 +185,7 @@ def reduce_scatter(x, op: ReduceOp = ReduceOp.AVERAGE, axis: str = "dp"):
     The building block of hierarchical allreduce (the reference's
     ``ncclReduceScatter`` leg, nccl_operations.cc:224-342).
     """
-    n = _one_axis_size(axis)
+    n = lax.axis_size(axis)
     y = lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)
     if op == ReduceOp.AVERAGE:
         y = y / n
@@ -223,7 +210,7 @@ def hierarchical_allreduce(
     """
     if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
         raise ValueError("hierarchical_allreduce supports SUM/AVERAGE")
-    n_in = _one_axis_size(inner_axis)
+    n_in = lax.axis_size(inner_axis)
     pad = (-x.shape[0]) % n_in
     orig = x.shape[0]
     if pad:
@@ -235,7 +222,7 @@ def hierarchical_allreduce(
     if pad:
         full = full[:orig]
     if op == ReduceOp.AVERAGE:
-        full = full / (n_in * _one_axis_size(outer_axis))
+        full = full / (n_in * lax.axis_size(outer_axis))
     return full
 
 
@@ -264,6 +251,6 @@ def barrier(axis: AxisSpec = "dp"):
 def ppermute_ring(x, axis: str, shift: int = 1):
     """Send to the neighbor ``shift`` steps around the ``axis`` ring —
     the primitive under ring attention and custom pipeline schedules."""
-    n = _one_axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)

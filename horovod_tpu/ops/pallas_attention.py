@@ -19,8 +19,10 @@ FlashAttention-2 blockwise recipe re-derived for Pallas.  Composition:
   ring with the same online-softmax update — this kernel is the
   single-chip analog of one ring hop.
 
-Runs under ``interpret=True`` off-TPU (tests run on the CPU backend);
-on a TPU backend it compiles to Mosaic.
+A computation lowered for TPU devices carries the Mosaic-compiled kernel;
+lowered for any other platform (tests run on the CPU backend) the same
+kernel body is interpreted.  The choice follows the lowering platform, not
+``jax.default_backend()``: see :func:`_pallas_call`.
 
 Tuning (measured on one TPU v5e chip, B=8 S=1024 H=16 D=64 bf16):
 dot inputs keep their storage dtype (f32 upcasts before the dots ran
@@ -65,8 +67,19 @@ def _pick_block(seq_len: int, want: int) -> int:
     return max(b, 1)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def _pallas_call(kernel, *args, **kwargs):
+    """``pl.pallas_call(kernel, **kwargs)(*args)``, compiled by Mosaic where
+    the surrounding computation is lowered for a TPU and interpreted on
+    every other platform.  ``lax.platform_dependent`` resolves the branch
+    at lowering time, so a step lowered for TPU devices from a CPU process
+    (AOT, or a chip JAX failed to make the default) never carries the
+    interpreter in place of the kernel."""
+    def branch(interpret):
+        return lambda *a: pl.pallas_call(
+            kernel, interpret=interpret, **kwargs)(*a)
+
+    return jax.lax.platform_dependent(
+        *args, tpu=branch(False), default=branch(True))
 
 
 # ---------------------------------------------------------------------------
@@ -120,16 +133,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_scr[:] + jnp.log(l)         # [bq, 1]
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-               out_f32=False):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32=False):
     BH, S, D = q.shape
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
     grid = (BH, S // bq, S // bk)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk)
-    o, lse = pl.pallas_call(
-        kernel,
+    o, lse = _pallas_call(
+        kernel, q, k, v,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -155,8 +167,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
             _vmem((bq, 1)),
             _vmem((bq, D)),
         ],
-        interpret=interpret,
-    )(q, k, v)
+    )
     return o, lse
 
 
@@ -258,7 +269,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
+def _flash_bwd(res, g, scale, causal, block_q, block_k):
     q, k, v, o, lse = res
     do, dlse = g
     BH, S, D = q.shape
@@ -271,9 +282,10 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
                     axis=-1, keepdims=True)         # [BH, S, 1]
     dlse = dlse.astype(jnp.float32)
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
+        q, k, v, do, lse, delta, dlse,
         grid=(BH, S // bq, S // bk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -287,12 +299,12 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[_vmem((bq, D))],
-        interpret=interpret,
-    )(q, k, v, do, lse, delta, dlse)
+    )
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
+        q, k, v, do, lse, delta, dlse,
         grid=(BH, S // bk, S // bq),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
@@ -312,8 +324,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
         scratch_shapes=[_vmem((bk, D)), _vmem((bk, D))],
-        interpret=interpret,
-    )(q, k, v, do, lse, delta, dlse)
+    )
     return dq, dk, dv
 
 
@@ -322,42 +333,34 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret,
-           out_f32):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                      out_f32)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, out_f32):
+    return _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-                   out_f32):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k,
-                        interpret, out_f32)
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32):
+    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, out_f32,
-                   res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k, interpret)
+def _flash_vjp_bwd(scale, causal, block_q, block_k, out_f32, res, g):
+    return _flash_bwd(res, g, scale, causal, block_q, block_k)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _run_flash(q, k, v, causal, scale, block_q, block_k, interpret,
-               out_f32=False):
+def _run_flash(q, k, v, causal, scale, block_q, block_k, out_f32=False):
     B, S, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = _interpret_default()
 
     def fold(x):
         return jnp.moveaxis(x, 2, 1).reshape(B * H, S, D)
 
     o, lse = _flash(fold(q), fold(k), fold(v), float(scale),
                     bool(causal), int(block_q), int(block_k),
-                    bool(interpret), bool(out_f32))
+                    bool(out_f32))
     o = jnp.moveaxis(o.reshape(B, H, S, D), 1, 2)
     lse = jnp.moveaxis(lse.reshape(B, H, S), 1, 2)   # [B, S, H]
     return o, lse
@@ -365,22 +368,19 @@ def _run_flash(q, k, v, causal, scale, block_q, block_k, interpret,
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512,
-                    interpret: Optional[bool] = None):
+                    block_q: int = 512, block_k: int = 512):
     """Blockwise flash attention.  ``q/k/v``: [B, S, H, D].
 
     Returns [B, S, H, D] context.  Differentiable (custom VJP running the
-    flash backward kernels).  ``interpret`` defaults to True off-TPU so
-    the same code tests on the CPU backend.
+    flash backward kernels).
     """
-    o, _ = _run_flash(q, k, v, causal, scale, block_q, block_k, interpret)
+    o, _ = _run_flash(q, k, v, causal, scale, block_q, block_k)
     return o
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        block_q: int = 512, block_k: int = 512,
-                        interpret: Optional[bool] = None):
+                        block_q: int = 512, block_k: int = 512):
     """Like :func:`flash_attention` but also returns the per-query
     logsumexp ``[B, S, H]`` (fp32).  The pair ``(o, lse)`` is what
     blockwise composition needs: partial attentions over disjoint key
@@ -390,4 +390,4 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     term in the backward kernels).  The partial output is emitted in
     fp32 (no per-hop rounding when partials are combined)."""
     return _run_flash(q, k, v, causal, scale, block_q, block_k,
-                      interpret, out_f32=True)
+                      out_f32=True)

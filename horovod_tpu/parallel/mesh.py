@@ -88,8 +88,7 @@ def make_mesh(
 
     On real TPU hardware ``jax.experimental.mesh_utils`` picks a device
     order that keeps each named axis on physically adjacent chips so XLA's
-    collectives ride ICI rings; on CPU test meshes we fall back to a plain
-    reshape.
+    collectives ride ICI rings; CPU test meshes are a plain reshape.
     """
     import jax
 
@@ -114,7 +113,16 @@ def make_mesh(
             dev_array = mesh_utils.create_device_mesh(
                 sizes, devices=list(devices),
                 allow_split_physical_axes=allow_split_physical_axes)
-        except Exception:
+        except Exception as e:
+            # Any topology mesh_utils cannot lay out (odd axis products,
+            # a partial slice): the mesh is still valid, its axes just
+            # follow enumeration order instead of ICI adjacency.
+            from horovod_tpu.utils.logging import get_logger
+
+            get_logger().warning(
+                "create_device_mesh(%s) failed (%s: %s); mesh axes follow "
+                "device enumeration order, not ICI adjacency",
+                sizes, type(e).__name__, e)
             dev_array = np.array(list(devices)).reshape(sizes)
     else:
         dev_array = np.array(list(devices)).reshape(sizes)

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from horovod_tpu.ops.collective import _one_axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.models import transformer as tfm
@@ -74,7 +73,7 @@ def gpipe(stage_fn, x_mb, *, axis: str = "pp"):
     stage's cell.  Returns ``([M, ...] outputs, total_aux)``, both
     replicated across the ``axis`` ring.
     """
-    n_stages = _one_axis_size(axis)
+    n_stages = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     n_micro = x_mb.shape[0]
     ticks = n_micro + n_stages - 1
@@ -99,11 +98,8 @@ def gpipe(stage_fn, x_mb, *, axis: str = "pp"):
 
     # The carry becomes pp-varying after one tick (each stage holds its
     # own activations), so it must *start* varying for scan's type check.
-    # jax < 0.5 has no varying-manual-axes typing (no lax.pcast) and no
-    # such check: the seed is used as-is there.
-    _pcast = getattr(lax, "pcast", lambda a, _axis, to: a)
     carry0 = jax.tree.map(
-        lambda a: _pcast(a, axis, to="varying"),
+        lambda a: lax.pcast(a, axis, to="varying"),
         (jnp.zeros_like(x_mb[0]), jnp.zeros_like(x_mb),
          jnp.zeros((), jnp.float32)))
     (_, out, aux_sum), _ = lax.scan(tick, carry0, jnp.arange(ticks))
@@ -138,6 +134,8 @@ def pipeline_apply(params, tokens, cfg: tfm.TransformerConfig, mesh,
         # cannot be partitioned by GSPMD — use dense attention there.
         import dataclasses
 
+        tfm.warn_flash_runs_dense("dp", mesh.shape["dp"],
+                                  "the pipeline body (pp > 1)")
         cfg = dataclasses.replace(cfg, attn_impl="dense")
 
     layer_fn = tfm._layer

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.ops.collective import _one_axis_size
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.shard import shard_map
@@ -77,7 +76,7 @@ def ring_attention(q, k, v, axis: str = "sp", causal: bool = True):
     """
     from horovod_tpu.ops.pallas_attention import flash_attention_lse
 
-    n = _one_axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     B, S, H, D = q.shape
     scale = 1.0 / math.sqrt(D)
@@ -111,7 +110,7 @@ def ulysses_attention(q, k, v, axis: str = "sp", causal: bool = True):
     """Ulysses sequence parallelism: all-to-all head exchange (inside
     shard_map).  q/k/v: [B, S_local, H, D] with H divisible by the axis
     size; returns [B, S_local, H, D]."""
-    n = _one_axis_size(axis)
+    n = lax.axis_size(axis)
     B, S, H, D = q.shape
     if H % n != 0:
         raise ValueError(f"heads {H} not divisible by axis size {n}")

@@ -1,55 +1,21 @@
-"""Version-portable ``shard_map`` wrapper.
+"""``jax.shard_map`` with varying-manual-axes checking off by default.
 
-JAX moved ``shard_map`` from ``jax.experimental`` to ``jax.shard_map`` and
-added varying-manual-axes (VMA) replication checking; collective-heavy
-bodies (all_gather outputs consumed as replicated) frequently defeat the
-static inference, so we default ``check_vma=False`` — the collectives in
-``horovod_tpu.ops.collective`` define their own replication semantics.
+Collective-heavy bodies (all_gather outputs consumed as replicated)
+frequently defeat the static VMA inference, and the collectives in
+``horovod_tpu.ops.collective`` define their own replication semantics, so
+``check_vma`` defaults to ``False`` here; pass ``check_vma=True`` to ask
+for the check.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any
-
 import jax
 
 
-def _resolve():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    return fn
-
-
-_SHARD_MAP = _resolve()
-_PARAMS = set(inspect.signature(_SHARD_MAP).parameters)
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kwargs: Any):
-    """``shard_map(f, mesh, in_specs, out_specs)`` with VMA checking off
-    unless explicitly requested.  Accepts the current keyword surface on
-    every supported jax: ``check_vma`` maps to the older ``check_rep``,
-    and partial-manual ``axis_names`` maps to the pre-0.5 ``auto``
-    complement (the axes left automatic)."""
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", False))
-    if "check_vma" in _PARAMS:
-        kwargs["check_vma"] = check
-    elif "check_rep" in _PARAMS:
-        kwargs["check_rep"] = check
-    manual = kwargs.pop("axis_names", None)
-    jit_wrap = False
-    if manual is not None:
-        if "axis_names" in _PARAMS:
-            kwargs["axis_names"] = frozenset(manual)
-        elif "auto" in _PARAMS:
-            kwargs["auto"] = \
-                frozenset(mesh.axis_names) - frozenset(manual)
-            # pre-0.5 partial-auto only exists on the jit lowering path
-            # (the eager impl and the replication checker both raise
-            # NotImplementedError for it)
-            kwargs["check_rep"] = False
-            jit_wrap = bool(kwargs["auto"])
-    mapped = _SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kwargs)
-    return jax.jit(mapped) if jit_wrap else mapped
+def shard_map(f, mesh, in_specs, out_specs, *, check_vma: bool = False,
+              axis_names=frozenset()):
+    """``shard_map(f, mesh, in_specs, out_specs)``.  ``axis_names`` names
+    the manual axes of a partial-manual map (default: every mesh axis)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma,
+                         axis_names=frozenset(axis_names))

@@ -217,6 +217,18 @@ class ResNetState(NamedTuple):
     step: jnp.ndarray
 
 
+def _resnet_init_fn(cfg, mesh, optimizer):
+    """``init_fn(rng) -> ResNetState`` as one jitted program whose outputs
+    are born replicated over the mesh (like the transformer's), so no leaf
+    is made op by op on the first device and copied out from there."""
+    def build(rng) -> ResNetState:
+        params, stats = resnet_model.init(rng, cfg)
+        return ResNetState(params, stats, optimizer.init(params),
+                           jnp.zeros((), jnp.int32))
+
+    return jax.jit(build, out_shardings=_replicated(mesh))
+
+
 def make_resnet_train_step(
     cfg: resnet_model.ResNetConfig,
     mesh,
@@ -230,16 +242,8 @@ def make_resnet_train_step(
     """
     if optimizer is None:
         optimizer = optax.sgd(0.1, momentum=0.9)
-    rep = _replicated(mesh)
     data_sharding = NamedSharding(mesh, _batch_spec(mesh, "dp"))
-
-    def init_fn(rng) -> ResNetState:
-        params, stats = resnet_model.init(rng, cfg)
-        params = jax.device_put(params, rep)
-        stats = jax.device_put(stats, rep)
-        opt_state = jax.device_put(optimizer.init(params), rep)
-        return ResNetState(params, stats, opt_state,
-                           _step0(mesh))
+    init_fn = _resnet_init_fn(cfg, mesh, optimizer)
 
     def _step(state: ResNetState, images, labels):
         (loss, new_stats), grads = jax.value_and_grad(
@@ -288,19 +292,11 @@ def make_resnet_train_step_hvd(
     if optimizer is None:
         optimizer = opt_mod.DistributedOptimizer(
             optax.sgd(0.1, momentum=0.9), axis=axes)
-    rep = _replicated(mesh)
     # All data-parallel axes gang up on dim 0 (batch).  P(*axes) would
     # instead spread them across dims — sharding image height over the
     # second axis (caught by the hier-ici-dcn dryrun mesh).
     batch_p = filter_spec(P(axes), mesh) if axes else P()
-
-    def init_fn(rng) -> ResNetState:
-        params, stats = resnet_model.init(rng, cfg)
-        params = jax.device_put(params, rep)
-        stats = jax.device_put(stats, rep)
-        opt_state = jax.device_put(optimizer.init(params), rep)
-        return ResNetState(params, stats, opt_state,
-                           _step0(mesh))
+    init_fn = _resnet_init_fn(cfg, mesh, optimizer)
 
     def body(state: ResNetState, images, labels):
         (loss, new_stats), grads = jax.value_and_grad(
@@ -328,13 +324,15 @@ def make_resnet_train_step_hvd(
 def make_mnist_train_step(mesh, optimizer=None):
     if optimizer is None:
         optimizer = optax.adam(1e-3)
-    rep = _replicated(mesh)
     data_sharding = NamedSharding(mesh, _batch_spec(mesh, "dp"))
 
-    def init_fn(rng) -> TrainState:
-        params = jax.device_put(mnist_model.init(rng), rep)
-        opt_state = jax.device_put(optimizer.init(params), rep)
-        return TrainState(params, opt_state, _step0(mesh))
+    def build(rng) -> TrainState:
+        params = mnist_model.init(rng)
+        return TrainState(params, optimizer.init(params),
+                          jnp.zeros((), jnp.int32))
+
+    # Born replicated, as in _resnet_init_fn.
+    init_fn = jax.jit(build, out_shardings=_replicated(mesh))
 
     def _step(state: TrainState, images, labels):
         loss, grads = jax.value_and_grad(mnist_model.loss_fn)(

@@ -18,6 +18,7 @@ Sources, in priority order:
 from __future__ import annotations
 
 import os
+import sys
 from dataclasses import dataclass
 from typing import Optional
 
@@ -72,12 +73,14 @@ def from_tpu_metadata() -> Optional[PodTopology]:
 
 
 def from_jax_distributed() -> Optional[PodTopology]:
-    try:
-        import jax
-
-        n = jax.process_count()
-    except Exception:
+    """Topology of a ``jax.distributed`` job this process already joined;
+    None otherwise.  Asking JAX for ``process_count()`` initialises its
+    backend (on a TPU host: takes the chips), so a bare ``hvd.init()``
+    only asks when ``jax.distributed.initialize`` has already run."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.distributed.is_initialized():
         return None
+    n = jax.process_count()
     if n <= 1:
         return None
     r = jax.process_index()

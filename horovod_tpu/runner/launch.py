@@ -39,7 +39,8 @@ def _remote_command(env: Dict[str, str], command: Sequence[str]):
     sensitive = [(k, env[k]) for k in SENSITIVE_ENV if k in env]
     exports = " ".join(
         f"{k}={shlex.quote(v)}" for k, v in env.items()
-        if k.startswith(("HVD_", "JAX_", "XLA_", "PYTHON"))
+        if (k.startswith(("HVD_", "JAX_", "XLA_", "PYTHON"))
+            or k in _CHIP_PIN_VARS)
         and k not in SENSITIVE_ENV)
     inner = f"cd {shlex.quote(os.getcwd())} && {exports} " + \
         " ".join(shlex.quote(c) for c in command)
@@ -64,6 +65,33 @@ def is_local(hostname: str) -> bool:
         return False
 
 
+# libtpu's variables for "this process owns these chips".  A chip belongs
+# to one process at a time, so several ranks on one host must each be told
+# which chip is theirs before they import JAX.
+_CHIP_PIN_VARS = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                  "TPU_PROCESS_BOUNDS", "TPU_PROCESS_ADDRESSES",
+                  "TPU_PROCESS_PORT")
+_TPU_PROCESS_BASE_PORT = 8476  # libtpu's default runtime port
+
+
+def _chip_pin(slot: SlotInfo, env: Dict[str, str]) -> Dict[str, str]:
+    """One chip per local rank: local rank ``i`` sees chip ``i`` as a
+    one-chip topology of its own, its TPU runtime on a port of its own
+    (the one-process-per-device regime of ``ops/bridge.py`` and the eager
+    engine).  Nothing is set for a rank that is alone on its host (it
+    owns every chip there: the in-graph regime), nor when the user
+    already set any of these variables.  The launcher itself never
+    touches JAX; on a host without chips the variables are inert."""
+    if slot.local_size <= 1 or any(v in env for v in _CHIP_PIN_VARS):
+        return {}
+    port = _TPU_PROCESS_BASE_PORT + slot.local_rank
+    return {"TPU_VISIBLE_CHIPS": str(slot.local_rank),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "TPU_PROCESS_PORT": str(port)}
+
+
 def worker_env(slot: SlotInfo, rdv_addr: str, rdv_port: int,
                extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     """Per-slot env block (parity: gloo_run.py:210-215 HOROVOD_RANK/...)."""
@@ -80,6 +108,7 @@ def worker_env(slot: SlotInfo, rdv_addr: str, rdv_port: int,
     pp = env.get("PYTHONPATH", "")
     if pkg_root not in pp.split(os.pathsep):
         env["PYTHONPATH"] = (pkg_root + os.pathsep + pp) if pp else pkg_root
+    env.update(_chip_pin(slot, env))
     env.update({
         "HVD_HOSTNAME": slot.hostname,
         "HVD_RANK": str(slot.rank),

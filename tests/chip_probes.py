@@ -1,0 +1,143 @@
+"""Fresh-interpreter probes behind ``tests/test_chip_smoke.py``.
+
+Each probe needs an interpreter of its own (``jax.config`` is process-wide;
+libtpu admits one process at a time), so it runs as
+``python chip_probes.py <probe> [args]``.  :class:`Probes` starts them all
+at once; ``tests/conftest.py`` does so while collection is still importing
+the remaining test modules on one core, so by the time the tests ask for
+the results the ~14 s of libtpu start-up and compiles have cost the suite
+nothing.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# Mesh shapes a flash LM step must lower for: plain data parallel, and the
+# two whose automatic axes (ep, dcn) Mosaic used to refuse to partition.
+MESHES = ({"dp": 4}, {"dp": 2, "ep": 2}, {"dcn": 2, "dp": 2})
+
+
+def probe_cache():
+    """Where the compile cache went, and that something was written."""
+    import jax
+
+    from horovod_tpu.utils.platform import enable_compile_cache
+
+    path = enable_compile_cache()
+    jax.jit(lambda x: x * 2 + 1)(jax.numpy.arange(8.0)).block_until_ready()
+    print(json.dumps([path, jax.config.jax_compilation_cache_dir]))
+
+
+def probe_bare_init():
+    """Which JAX backends a bare ``hvd.init()`` left initialised."""
+    import horovod_tpu as hvd
+
+    hvd.init()
+    import jax
+
+    print("BACKENDS", sorted(jax._src.xla_bridge._backends))
+    hvd.shutdown()
+
+
+def probe_lower_for_tpu(meshes_json):
+    """Mosaic custom calls in a small flash LM step lowered, from this CPU
+    process, for the compile-only ``v5e:2x2`` topology.  One process for
+    every mesh (libtpu's lockfile); the meshes compile in threads, XLA
+    works outside the interpreter lock."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.parallel import mesh as mesh_mod
+    from horovod_tpu.parallel import train as train_mod
+
+    assert jax.default_backend() == "cpu"
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+    def mosaic_calls(axes):
+        mesh = mesh_mod.make_mesh(axes, devices=topo.devices)
+        cfg = tfm.TransformerConfig(
+            vocab_size=512, d_model=256, n_layers=2, n_heads=4, d_ff=512,
+            max_seq_len=256, attn_impl="flash",
+            n_experts=4 if "ep" in axes else 0)
+        step, init = train_mod.make_transformer_train_step(cfg, mesh)
+        state = jax.eval_shape(init, jax.random.PRNGKey(0))
+        toks = jax.ShapeDtypeStruct((8, 256), jnp.int32)
+        text = step.lower(state, toks, toks).compile().as_text()
+        return text.count("tpu_custom_call")
+
+    meshes = json.loads(meshes_json)
+    with ThreadPoolExecutor(len(meshes)) as pool:
+        counts = list(pool.map(mosaic_calls, meshes))
+    print("RESULT", json.dumps({
+        "device_kind": topo.devices[0].device_kind,
+        "tpu_custom_call": counts}))
+
+
+class Probes:
+    """Every probe, started at once; ``result(name)`` waits for one."""
+
+    def __init__(self):
+        self.scratch = tempfile.mkdtemp(prefix="hvd-chip-probes-")
+        self.set_dir = os.path.join(self.scratch, "set_cache_dir")
+        cwds = [os.path.join(self.scratch, d) for d in ("cwd_a", "cwd_b")]
+        for d in (self.set_dir, *cwds):
+            os.mkdir(d)
+        me = os.path.abspath(__file__)
+        self._procs = {
+            "smoke_on_cpu": self._spawn(
+                [os.path.join(REPO, "chip_smoke.py")]),
+            "cache_set": self._spawn(
+                [me, "cache"], JAX_COMPILATION_CACHE_DIR=self.set_dir),
+            "cache_unset_a": self._spawn(
+                [me, "cache"], cwd=cwds[0], JAX_COMPILATION_CACHE_DIR=None),
+            "cache_unset_b": self._spawn(
+                [me, "cache"], cwd=cwds[1], JAX_COMPILATION_CACHE_DIR=None),
+            "bare_init": self._spawn([me, "bare_init"], HVD_SIZE=None),
+            "lower_for_tpu": self._spawn(
+                [me, "lower_for_tpu", json.dumps(MESHES)]),
+        }
+        self._done = {}
+
+    @staticmethod
+    def _spawn(args, *, cwd=REPO, **env_changes):
+        env = dict(os.environ)
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"
+        for k, v in env_changes.items():
+            if v is None:
+                env.pop(k, None)
+            else:
+                env[k] = v
+        return subprocess.Popen(
+            [sys.executable, *args], cwd=cwd, env=env,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+
+    def result(self, name):
+        """``(returncode, stdout, stderr)`` of probe ``name``."""
+        if name not in self._done:
+            proc = self._procs[name]
+            out, err = proc.communicate(timeout=300)
+            self._done[name] = (proc.returncode, out, err)
+        return self._done[name]
+
+    def close(self):
+        for proc in self._procs.values():
+            if proc.poll() is None:
+                proc.kill()
+                proc.communicate()
+        shutil.rmtree(self.scratch, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    globals()["probe_" + sys.argv[1]](*sys.argv[2:])

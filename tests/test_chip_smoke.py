@@ -1,0 +1,160 @@
+"""The standing guards for the chip path on a box with no chip.
+
+* ``chip_smoke.py`` cannot pass without a TPU, and a failed leg fails it;
+* the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
+  one in-checkout place whatever the working directory;
+* an unknown ``device_kind`` is an error, not a default peak;
+* a bare ``hvd.init()`` does not open the JAX backend (on a TPU host that
+  takes the chips), and ``hvdrun`` pins one chip per local rank;
+* "kernels that compile": a flash LM step lowered for TPU devices (the
+  compile-only ``v5e:2x2`` topology this installation's libtpu provides)
+  carries the Mosaic-compiled kernel on every mesh shape the repo claims,
+  including the ones interpret mode on a CPU mesh could never reject.
+
+Every check that needs a fresh interpreter (jax.config, libtpu) is a probe
+in ``tests/chip_probes.py``, started early by ``tests/conftest.py``.
+"""
+
+import json
+import os
+
+import pytest
+
+import chip_probes
+from chip_probes import MESHES, REPO
+
+
+@pytest.fixture(scope="module")
+def probes(request):
+    """The running probes: conftest's if collection started them, else
+    (this file run some other way) a set of our own."""
+    early = getattr(request.config, "_chip_probes", None)
+    if early is not None:
+        yield early  # conftest closes it
+        return
+    own = chip_probes.Probes()
+    yield own
+    own.close()
+
+
+def test_chip_smoke_refuses_to_run_on_the_cpu(probes):
+    rc, out, err = probes.result("smoke_on_cpu")
+    assert rc != 0
+    assert "platform='cpu'" in err and "JAX_PLATFORMS='cpu'" in err, err
+    assert '"ok"' not in out, out
+
+
+def test_failed_leg_fails_the_run(capsys):
+    import jax
+
+    import chip_smoke
+
+    def boom(devices, sizes):
+        raise RuntimeError("leg made to raise")
+
+    def fine(devices, sizes):
+        return {"compile_s": 0.0, "run_s": 0.0}
+
+    devices = jax.devices()
+    assert chip_smoke.run(devices, [("fine", fine), ("boom", boom)],
+                          None) != 0
+    assert '"ok"' not in capsys.readouterr().out
+    assert chip_smoke.run(devices, [("fine", fine)], None) == 0
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}
+
+
+@pytest.mark.slow
+def test_legs_pass_a_tiny_rehearsal_on_the_cpu_mesh(monkeypatch):
+    """The three legs through the real builders at tiny sizes, the way a
+    builder rehearses before spending chip time (not in tier-1: ~25 s)."""
+    import jax
+
+    # ServingLoop.run setdefaults this in os.environ; keep it to this test.
+    monkeypatch.setenv("HVD_TPU_CORE", "py")
+
+    import chip_smoke
+    from horovod_tpu.models import resnet
+
+    lm = dict(vocab_size=512, d_model=64, n_layers=2, n_heads=4, d_ff=128)
+    tiny = chip_smoke.Sizes(
+        resnet=lambda: resnet.ResNetConfig(blocks=(1, 1, 1, 1), width=8,
+                                           num_classes=100),
+        image=32, images_per_chip=4,
+        lm=dict(lm, max_seq_len=128, attn_impl="flash"),
+        lm_seqs_per_chip=2, lm_seq=128, attn_shape=(2, 128, 4, 32),
+        serve=dict(lm, max_seq_len=64),
+        serve_requests=((3, 6), (5, 4), (9, 3)))
+    assert chip_smoke.run(jax.devices()[:4], chip_smoke.LEGS, tiny) == 0
+
+
+def test_set_cache_dir_is_honoured_untouched(probes):
+    rc, out, err = probes.result("cache_set")
+    assert rc == 0, err
+    returned, configured = json.loads(out.strip().splitlines()[-1])
+    assert returned == configured == probes.set_dir
+    assert os.listdir(probes.set_dir), "nothing was cached there"
+
+
+def test_unset_cache_dir_is_one_place_in_the_checkout(probes):
+    paths = []
+    for name in ("cache_unset_a", "cache_unset_b"):
+        rc, out, err = probes.result(name)
+        assert rc == 0, err
+        returned, configured = json.loads(out.strip().splitlines()[-1])
+        assert returned == configured
+        paths.append(returned)
+    assert paths[0] == paths[1] == os.path.join(REPO, ".jax_cache")
+
+
+def test_unknown_device_kind_raises():
+    import device_peaks
+
+    assert device_peaks.peak("TPU v5 lite").bf16_flops == 197e12
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        device_peaks.peak("TPU v9 imaginary")
+
+
+def test_bare_init_does_not_open_the_backend(probes):
+    rc, out, err = probes.result("bare_init")
+    assert rc == 0, err
+    assert "BACKENDS []" in out, out
+
+
+def test_hvdrun_pins_one_chip_per_local_rank(monkeypatch):
+    from horovod_tpu.runner.hosts import SlotInfo
+    from horovod_tpu.runner.launch import _CHIP_PIN_VARS, worker_env
+
+    for var in _CHIP_PIN_VARS:
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    envs = [worker_env(SlotInfo("localhost", r, 4, r, 4, 0, 1),
+                       "127.0.0.1", 1234) for r in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    # children inherit the cache's place
+    assert all(e["JAX_COMPILATION_CACHE_DIR"] == "/some/dir" for e in envs)
+    # a rank alone on its host owns every chip there: nothing is pinned
+    alone = worker_env(SlotInfo("localhost", 0, 1, 0, 1, 0, 1),
+                       "127.0.0.1", 1234)
+    assert "TPU_VISIBLE_CHIPS" not in alone
+    # the user's own setting wins
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    mine = worker_env(SlotInfo("localhost", 1, 4, 1, 4, 0, 1),
+                      "127.0.0.1", 1234)
+    assert mine["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert "TPU_PROCESS_BOUNDS" not in mine
+
+
+@pytest.mark.parametrize("i", range(len(MESHES)),
+                         ids=[json.dumps(m) for m in MESHES])
+def test_flash_lm_step_lowered_for_tpu_carries_the_kernel(probes, i):
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    result = json.loads(out.split("RESULT", 1)[1])
+    assert result["device_kind"] == "TPU v5 lite"
+    assert result["tpu_custom_call"][i] > 0, result
