@@ -67,16 +67,19 @@ def _pick_block(seq_len: int, want: int) -> int:
     return max(b, 1)
 
 
-def _pallas_call(kernel, *args, **kwargs):
-    """``pl.pallas_call(kernel, **kwargs)(*args)``, compiled by Mosaic where
-    the surrounding computation is lowered for a TPU and interpreted on
-    every other platform.  ``lax.platform_dependent`` resolves the branch
-    at lowering time, so a step lowered for TPU devices from a CPU process
-    (AOT, or a chip JAX failed to make the default) never carries the
-    interpreter in place of the kernel."""
+def _pallas_call(name, kernel, *args, **kwargs):
+    """``pl.pallas_call(kernel, name=name, **kwargs)(*args)``, compiled by
+    Mosaic where the surrounding computation is lowered for a TPU and
+    interpreted on every other platform.  ``name`` becomes the compiled
+    step's instruction name (``flash_fwd.3``), which is what a profiler
+    trace shows and the benchmark's per-kernel metrics match.
+    ``lax.platform_dependent`` resolves the branch at lowering time, so a
+    step lowered for TPU devices from a CPU process (AOT, or a chip JAX
+    failed to make the default) never carries the interpreter in place of
+    the kernel."""
     def branch(interpret):
         return lambda *a: pl.pallas_call(
-            kernel, interpret=interpret, **kwargs)(*a)
+            kernel, interpret=interpret, name=name, **kwargs)(*a)
 
     return jax.lax.platform_dependent(
         *args, tpu=branch(False), default=branch(True))
@@ -141,7 +144,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32=False):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk)
     o, lse = _pallas_call(
-        kernel, q, k, v,
+        "flash_fwd", kernel, q, k, v,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -283,6 +286,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
     dlse = dlse.astype(jnp.float32)
 
     dq = _pallas_call(
+        "flash_bwd_dq",
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         q, k, v, do, lse, delta, dlse,
@@ -302,6 +306,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
     )
 
     dk, dv = _pallas_call(
+        "flash_bwd_dkv",
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk),
         q, k, v, do, lse, delta, dlse,

@@ -268,7 +268,9 @@ class SingleProcessEngine(_EngineBase):
         super().__init__(0, 1, 0, 1, 0, 1)
         self.timeline = timeline_mod.from_env(0)
         _telemetry.init_from_env(0, 0)
-        self._tracer = None  # tracing needs a gang; see PyEngine
+        # No collective to trace at size 1; the tracer is there for the
+        # serving loop's spans (telemetry/trace.py ``span``).
+        self._tracer = trace_mod.from_env(0)
         # Serving surface (serving/loop.py): a broadcast to a gang of
         # one is a local enqueue, so the loop's drive/apply split works
         # unchanged single-process.
@@ -284,6 +286,8 @@ class SingleProcessEngine(_EngineBase):
         with self._serve_cv:
             self._serve_cv.notify_all()
         self.timeline.shutdown()
+        trace_mod.release(self._tracer)
+        self._tracer = None
 
     def serve_broadcast(self, payload: bytes) -> None:
         with self._serve_cv:

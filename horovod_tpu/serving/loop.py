@@ -55,6 +55,7 @@ from horovod_tpu.serving.scheduler import Scheduler
 from horovod_tpu.serving.server import FrontDoor
 from horovod_tpu.telemetry import blackbox as _bb
 from horovod_tpu.telemetry import registry as _tmx
+from horovod_tpu.telemetry import trace as _trace
 from horovod_tpu.utils import env as env_util
 from horovod_tpu.utils.logging import get_logger
 
@@ -270,19 +271,21 @@ class ServingLoop:
     def _drive(self, eng, engine: DecodeEngine) -> None:
         seq = 0
         while True:
-            stopping = self._stop.is_set() and not self.scheduler.has_work()
-            admissions = self.scheduler.take_admissions()
-            if not stopping and not admissions and not self._slots:
+            work = self.scheduler.has_work()
+            if not work and not self._stop.is_set():
                 time.sleep(self.idle_poll_s)  # idle: no frame, no step
                 continue
+            stopping = not work  # drained, and stop() was asked
             seq += 1
-            payload = wire.encode_serve_delta(
-                seq, stopping,
-                [(slot, r.id, r.max_new, r.prompt)
-                 for slot, r in admissions],
-                eng.epoch, leader_addr=self._known_leader or "")
-            eng.serve_broadcast(payload)
-            frame = eng.serve_recv(timeout=self.recv_timeout_s)
+            with _trace.span("serve.frame", step=seq):
+                admissions = self.scheduler.take_admissions()
+                payload = wire.encode_serve_delta(
+                    seq, stopping,
+                    [(slot, r.id, r.max_new, r.prompt)
+                     for slot, r in admissions],
+                    eng.epoch, leader_addr=self._known_leader or "")
+                eng.serve_broadcast(payload)
+                frame = eng.serve_recv(timeout=self.recv_timeout_s)
             if frame is None:  # own frame is in the inbox unless dying
                 if self._engine_dying(eng):
                     return
@@ -327,42 +330,49 @@ class ServingLoop:
         # Chaos: a mid-decode stall/delay on this rank, fired before any
         # device work so the step's collective shows the gap.
         _fi.fire("serve.step", str(seq))
-        tr = getattr(eng, "_tracer", None)
-        ta0 = time.monotonic_ns() if tr is not None else 0
+        # serve.apply holds the turn; prefill, decode, confirm and emit
+        # are its disjoint leaves (docs/serving.md "Spans").
+        with _trace.span("serve.apply", step=seq,
+                         admitted=len(admissions)):
+            self._turn(seq, admissions, engine, rank0)
+        return False
+
+    def _turn(self, seq: int, admissions, engine: DecodeEngine,
+              rank0: bool) -> None:
         t0 = time.monotonic()
         for slot, req_id, max_new, prompt in admissions:
-            first = engine.prefill(slot, prompt)
-            self._slots[slot] = {"id": req_id, "prompt": list(prompt),
-                                 "max_new": max_new,
-                                 "remaining": max_new}
-            self._emit(slot, first, engine, rank0)
-        if self._slots:
+            with _trace.span("serve.prefill",
+                             histogram="hvd_serve_prefill_seconds",
+                             slot=slot, prompt_len=len(prompt)):
+                first = engine.prefill(slot, prompt)
+                self._slots[slot] = {"id": req_id, "prompt": list(prompt),
+                                     "max_new": max_new,
+                                     "remaining": max_new}
+                self._emit(slot, first, engine, rank0)
+        if not self._slots:
+            return
+        with _trace.span("serve.decode", slots=len(self._slots)):
             toks = engine.step()
-            tc0 = time.monotonic_ns() if tr is not None else 0
+        # The agreement allreduce's own collective spans share this
+        # step's wall window; the serve.confirm span ties them to the
+        # TAG_SERVE seq that caused them.
+        with _trace.span("serve.confirm", step=seq,
+                         slots=len(self._slots)) as confirm:
             self._confirm(toks)
-            if tr is not None:
-                # The agreement allreduce's own collective spans share
-                # this step's wall window; the serve.confirm span ties
-                # them to the TAG_SERVE seq that caused them.
-                tr.span("serve.confirm", tc0, time.monotonic_ns(),
-                        step=seq, slots=len(self._slots))
+        with _trace.span("serve.emit", slots=len(self._slots)):
             for slot in sorted(self._slots):
                 self._emit(slot, int(toks[slot]), engine, rank0)
-            # Step confirm on the flight recorder: reuses the tracer's
-            # post-confirm read when tracing, untimed otherwise (ring
-            # order still sequences it against failure events).
-            _bb.note("serve.confirm", tc0, step=seq,
-                     slots=len(self._slots))
-            if rank0:
-                t1 = time.monotonic()
-                _tmx.observe("hvd_serve_token_latency_seconds", t1 - t0)
-                # Staleness surface for /stats last_step_age_s — the
-                # same clock read the latency observe just took.
-                self.scheduler.note_step(t1)
-        if tr is not None:
-            tr.span("serve.apply", ta0, time.monotonic_ns(), step=seq,
-                    admitted=len(admissions))
-        return False
+        # Step confirm on the flight recorder: stamped with the span's
+        # entry read when tracing, untimed otherwise (ring order still
+        # sequences it against failure events).
+        _bb.note("serve.confirm", confirm.t0, step=seq,
+                 slots=len(self._slots))
+        if rank0:
+            t1 = time.monotonic()
+            _tmx.observe("hvd_serve_token_latency_seconds", t1 - t0)
+            # Staleness surface for /stats last_step_age_s — the same
+            # clock read the latency observe just took.
+            self.scheduler.note_step(t1)
 
     def _emit(self, slot: int, token: int, engine: DecodeEngine,
               rank0: bool) -> None:

@@ -44,6 +44,11 @@ class Request:
         self.max_new = max_new
         self.tokens: List[int] = []
         self.slot: Optional[int] = None
+        # Set at the first admission into a slot (and by fail_all, so a
+        # handler still waiting for a slot is released): the handler's
+        # wait is queued -> admitted -> done.  A replayed request stays
+        # admitted: its handler's first wait is over.
+        self.admitted = threading.Event()
         self.done = threading.Event()
         self.error: Optional[str] = None
         self.t_submit = time.monotonic()
@@ -160,6 +165,11 @@ class Scheduler:
                 req.attempts += 1
                 self._slots[slot] = req
                 out.append((slot, req))
+                if not req.admitted.is_set():
+                    if _tmx.enabled():
+                        _tmx.observe("hvd_serve_queue_wait_seconds",
+                                     time.monotonic() - req.t_submit)
+                    req.admitted.set()
             if out:
                 _tmx.set_gauge("hvd_serve_queue_depth", len(self._queue))
                 _tmx.set_gauge("hvd_serve_batch_occupancy",
@@ -226,6 +236,7 @@ class Scheduler:
         for req in pending:
             req.error = reason
             req.done.set()
+            req.admitted.set()
 
     # -- introspection ---------------------------------------------------
 

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -35,6 +36,7 @@ from typing import Callable, Optional
 from horovod_tpu.common import fault_injection as _fi
 from horovod_tpu.serving.scheduler import QueueFull, Scheduler
 from horovod_tpu.telemetry import registry as _tmx
+from horovod_tpu.telemetry import trace as _trace
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -122,7 +124,16 @@ class _Handler(BaseHTTPRequestHandler):
                              labels=("error",))
             self._send_json(400, {"error": str(e)})
             return
-        if not req.done.wait(self.door.timeout_s):
+        # One deadline over both waits: for a slot, then for the answer.
+        deadline = time.monotonic() + self.door.timeout_s
+        with _trace.span("serve.queued", id=req.id):
+            admitted = req.admitted.wait(self.door.timeout_s)
+        done = False
+        if admitted:
+            with _trace.span("serve.active", id=req.id):
+                done = req.done.wait(
+                    max(deadline - time.monotonic(), 0.0))
+        if not done:
             _tmx.inc_counter("hvd_serve_requests_total",
                              labels=("error",))
             self._send_json(504, {"error": "request timed out",
@@ -133,8 +144,6 @@ class _Handler(BaseHTTPRequestHandler):
                              labels=("error",))
             self._send_json(500, {"error": req.error, "id": req.id})
             return
-        import time
-
         now = time.monotonic()
         self._send_json(200, {
             "id": req.id,

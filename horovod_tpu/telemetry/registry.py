@@ -160,9 +160,6 @@ KNOWN_METRICS: Dict[str, dict] = {
     "hvd_trace_clock_skew_seconds": _gauge(
         "Latest midpoint-method estimate of this rank's monotonic-clock "
         "offset from rank 0 (TAG_CLOCK_PING over the control channel)."),
-    "hvd_trace_spans_total": _counter(
-        "Trace spans recorded, by span phase (negotiate, pack, hop, "
-        "unpack, callback, serve.*, elastic.*, ...).", ("phase",)),
     # -- straggler detection (telemetry/straggler.py) --
     "hvd_straggler_skew_seconds": _hist(
         "Negotiation skew: last rank ready minus first rank ready, "
@@ -182,6 +179,13 @@ KNOWN_METRICS: Dict[str, dict] = {
     "hvd_serve_ttft_seconds": _hist(
         "Time to first token: submit to first sampled token.",
         *_SECONDS),
+    "hvd_serve_queue_wait_seconds": _hist(
+        "Time a request waited for a decode slot: submit to its first "
+        "admission (rank 0).", *_SECONDS),
+    "hvd_serve_prefill_seconds": _hist(
+        "Wall time of one admission's prefill: dispatch, the cache "
+        "install and the first token's readback (a serve.prefill "
+        "span).", *_SECONDS),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one gang decode step (prefills + batched step + "
         "token-agreement allreduce).", *_SECONDS),

@@ -4,11 +4,17 @@ The timeline (utils/timeline.py) is rank-0-only and records *that* a
 collective ran; this module records *where the time went on every rank*:
 one span stream per rank covering the full life of each fused collective
 — ``negotiate`` (enqueue to execution start), ``pack``,
-``hop[i]{send_wait, recv, reduce}``, ``unpack``, ``callback`` — plus the
-serving lockstep steps (``serve.apply`` / ``serve.confirm``), elastic
-``elastic.reform`` / ``elastic.replay``, and recovery-ladder
+``hop[i]{send_wait, recv, reduce}``, ``unpack``, ``callback`` — plus
+elastic ``elastic.reform`` / ``elastic.replay``, and recovery-ladder
 ``hop.retry`` / ``transport.failover`` events, each tagged with (rank,
 collective seq, transport kind, peer).
+
+The serving path (serving/loop.py, serving/server.py) names its work
+through :func:`span`, one call site with two sinks: a
+``jax.profiler.TraceAnnotation`` (``hvd:serve.*``, on the device
+trace's clock whenever a profiler session is running) and, when this
+process has a :class:`Tracer`, the same phase and args in the JSONL
+stream below.
 
 On-disk format is JSONL, one record per line (append-safe across elastic
 re-forms, truncation-safe on crash):
@@ -103,8 +109,6 @@ class Tracer:
         if args:
             rec.update(args)
         self._push(rec)
-        if _tmx.enabled():
-            _tmx.inc_counter("hvd_trace_spans_total", 1, (phase,))
 
     def instant(self, phase: str, **args) -> None:
         t = time.monotonic_ns()
@@ -202,6 +206,52 @@ def emit_instant(phase: str, **args) -> None:
     tr = _TR
     if tr is not None:
         tr.instant(phase, **args)
+
+
+class span:
+    """``with span("serve.decode", slots=n): ...`` — one call site, two
+    sinks.  Entering opens ``jax.profiler.TraceAnnotation("hvd:" +
+    phase, **args)``: a flag check unless a profiler session is running,
+    and then a host event on the device trace's own clock.  Leaving
+    records the same phase and args in this process's JSONL stream when
+    it has a :class:`Tracer` (``HVD_TRACE``).
+
+    The clock is read only for that second sink, or for ``histogram``:
+    a registry histogram (telemetry on) that takes the span's length in
+    seconds.  With neither, no clock read, no write, no state.  ``t0``
+    is the entry stamp (``time.monotonic_ns()`` axis, 0 = untimed) for
+    a caller that hands it on (the flight recorder's ``serve.confirm``).
+
+    jax is imported on first entry, not with this module: the launcher
+    and numpy-only workers import the module and must stay off jax.
+    """
+
+    __slots__ = ("phase", "args", "t0", "_histogram", "_annotation")
+
+    def __init__(self, phase: str, histogram: Optional[str] = None,
+                 **args):
+        self.phase = phase
+        self.args = args
+        self.t0 = 0
+        self._histogram = histogram
+
+    def __enter__(self) -> "span":
+        from jax.profiler import TraceAnnotation
+
+        if _TR is not None or (self._histogram and _tmx.enabled()):
+            self.t0 = time.monotonic_ns()
+        self._annotation = TraceAnnotation("hvd:" + self.phase,
+                                           **self.args)
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+        if self.t0:
+            t1 = time.monotonic_ns()
+            emit(self.phase, self.t0, t1, **self.args)
+            if self._histogram:
+                _tmx.observe(self._histogram, (t1 - self.t0) * 1e-9)
 
 
 def release(tr: Optional[Tracer]) -> None:

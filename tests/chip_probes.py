@@ -11,6 +11,7 @@ nothing.
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -47,9 +48,11 @@ def probe_bare_init():
 
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
-    process, for the compile-only ``v5e:2x2`` topology.  One process for
-    every mesh (libtpu's lockfile); the meshes compile in threads, XLA
-    works outside the interpreter lock."""
+    process, for the compile-only ``v5e:2x2`` topology, and the names of
+    those instructions (what the profiler's ``XLA Ops`` events, and the
+    benchmark's per-kernel metrics, tell the kernels apart by).  One
+    process for every mesh (libtpu's lockfile); the meshes compile in
+    threads, XLA works outside the interpreter lock."""
     from concurrent.futures import ThreadPoolExecutor
 
     import jax
@@ -74,14 +77,18 @@ def probe_lower_for_tpu(meshes_json):
         state = jax.eval_shape(init, jax.random.PRNGKey(0))
         toks = jax.ShapeDtypeStruct((8, 256), jnp.int32)
         text = step.lower(state, toks, toks).compile().as_text()
-        return text.count("tpu_custom_call")
+        names = re.findall(
+            r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+            text, re.M)
+        return text.count("tpu_custom_call"), names
 
     meshes = json.loads(meshes_json)
     with ThreadPoolExecutor(len(meshes)) as pool:
-        counts = list(pool.map(mosaic_calls, meshes))
+        found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
-        "tpu_custom_call": counts}))
+        "tpu_custom_call": [n for n, _ in found],
+        "kernel_names": [names for _, names in found]}))
 
 
 class Probes:

@@ -33,8 +33,16 @@ sys.path.insert(0, REPO)
 SEG_GLOB = "/dev/shm/hvd-shm-*"
 
 
-def _segs():
-    return glob.glob(SEG_GLOB)
+def _segs(patience=5.0):
+    """Named segments still in ``/dev/shm`` after ``patience`` seconds.
+    A gang of another test file, run beside this one by pytest-xdist, has
+    its own for the instant of its pairing; a leak stays."""
+    deadline = time.monotonic() + patience
+    while True:
+        found = glob.glob(SEG_GLOB)
+        if not found or time.monotonic() >= deadline:
+            return found
+        time.sleep(0.05)
 
 
 def _senders():

@@ -158,3 +158,18 @@ def test_flash_lm_step_lowered_for_tpu_carries_the_kernel(probes, i):
     result = json.loads(out.split("RESULT", 1)[1])
     assert result["device_kind"] == "TPU v5 lite"
     assert result["tpu_custom_call"][i] > 0, result
+
+
+@pytest.mark.parametrize("kernel",
+                         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
+def test_flash_kernels_keep_their_names_in_the_lowered_step(probes, kernel):
+    """The compiled step's Mosaic instructions are named after the three
+    ``pallas_call``s (``flash_fwd.3``, ...), on every mesh: the names the
+    profiler shows and ``perfbench/readers/kernel_ms.py`` matches."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    result = json.loads(out.split("RESULT", 1)[1])
+    for names in result["kernel_names"]:
+        stems = {n.rsplit(".", 1)[0] for n in names}
+        assert kernel in stems, names
+        assert stems <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, names
