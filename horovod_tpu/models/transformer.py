@@ -547,11 +547,16 @@ def generate(params, prompt, cfg: TransformerConfig, *,
 KV_CACHE_SPEC = P(None, None, None, "tp", None)  # [L, B, Smax, H, HD]
 
 
-def _attention_cached_slots(x, lp, cfg, k_cache, v_cache, pos):
-    """One token per slot against the cache, at per-slot positions.
+def _attention_cached_slots(x, lp, cfg, ks, vs, layer, pos):
+    """One token per slot against layer ``layer`` of the stacked caches,
+    at per-slot positions.
 
-    x: [B, 1, D]; k/v_cache: [B, Smax, H, HD]; ``pos``: [B] int32, the
-    absolute position of THIS token in each slot.  Returns
+    x: [B, 1, D]; ks/vs: [L, B, Smax, H, HD]; ``layer``: scalar int32;
+    ``pos``: [B] int32, the absolute position of THIS token in each slot.
+    Writes the B new rows at [layer, b, pos[b]] and reads layer
+    ``layer``'s lane where it lies: nothing of the cache's or a lane's
+    size is produced besides the caches themselves, which the caller
+    carries (and donates), so XLA updates them in place.  Returns
     (out [B, 1, D], updated caches)."""
     dtype = cfg.compute_dtype
     B = x.shape[0]
@@ -578,8 +583,10 @@ def _attention_cached_slots(x, lp, cfg, k_cache, v_cache, pos):
     q = rot(q)
     k = rot(k)
     rows = jnp.arange(B)
-    k_cache = k_cache.at[rows, pos].set(k[:, 0])
-    v_cache = v_cache.at[rows, pos].set(v[:, 0])
+    ks = ks.at[layer, rows, pos].set(k[:, 0])
+    vs = vs.at[layer, rows, pos].set(v[:, 0])
+    k_cache = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
+    v_cache = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
     scale = 1.0 / math.sqrt(cfg.head_dim)
     logits = jnp.einsum("bshk,bthk->bhst", q, k_cache
                         ).astype(jnp.float32) * scale
@@ -589,7 +596,7 @@ def _attention_cached_slots(x, lp, cfg, k_cache, v_cache, pos):
     probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
     ctx = jnp.einsum("bhst,bthk->bshk", probs, v_cache)
     return (jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)),
-            k_cache, v_cache)
+            ks, vs)
 
 
 def decode_step(params, tok, pos, ks, vs, cfg: TransformerConfig):
@@ -597,19 +604,25 @@ def decode_step(params, tok, pos, ks, vs, cfg: TransformerConfig):
     at its own ``pos`` [B], return (next-token logits [B, V] f32, updated
     caches [L, B, Smax, H, HD]).  The layer body is generate()'s step
     with _attention_cached swapped for the per-slot-position variant.
-    Dense-FFN configs only (same contract as generate())."""
+    The caches are the layer loop's CARRY, indexed by layer, not its
+    ``xs``/``ys``: a scan that slices a lane out and stacks it back
+    copies the whole cache every step.  Dense-FFN configs only (same
+    contract as generate())."""
     dtype = cfg.compute_dtype
     x = params["embed"].astype(dtype)[tok[:, None]]
 
-    def layer(h, layer_in):
-        lp, k_c, v_c = layer_in
+    def layer(carry, layer_in):
+        h, ks, vs = carry
+        lp, l = layer_in
         y = _rmsnorm(h, lp["ln1"])
-        attn, k_c, v_c = _attention_cached_slots(y, lp, cfg, k_c, v_c, pos)
+        attn, ks, vs = _attention_cached_slots(y, lp, cfg, ks, vs, l, pos)
         h = h + attn
         h = h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype)
-        return h, (k_c, v_c)
+        return (h, ks, vs), None
 
-    x, (ks, vs) = lax.scan(layer, x, (params["layers"], ks, vs))
+    (x, ks, vs), _ = lax.scan(
+        layer, (x, ks, vs),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = _rmsnorm(x, params["ln_f"])
     logits = vocab_projection(x, params["embed"])[:, 0]
     return logits, ks, vs

@@ -10,6 +10,7 @@ nothing.
 """
 
 import json
+import math
 import os
 import re
 import shutil
@@ -46,18 +47,68 @@ def probe_bare_init():
     hvd.shutdown()
 
 
+def serve_cache_programs(cfg, slots, min_elems, sharding=None):
+    """The two programs that write the serving slot cache (the decode step
+    and the install that ends a prefill), compiled from shapes alone for
+    the default device or for ``sharding``'s: what each produces of
+    ``min_elems`` elements or more (:func:`big_ops`), its temporaries and
+    its aliased bytes."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving import decode
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: tfm.init(k, cfg), jax.random.PRNGKey(0)))
+    lane = (cfg.n_layers, 1, cfg.max_seq_len, cfg.n_heads, cfg.head_dim)
+    cache = spec((lane[0], slots) + lane[2:], cfg.compute_dtype)
+    lane = spec(lane, cfg.compute_dtype)
+    lowered = {
+        "step": jax.jit(partial(tfm.decode_step, cfg=cfg),
+                        donate_argnums=(3, 4)).lower(
+            params, spec((slots,)), spec((slots,)), cache, cache),
+        "install": jax.jit(decode._install, donate_argnums=(0, 1)).lower(
+            cache, cache, spec((slots,)), spec((slots,)), spec(()),
+            spec((cfg.vocab_size,), jnp.float32), lane, lane, spec(()))}
+    out = {}
+    for name, program in lowered.items():
+        compiled = program.compile()
+        mem = compiled.memory_analysis()
+        out[name] = {"big_ops": big_ops(compiled.as_text(), min_elems),
+                     "temp_bytes": mem.temp_size_in_bytes,
+                     "alias_bytes": mem.alias_size_in_bytes}
+    return out
+
+
+# The benchmark's cache shape (32 slots x 1536 x 16 heads of 128) with two
+# layers and a narrow feed-forward, so that the weights are smaller than
+# one layer's lane of the cache.
+SERVE_CACHE = dict(slots=32, vocab_size=1024, d_model=2048, n_layers=2,
+                   n_heads=16, d_ff=512, max_seq_len=1536)
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
     those instructions (what the profiler's ``XLA Ops`` events, and the
-    benchmark's per-kernel metrics, tell the kernels apart by).  One
-    process for every mesh (libtpu's lockfile); the meshes compile in
-    threads, XLA works outside the interpreter lock."""
+    benchmark's per-kernel metrics, tell the kernels apart by); and what
+    the serving cache's two programs produce there
+    (:func:`serve_cache_programs`).  One process for everything compiled
+    for the chip (libtpu's lockfile); the compiles run in threads, XLA
+    works outside the interpreter lock."""
     from concurrent.futures import ThreadPoolExecutor
 
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
@@ -83,12 +134,58 @@ def probe_lower_for_tpu(meshes_json):
         return text.count("tpu_custom_call"), names
 
     meshes = json.loads(meshes_json)
-    with ThreadPoolExecutor(len(meshes)) as pool:
+    sizes = dict(SERVE_CACHE)
+    slots = sizes.pop("slots")
+    cfg = tfm.TransformerConfig(**sizes)
+    with ThreadPoolExecutor(len(meshes) + 1) as pool:
+        serve_cache = pool.submit(
+            serve_cache_programs, cfg, slots,
+            slots * cfg.max_seq_len * cfg.d_model,      # one layer's lane
+            SingleDeviceSharding(topo.devices[0]))
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
+        "serve_cache": serve_cache.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
+
+
+_INSTR = re.compile(
+    r"^\s*(ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\("
+    r"(?:.*\bcalls=%?([\w.\-]+))?")
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "bitcast", "while")
+
+
+def big_ops(hlo_text, min_elems):
+    """The instructions of a compiled program that produce an array of at
+    least ``min_elems`` elements, as ``[name, opcode]``: those outside
+    fused computations (a fusion's inner instructions never reach memory)
+    and other than plumbing (parameters, tuples, bitcasts, the ``while``
+    itself).  A fusion is named by its root's opcode (``fusion:scatter``)."""
+    comps, body = {}, None      # computation -> (root, name, type, op, calls)
+    for line in hlo_text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*->.*\{\s*$", line)
+        instr = _INSTR.match(line)
+        if head:
+            body = comps.setdefault(head.group(1), [])
+        elif body is not None and instr:
+            body.append(instr.groups())
+    fused = {calls for instrs in comps.values()
+             for _, _, _, op, calls in instrs if op == "fusion"}
+    roots = {comp: op for comp, instrs in comps.items()
+             for root, _, _, op, _ in instrs if root}
+    found = []
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        for _, name, shape, op, calls in instrs:
+            elems = [math.prod(map(int, dims.split(",")))
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", shape)]
+            if op in _PLUMBING or max(elems, default=0) < min_elems:
+                continue
+            found.append([name, "fusion:" + roots.get(calls, "?")
+                          if op == "fusion" else op])
+    return found
 
 
 class Probes:

@@ -173,3 +173,22 @@ def test_flash_kernels_keep_their_names_in_the_lowered_step(probes, kernel):
         stems = {n.rsplit(".", 1)[0] for n in names}
         assert kernel in stems, names
         assert stems <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, names
+
+
+@pytest.mark.parametrize("program,update", [
+    ("step", "fusion:scatter"), ("install", "fusion:dynamic-update-slice")])
+def test_serving_cache_programs_update_in_place_on_the_chip(
+        probes, program, update):
+    """Compiled for ``v5e`` at the benchmark's cache shape, ``decode_step``
+    and the install produce nothing of a layer lane's size besides the
+    in-place row or lane update of the caches they were given: no copy of
+    the stack, no lane cut out of it, temporaries under one lane, and both
+    caches aliased from input to output."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_cache"][program]
+    c = chip_probes.SERVE_CACHE
+    lane_bytes = 2 * c["slots"] * c["max_seq_len"] * c["d_model"]  # bfloat16
+    assert [op for _, op in got["big_ops"]] == [update, update], got
+    assert got["temp_bytes"] < lane_bytes, got
+    assert got["alias_bytes"] == 2 * c["n_layers"] * lane_bytes, got

@@ -385,8 +385,9 @@ def _requests(n):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.timeout(240)
-def test_single_process_selftest_matches_generate():
+def _selftest_example():
+    """``examples/serve_lm.py --selftest 3``: three concurrent requests
+    over HTTP through a ServingLoop in a process of their own."""
     env = dict(os.environ)
     env.pop(fi.ENV_VAR, None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -405,8 +406,53 @@ def test_single_process_selftest_matches_generate():
            for m in re.finditer(r"request (\d+): (\[[^\]]*\])",
                                 res.stdout)}
     assert sorted(got) == [0, 1, 2], res.stdout
-    for i in range(3):
-        assert got[i] == _oracle_tokens([3 + i, 14, 15], 12), i
+    return [([3 + i, 14, 15], got[i]) for i in range(3)]
+
+
+def _slot_reuse():
+    """A slot retired and re-admitted with a SHORTER prompt, then decoded
+    past the old request's length: the install writes the new lane in
+    place over the old request's, and nothing of the old one (nor of the
+    idle slot's steps between) may reach the new request's tokens.  A
+    neighbour decodes in the other slot throughout."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving.decode import DecodeEngine
+
+    cfg = tfm.TransformerConfig(
+        max_seq_len=CACHE_LEN, compute_dtype=jnp.float32, remat=False,
+        **MODEL)
+    engine = DecodeEngine(tfm.init(jax.random.PRNGKey(0), cfg), cfg,
+                          max_batch=2, cache_len=CACHE_LEN)
+
+    neighbour = [9, 8, 7, 6]
+    beside = [engine.prefill(1, neighbour)]
+
+    def serve(prompt, max_new):
+        tokens = [engine.prefill(0, prompt)]
+        for _ in range(max_new - 1):
+            step = engine.step()
+            tokens.append(int(step[0]))
+            beside.append(int(step[1]))
+        engine.clear(0)
+        return prompt, tokens
+
+    long_prompt = [1 + (5 * i) % 60 for i in range(20)]
+    return [serve(long_prompt, 6),      # rows 0..24 of slot 0's lane
+            serve([3, 14, 15], 40),     # rows 0..41
+            (neighbour, beside)]
+
+
+@pytest.mark.timeout(240)
+@pytest.mark.parametrize("scenario", [_selftest_example, _slot_reuse],
+                         ids=["selftest_example", "slot_reuse"])
+def test_single_process_selftest_matches_generate(scenario):
+    """Every completion is bit-identical to ``generate`` decoding that
+    prompt alone at the serving cache length."""
+    for prompt, tokens in scenario():
+        assert tokens == _oracle_tokens(prompt, len(tokens)), prompt
 
 
 # ---------------------------------------------------------------------------
