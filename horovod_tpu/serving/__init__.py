@@ -1,8 +1,13 @@
 """horovod_tpu.serving — continuous-batching LM inference on the gang.
 
 The north star serves heavy traffic, not just training throughput: this
-package turns the flagship transformer's KV-cache decode loop
-(models/transformer.py) into a served workload with a latency SLO.
+package turns a model's single-request decode into a served workload
+with a latency SLO.  The model is chosen by the type of the config handed
+to :class:`ServingLoop`: a :class:`TransformerConfig` (the dense decoder,
+models/transformer.py: a K/V cache a slot) or a :class:`JambaConfig`
+(models/jamba.py: Mamba layers beside attention, so a slot holds
+recurrent state beside a small K/V lane); ``init`` of the same module
+makes its weights.
 
 Shape of the system (docs/serving.md):
 
@@ -23,7 +28,9 @@ Shape of the system (docs/serving.md):
   loop.py).
 """
 
-from horovod_tpu.serving.decode import DecodeEngine
+from horovod_tpu.models.jamba import JambaConfig
+from horovod_tpu.models.transformer import TransformerConfig
+from horovod_tpu.serving.decode import DecodeEngine, SlotModel, slot_model
 from horovod_tpu.serving.loop import ServingLoop
 from horovod_tpu.serving.scheduler import QueueFull, Request, Scheduler
 from horovod_tpu.serving.server import FrontDoor
@@ -31,8 +38,12 @@ from horovod_tpu.serving.server import FrontDoor
 __all__ = [
     "DecodeEngine",
     "FrontDoor",
+    "JambaConfig",
     "QueueFull",
     "Request",
     "Scheduler",
     "ServingLoop",
+    "SlotModel",
+    "TransformerConfig",
+    "slot_model",
 ]

@@ -1,23 +1,35 @@
 """Per-rank continuous-batching decode state.
 
 One :class:`DecodeEngine` lives on every rank of the serving gang and
-holds the slot-batched KV caches ([L, max_batch, cache_len, H, HD]) and
-the per-slot current token and position vectors.  The caches are ONE
-resident pair of buffers: the two programs that write them, the jit-ed
-step (models/transformer.decode_step: B new rows a layer) and the
-install that ends a prefill (``_install``: one slot's lane), take them
-donated and update them in place, so neither a turn nor an admission
-copies the cache or holds a second one (pinned on the compiled programs
-by tests/test_serving_cache.py, and on the chip by the benchmark's
-``peak_hbm_gb.serve`` and op breakdown).  The per-slot math is
-bit-identical to the single-request ``generate`` path, so a slot's
-output never depends on what its neighbors are decoding (pinned by
-tests/test_serving.py oracles).
+holds the state of ``max_batch`` slots and the per-slot current token and
+position vectors.  What that state IS belongs to the model: one pytree
+that the model's side of the seam (:func:`slot_model`) makes, fills from
+a prompt, installs into a slot and steps.  Its top-level keys are the
+kinds of state a slot holds:
 
-Long-context KV shards over the mesh via the model's KV_CACHE_SPEC
-(heads over ``tp``) — the same ``parallel/`` mesh-spec plumbing training
-uses, applied with ``filter_spec`` so a spec axis missing from the mesh
-degrades to replication.
+* ``"kv"``: position-indexed keys and values.  A retired lane is hidden
+  by the position mask and overwritten by the next install.
+* ``"recurrent"``: fixed-size state with no mask (a state-space layer's).
+  The install overwrites ALL of a slot's, so nothing of its last tenant
+  reaches the next (pinned by tests/test_jamba.py).
+
+The dense decoder (models/transformer.py) holds ``{"kv": (ks, vs)}``, each
+``[L, max_batch, cache_len, H, HD]``; models/jamba.py holds both kinds.
+The state is ONE resident set of buffers: the two programs that write it,
+the jit-ed step (B new rows, or one recurrent update, a layer) and the
+install that ends a prefill (one slot's share), take it donated and
+update it in place, so neither a turn nor an admission copies it or
+holds a second one (pinned on the compiled programs by
+tests/test_serving_cache.py and tests/test_jamba.py, and on the chip by
+the benchmark's ``peak_hbm_gb.serve`` and op breakdown).  The per-slot
+math is that of the model's single-request path, so a slot's output never
+depends on what its neighbors are decoding (pinned by
+tests/test_serving.py and tests/test_jamba.py oracles).
+
+Under a mesh the state shards by the model's spec (the dense decoder's
+KV_CACHE_SPEC: heads over ``tp``), applied with ``filter_spec`` so a spec
+axis missing from the mesh degrades to replication; a model without a
+spec refuses a mesh.
 
 Prefill compiles once per distinct prompt length (the serving analogue
 of generate()'s per-shape compile); the install takes the slot as a
@@ -29,82 +41,149 @@ re-formed gang replay a request to the identical completion.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
+from horovod_tpu.models import jamba as J
 from horovod_tpu.models import transformer as T
+from horovod_tpu.telemetry import registry as _tmx
+
+STATE_KINDS = ("kv", "recurrent")
 
 
-def _install(ks, vs, tok, pos, slot, logits, k1, v1, length):
-    """End of a prefill: write the request's K/V ([L, 1, cache_len, H,
-    HD], zero past the prompt) into lane ``slot`` of the caches and set
-    the slot's token (greedy, from the prefill's ``logits`` [V]) and
-    position.  Returns (first token, ks, vs, tok, pos)."""
-    first = jnp.argmax(logits).astype(jnp.int32)
+class SlotModel(NamedTuple):
+    """A model's side of the seam.  ``state`` is its pytree, keyed by kind
+    at the top; a request's state is what ``install`` takes.
+
+    * ``init_state(max_batch)`` -> state, zeros
+    * ``prefill(params, prompt [S])`` -> (logits [V], request state)
+    * ``install(state, slot, request state)`` -> state
+    * ``step(params, tok [B], pos [B], state)`` -> (logits [B, V], state)
+    * ``spec``: the state's PartitionSpec pytree, or None (no mesh)
+    """
+    init_state: Callable
+    prefill: Callable
+    install: Callable
+    step: Callable
+    spec: Any = None
+
+
+def _dense_install(state, slot, request):
+    (ks, vs), (k1, v1) = state["kv"], request["kv"]
     at = (0, slot, 0, 0, 0)
-    ks = jax.lax.dynamic_update_slice(ks, k1, at)
-    vs = jax.lax.dynamic_update_slice(vs, v1, at)
-    return (first, ks, vs, tok.at[slot].set(first),
-            pos.at[slot].set(length))
+    return {"kv": (jax.lax.dynamic_update_slice(ks, k1, at),
+                   jax.lax.dynamic_update_slice(vs, v1, at))}
+
+
+def _dense_slot_model(cfg: T.TransformerConfig, cache_len: int) -> SlotModel:
+    """models/transformer.py's side: ``{"kv": (ks, vs)}``, a request's
+    lane ``[L, 1, cache_len, H, HD]``, zero past the prompt."""
+    if cfg.n_experts:
+        raise NotImplementedError(
+            "serving supports dense-FFN configs (same contract as "
+            "models.transformer.generate)")
+    shape = (cfg.n_layers, cache_len, cfg.n_heads, cfg.head_dim)
+
+    def init_state(max_batch):
+        full = (shape[0], max_batch) + shape[1:]
+        return {"kv": (jnp.zeros(full, cfg.compute_dtype),
+                       jnp.zeros(full, cfg.compute_dtype))}
+
+    def prefill(params, prompt):
+        logits, k1, v1 = T.prefill_request(params, prompt, cfg, cache_len)
+        return logits, {"kv": (k1, v1)}
+
+    def step(params, tok, pos, state):
+        logits, ks, vs = T.decode_step(params, tok, pos, *state["kv"], cfg)
+        return logits, {"kv": (ks, vs)}
+
+    return SlotModel(init_state, prefill, _dense_install, step,
+                     {"kv": (T.KV_CACHE_SPEC, T.KV_CACHE_SPEC)})
+
+
+def slot_model(cfg, cache_len: int) -> SlotModel:
+    """The model is chosen by the type of its config."""
+    if isinstance(cfg, T.TransformerConfig):
+        return _dense_slot_model(cfg, cache_len)
+    if isinstance(cfg, J.JambaConfig):
+        return SlotModel(
+            partial(J.init_state, cfg, cache_len=cache_len),
+            partial(J.prefill_request, cfg=cfg, cache_len=cache_len),
+            J.install_request, partial(J.decode_step, cfg=cfg))
+    raise TypeError(f"no serving path for a {type(cfg).__name__}")
+
+
+def install(model: SlotModel, state, tok, pos, slot, logits, request,
+            length):
+    """End of a prefill: write the request's state into slot ``slot`` of
+    the batch's and set the slot's token (greedy, from the prefill's
+    ``logits`` [V]) and position.  Returns (first token, state, tok,
+    pos)."""
+    first = jnp.argmax(logits).astype(jnp.int32)
+    return (first, model.install(state, slot, request),
+            tok.at[slot].set(first), pos.at[slot].set(length))
 
 
 class DecodeEngine:
-    def __init__(self, params, cfg: T.TransformerConfig, *,
-                 max_batch: int, cache_len: Optional[int] = None,
-                 mesh=None):
-        if cfg.n_experts:
-            raise NotImplementedError(
-                "serving supports dense-FFN configs (same contract as "
-                "models.transformer.generate)")
+    def __init__(self, params, cfg, *, max_batch: int,
+                 cache_len: Optional[int] = None, mesh=None):
         self.params = params
         self.cfg = cfg
         self.max_batch = max_batch
         self.cache_len = cache_len or cfg.max_seq_len
         self.mesh = mesh
-        L, H, HD = cfg.n_layers, cfg.n_heads, cfg.head_dim
-        shape = (L, max_batch, self.cache_len, H, HD)
-        self.ks = jnp.zeros(shape, cfg.compute_dtype)
-        self.vs = jnp.zeros(shape, cfg.compute_dtype)
+        self.model = slot_model(cfg, self.cache_len)
+        self.state = self.model.init_state(max_batch)
         sharding = None
         if mesh is not None:
+            if self.model.spec is None:
+                raise NotImplementedError(
+                    f"serving a {type(cfg).__name__} under a mesh: its "
+                    "state has no sharding spec (recurrent state under tp "
+                    "is not written); serve it with mesh=None")
             from horovod_tpu.parallel.mesh import sharding_for
 
-            sharding = sharding_for(mesh, T.KV_CACHE_SPEC)
-            self.ks = jax.device_put(self.ks, sharding)
-            self.vs = jax.device_put(self.vs, sharding)
+            sharding = jax.tree.map(
+                partial(sharding_for, mesh), self.model.spec,
+                is_leaf=lambda s: isinstance(s, PartitionSpec))
+            self.state = jax.device_put(self.state, sharding)
+        for kind in STATE_KINDS:
+            _tmx.set_gauge(
+                "hvd_serve_state_bytes",
+                sum(a.nbytes for a in jax.tree.leaves(
+                    self.state.get(kind, ()))), labels=(kind,))
         self.tok = jnp.zeros((max_batch,), jnp.int32)
         self.pos = jnp.zeros((max_batch,), jnp.int32)
-        # The caches leave both programs as they entered them: the same
+        # The state leaves both programs as it entered them: the same
         # buffers (donated), under the same sharding.
-        self._step = jax.jit(
-            partial(T.decode_step, cfg=cfg), donate_argnums=(3, 4),
-            out_shardings=(None, sharding, sharding))
+        self._step = jax.jit(self.model.step, donate_argnums=(3,),
+                             out_shardings=(None, sharding))
         self._install = jax.jit(
-            _install, donate_argnums=(0, 1),
-            out_shardings=(None, sharding, sharding, None, None))
+            partial(install, self.model), donate_argnums=(0,),
+            out_shardings=(None, sharding, None, None))
         self._prefills: Dict[int, object] = {}  # prompt len -> jit fn
 
     def prefill(self, slot: int, prompt: List[int]) -> int:
-        """Run the prompt through the model, install its K/V into the
-        slot's cache lane, and return the first sampled (greedy) token.
-        The slot is live from the next step() on."""
+        """Run the prompt through the model, install its state into the
+        slot, and return the first sampled (greedy) token.  The slot is
+        live from the next step() on."""
         fn = self._prefills.get(len(prompt))
         if fn is None:
-            fn = jax.jit(partial(T.prefill_request, cfg=self.cfg,
-                                 cache_len=self.cache_len))
-            self._prefills[len(prompt)] = fn
-        logits, k1, v1 = fn(self.params, jnp.asarray(prompt, jnp.int32))
-        first, self.ks, self.vs, self.tok, self.pos = self._install(
-            self.ks, self.vs, self.tok, self.pos, np.int32(slot),
-            logits, k1, v1, np.int32(len(prompt)))
+            fn = self._prefills[len(prompt)] = jax.jit(self.model.prefill)
+        logits, request = fn(self.params, jnp.asarray(prompt, jnp.int32))
+        first, self.state, self.tok, self.pos = self._install(
+            self.state, self.tok, self.pos, np.int32(slot), logits,
+            request, np.int32(len(prompt)))
         return int(first)
 
     def clear(self, slot: int) -> None:
-        """Retire a slot.  The cache lane is left as-is — the position
-        mask hides it, and the next admission's install overwrites it."""
+        """Retire a slot.  Its state is left as-is — the position mask
+        hides a key/value lane, an idle slot's recurrent state reaches no
+        other row, and the next admission's install overwrites both."""
         self.tok = self.tok.at[slot].set(0)
         self.pos = self.pos.at[slot].set(0)
 
@@ -112,8 +191,8 @@ class DecodeEngine:
         """One decode step for the whole batch; returns the [max_batch]
         greedy next-token vector (free slots compute harmless garbage —
         rows are independent)."""
-        logits, self.ks, self.vs = self._step(
-            self.params, self.tok, self.pos, self.ks, self.vs)
+        logits, self.state = self._step(
+            self.params, self.tok, self.pos, self.state)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self.tok = nxt
         # Clamp so an idle slot parked at the cap can never scatter out

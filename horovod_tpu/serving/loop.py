@@ -349,6 +349,7 @@ class ServingLoop:
                                      "max_new": max_new,
                                      "remaining": max_new}
                 self._emit(slot, first, engine, rank0)
+            _tmx.inc_counter("hvd_serve_prefill_tokens_total", len(prompt))
         if not self._slots:
             return
         with _trace.span("serve.decode", slots=len(self._slots)):

@@ -186,6 +186,15 @@ KNOWN_METRICS: Dict[str, dict] = {
         "Wall time of one admission's prefill: dispatch, the cache "
         "install and the first token's readback (a serve.prefill "
         "span).", *_SECONDS),
+    "hvd_serve_prefill_tokens_total": _counter(
+        "Prompt tokens prefilled (added where a serve.prefill span "
+        "closes); with hvd_serve_prefill_seconds, the time a prompt "
+        "token costs."),
+    "hvd_serve_state_bytes": _gauge(
+        "Bytes of slot state the decode engine holds, by kind: kv "
+        "(position-indexed keys and values) and recurrent (fixed-size "
+        "state-space and convolution state); set when the engine is "
+        "built.", ("kind",)),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one gang decode step (prefills + batched step + "
         "token-agreement allreduce).", *_SECONDS),

@@ -48,35 +48,39 @@ def probe_bare_init():
 
 
 def serve_cache_programs(cfg, slots, min_elems, sharding=None):
-    """The two programs that write the serving slot cache (the decode step
-    and the install that ends a prefill), compiled from shapes alone for
-    the default device or for ``sharding``'s: what each produces of
-    ``min_elems`` elements or more (:func:`big_ops`), its temporaries and
-    its aliased bytes."""
+    """The two programs that write the serving slots' state (the decode
+    step and the install that ends a prefill) as ``DecodeEngine`` builds
+    them for ``cfg``'s model, compiled from shapes alone for the default
+    device or for ``sharding``'s: what each produces of ``min_elems``
+    elements or more (:func:`big_ops`), its temporaries and its aliased
+    bytes."""
     from functools import partial
 
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.models import jamba, transformer as tfm
     from horovod_tpu.serving import decode
 
     def spec(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    params = jax.tree.map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(lambda k: tfm.init(k, cfg), jax.random.PRNGKey(0)))
-    lane = (cfg.n_layers, 1, cfg.max_seq_len, cfg.n_heads, cfg.head_dim)
-    cache = spec((lane[0], slots) + lane[2:], cfg.compute_dtype)
-    lane = spec(lane, cfg.compute_dtype)
+    def specs(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    init = jamba.init if isinstance(cfg, jamba.JambaConfig) else tfm.init
+    params = specs(jax.eval_shape(lambda k: init(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    model = decode.slot_model(cfg, cfg.max_seq_len)
+    state = specs(jax.eval_shape(lambda: model.init_state(slots)))
+    request = specs(jax.eval_shape(lambda: model.init_state(1)))
     lowered = {
-        "step": jax.jit(partial(tfm.decode_step, cfg=cfg),
-                        donate_argnums=(3, 4)).lower(
-            params, spec((slots,)), spec((slots,)), cache, cache),
-        "install": jax.jit(decode._install, donate_argnums=(0, 1)).lower(
-            cache, cache, spec((slots,)), spec((slots,)), spec(()),
-            spec((cfg.vocab_size,), jnp.float32), lane, lane, spec(()))}
+        "step": jax.jit(model.step, donate_argnums=(3,)).lower(
+            params, spec((slots,)), spec((slots,)), state),
+        "install": jax.jit(partial(decode.install, model),
+                           donate_argnums=(0,)).lower(
+            state, spec((slots,)), spec((slots,)), spec(()),
+            spec((cfg.vocab_size,), jnp.float32), request, spec(()))}
     out = {}
     for name, program in lowered.items():
         compiled = program.compile()
@@ -94,13 +98,24 @@ SERVE_CACHE = dict(slots=32, vocab_size=1024, d_model=2048, n_layers=2,
                    n_heads=16, d_ff=512, max_seq_len=1536)
 
 
+# The new cell's state shapes (64 slots x 1536; d_inner 5120, 16 states,
+# one key/value head of 128) with two short periods of layers and a
+# narrow feed-forward and vocabulary, so that the probe compiles in
+# seconds.
+SERVE_STATE = dict(slots=64, vocab_size=1024, hidden_size=2560,
+                   intermediate_size=512, num_hidden_layers=8,
+                   attn_layer_period=4, attn_layer_offset=1,
+                   max_seq_len=1536)
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
     those instructions (what the profiler's ``XLA Ops`` events, and the
     benchmark's per-kernel metrics, tell the kernels apart by); and what
-    the serving cache's two programs produce there
-    (:func:`serve_cache_programs`).  One process for everything compiled
+    the two programs that write the serving slots' state produce there
+    (:func:`serve_cache_programs`), for the dense decoder's cache and for
+    models/jamba.py's two kinds of state.  One process for everything compiled
     for the chip (libtpu's lockfile); the compiles run in threads, XLA
     works outside the interpreter lock."""
     from concurrent.futures import ThreadPoolExecutor
@@ -110,6 +125,7 @@ def probe_lower_for_tpu(meshes_json):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from horovod_tpu.models import jamba
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
@@ -137,15 +153,24 @@ def probe_lower_for_tpu(meshes_json):
     sizes = dict(SERVE_CACHE)
     slots = sizes.pop("slots")
     cfg = tfm.TransformerConfig(**sizes)
-    with ThreadPoolExecutor(len(meshes) + 1) as pool:
+    state_sizes = dict(SERVE_STATE)
+    state_slots = state_sizes.pop("slots")
+    jcfg = jamba.JambaConfig(**state_sizes)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with ThreadPoolExecutor(len(meshes) + 2) as pool:
         serve_cache = pool.submit(
             serve_cache_programs, cfg, slots,
             slots * cfg.max_seq_len * cfg.d_model,      # one layer's lane
-            SingleDeviceSharding(topo.devices[0]))
+            one_chip)
+        serve_state = pool.submit(
+            serve_cache_programs, jcfg, state_slots,
+            # one layer's recurrent state: [slots, d_state, d_inner]
+            state_slots * jcfg.mamba_d_state * jcfg.d_inner, one_chip)
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
         "serve_cache": serve_cache.result(),
+        "serve_state": serve_state.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
 

@@ -192,3 +192,33 @@ def test_serving_cache_programs_update_in_place_on_the_chip(
     assert [op for _, op in got["big_ops"]] == [update, update], got
     assert got["temp_bytes"] < lane_bytes, got
     assert got["alias_bytes"] == 2 * c["n_layers"] * lane_bytes, got
+
+
+@pytest.mark.parametrize("program,updates", [
+    ("step", {"fusion:dynamic-update-slice", "fusion:scatter"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_serving_state_programs_update_in_place_on_the_chip(
+        probes, program, updates):
+    """models/jamba.py's two kinds of slot state, compiled for ``v5e`` at
+    the benchmark cell's state shapes: the step and the install produce
+    nothing of one layer's recurrent state's size (nor a weight matrix cut
+    out of its stack) besides the in-place updates of the state they were
+    given (an update-slice a run of Mamba layers, a row scatter a cache an
+    attention layer) and the compiler's own asynchronous moves of weights
+    into fast memory (``copy-start``, ``slice-start``, the buffers it
+    allocates for them); all four state arrays are aliased from input to
+    output, unpadded, and the temporaries stay under one layer's state."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_state"][program]
+    c = chip_probes.SERVE_STATE
+    d_inner, n_mamba, n_attn = 2 * c["hidden_size"], 6, 2
+    layer_bytes = 4 * c["slots"] * 16 * d_inner
+    held = (n_mamba * (layer_bytes + 2 * 3 * c["slots"] * d_inner)
+            + 2 * n_attn * 2 * c["slots"] * c["max_seq_len"] * 128)
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done",
+                "custom-call"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    assert got["temp_bytes"] < layer_bytes, got
+    assert got["alias_bytes"] == held, got

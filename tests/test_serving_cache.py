@@ -1,7 +1,7 @@
 """The serving slot cache is one resident pair of buffers.
 
-``DecodeEngine`` keeps K and V as ``[L, max_batch, cache_len, H, HD]``.
-Two programs write them: the decode step (B new rows a layer) and the
+``DecodeEngine`` keeps the dense decoder's K and V (``engine.state["kv"]``)
+as ``[L, max_batch, cache_len, H, HD]``.  Two programs write them: the decode step (B new rows a layer) and the
 install that ends a prefill (one slot's lane).  Both take the caches
 donated and update them in place.  What is pinned here, on the CPU:
 
@@ -56,13 +56,14 @@ def test_compiled_program_updates_the_donated_caches_in_place(
 def test_prefill_and_step_donate_the_caches_they_were_given(model):
     cfg, params = model
     engine = DecodeEngine(params, cfg, max_batch=B, cache_len=S)
-    before = (engine.ks, engine.vs)
+    before = engine.state["kv"]
     engine.prefill(1, [3, 14, 15])
     assert all(a.is_deleted() for a in before)
-    before = (engine.ks, engine.vs)
+    before = engine.state["kv"]
     engine.step()
     assert all(a.is_deleted() for a in before)
-    assert engine.ks.shape == engine.vs.shape == (L, B, S, H, HD)
+    ks, vs = engine.state["kv"]
+    assert ks.shape == vs.shape == (L, B, S, H, HD)
     assert int(engine.pos[1]) == 4
 
 
@@ -74,13 +75,13 @@ def test_mesh_keeps_the_cache_sharding_through_both_programs(model):
     want = sharding_for(mesh, tfm.KV_CACHE_SPEC)
     plain = DecodeEngine(params, cfg, max_batch=B, cache_len=S)
     sharded = DecodeEngine(params, cfg, max_batch=B, cache_len=S, mesh=mesh)
-    assert sharded.ks.sharding.is_equivalent_to(want, 5)
+    assert sharded.state["kv"][0].sharding.is_equivalent_to(want, 5)
 
     def tokens(engine):
         first = engine.prefill(2, [5, 14, 15, 9])
         return [first] + [int(engine.step()[2]) for _ in range(3)]
 
     assert tokens(sharded) == tokens(plain)
-    for cache in (sharded.ks, sharded.vs):
+    for cache in sharded.state["kv"]:
         assert cache.sharding.is_equivalent_to(want, 5)
         assert len(cache.sharding.device_set) == 2
