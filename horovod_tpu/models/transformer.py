@@ -634,3 +634,27 @@ def prefill_request(params, prompt, cfg: TransformerConfig, cache_len: int):
     ready to be written into a serving batch's slot lane."""
     logits, ks, vs = _prefill(params, prompt[None], cfg, cache_len)
     return logits[0], ks, vs
+
+
+# Every leaf the forward casts to ``cfg.compute_dtype`` at its use: the
+# operands of the matmuls (the experts' alike) and the tied embedding.  The
+# norm gains and the router are used in float32 and are not among them.
+COMPUTE_DTYPE_LEAVES = frozenset(
+    {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
+
+
+def serving_params(params: Params, cfg: TransformerConfig) -> Params:
+    """``params`` as a serving engine holds them: each of
+    COMPUTE_DTYPE_LEAVES rounded to ``cfg.compute_dtype`` once, every other
+    leaf as given.  The rounding is the one the forward's cast at the use
+    makes, so decode_step and prefill_request return from this pytree, to
+    the bit, what they return from ``params``, and their cast is then a
+    no-op: inside decode_step's layer scan XLA otherwise hoists the casts
+    of ALL layers' float32 weights out of the loop and runs them, whole,
+    on every call.  A leaf already in the compute type comes back as the
+    same buffer, and a sharded leaf keeps its sharding (``astype``)."""
+    def held(path, leaf):
+        cast = path[-1].key in COMPUTE_DTYPE_LEAVES
+        return leaf.astype(cfg.compute_dtype) if cast else leaf
+
+    return jax.tree_util.tree_map_with_path(held, params)

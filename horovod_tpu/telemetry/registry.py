@@ -182,6 +182,12 @@ KNOWN_METRICS: Dict[str, dict] = {
     "hvd_serve_queue_wait_seconds": _hist(
         "Time a request waited for a decode slot: submit to its first "
         "admission (rank 0).", *_SECONDS),
+    "hvd_serve_param_bytes": _gauge(
+        "Bytes of the parameters the decode engine holds, by dtype: the "
+        "leaves the model's forward casts at their use are held in the "
+        "compute type (float32 weights of a bfloat16 model read bfloat16 "
+        "here, their norm gains float32); set when the engine is built.",
+        ("dtype",)),
     "hvd_serve_prefill_seconds": _hist(
         "Wall time of one admission's prefill: dispatch, the cache "
         "install and the first token's readback (a serve.prefill "

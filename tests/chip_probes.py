@@ -50,10 +50,11 @@ def probe_bare_init():
 def serve_cache_programs(cfg, slots, min_elems, sharding=None):
     """The two programs that write the serving slots' state (the decode
     step and the install that ends a prefill) as ``DecodeEngine`` builds
-    them for ``cfg``'s model, compiled from shapes alone for the default
-    device or for ``sharding``'s: what each produces of ``min_elems``
-    elements or more (:func:`big_ops`), its temporaries and its aliased
-    bytes."""
+    them for ``cfg``'s model from the weights its ``init`` makes, compiled
+    from shapes alone for the default device or for ``sharding``'s: what
+    each produces of ``min_elems`` elements or more (:func:`big_ops`), its
+    temporaries, its aliased bytes and what it converts to the compute
+    type (:func:`converts_to`)."""
     from functools import partial
 
     import jax
@@ -69,9 +70,9 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
         return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
 
     init = jamba.init if isinstance(cfg, jamba.JambaConfig) else tfm.init
-    params = specs(jax.eval_shape(lambda k: init(k, cfg),
-                                  jax.random.PRNGKey(0)))
     model = decode.slot_model(cfg, cfg.max_seq_len)
+    params = specs(jax.eval_shape(lambda k: model.held(init(k, cfg)),
+                                  jax.random.PRNGKey(0)))
     state = specs(jax.eval_shape(lambda: model.init_state(slots)))
     request = specs(jax.eval_shape(lambda: model.init_state(1)))
     lowered = {
@@ -85,7 +86,10 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
     for name, program in lowered.items():
         compiled = program.compile()
         mem = compiled.memory_analysis()
-        out[name] = {"big_ops": big_ops(compiled.as_text(), min_elems),
+        text = compiled.as_text()
+        out[name] = {"big_ops": big_ops(text, min_elems),
+                     "converts": converts_to(
+                         text, jnp.dtype(cfg.compute_dtype).name),
                      "temp_bytes": mem.temp_size_in_bytes,
                      "alias_bytes": mem.alias_size_in_bytes}
     return out
@@ -211,6 +215,48 @@ def big_ops(hlo_text, min_elems):
             found.append([name, "fusion:" + roots.get(calls, "?")
                           if op == "fusion" else op])
     return found
+
+
+# The leaves each model's forward casts to the compute type at their use
+# (models/transformer.py's COMPUTE_DTYPE_LEAVES, spelled out: the tests hold
+# the model to it; models/jamba.py upcasts a_log, dt_bias, d and its
+# convolution to float32 and is not in the list with them).
+DENSE_CAST_LEAVES = frozenset(
+    {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
+JAMBA_CAST_LEAVES = DENSE_CAST_LEAVES | {
+    "in_proj", "x_proj", "dt_proj", "out_proj"}
+
+_HLO_TYPES = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def converts_to(hlo_text, dtype):
+    """The dimensions of every array of ``dtype`` (a numpy name) that a
+    ``convert`` of a compiled program produces, inside fusions and out."""
+    return [[int(d) for d in dims.split(",") if d]
+            for dims in re.findall(
+                r"= %s\[([\d,]*)\]\S* convert\(" % _HLO_TYPES[dtype],
+                hlo_text)]
+
+
+def weight_dims(params, names):
+    """What a convert of a weight can look like: for every leaf of the
+    ``params`` pytree whose key is in ``names`` the sorted dimensions of
+    the whole leaf and, where it is a stack of matrices, of one layer's
+    slice of it, without the ones, as a set of tuples.  Compare with
+    ``dims_key`` of a convert."""
+    import jax
+
+    found = set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        if path[-1].key in names:
+            found.add(dims_key(leaf.shape))
+            if leaf.ndim > 2:
+                found.add(dims_key(leaf.shape[1:]))
+    return found
+
+
+def dims_key(dims):
+    return tuple(sorted(d for d in dims if d != 1))
 
 
 class Probes:

@@ -222,3 +222,31 @@ def test_serving_state_programs_update_in_place_on_the_chip(
     assert {op for _, op in got["big_ops"]} & updates, got
     assert got["temp_bytes"] < layer_bytes, got
     assert got["alias_bytes"] == held, got
+
+
+@pytest.mark.parametrize("probe", ["serve_cache", "serve_state"])
+def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
+    """The engine holds the weights in the compute type (the dense
+    decoder's float32 ones rounded once when it is built), so the decode
+    step compiled for ``v5e`` has no ``convert`` that produces a bfloat16
+    array of a weight's dimensions: given float32 weights, XLA hoists
+    those converts out of the layer loop and runs them on every turn."""
+    import jax
+
+    from horovod_tpu.models import jamba
+    from horovod_tpu.models import transformer as tfm
+
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    converts = json.loads(out.split("RESULT", 1)[1])[probe]["step"]["converts"]
+    sizes, model, config, cast = {
+        "serve_cache": (chip_probes.SERVE_CACHE, tfm, tfm.TransformerConfig,
+                        chip_probes.DENSE_CAST_LEAVES),
+        "serve_state": (chip_probes.SERVE_STATE, jamba, jamba.JambaConfig,
+                        chip_probes.JAMBA_CAST_LEAVES)}[probe]
+    cfg = config(**{k: v for k, v in sizes.items() if k != "slots"})
+    weights = chip_probes.weight_dims(
+        jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0)),
+        cast)
+    assert converts, "the step rounds its activations at least"
+    assert [d for d in converts if chip_probes.dims_key(d) in weights] == []

@@ -14,16 +14,36 @@ donated and update them in place.  What is pinned here, on the CPU:
   before are deleted (donated, not copied);
 * a mesh: the caches leave both programs sharded as ``KV_CACHE_SPEC`` says,
   and the tokens are those of the engine without a mesh.
+
+And the parameters an engine holds (``engine.params``): every leaf the
+model's forward casts to the compute type at its use is held in that type,
+rounded once when the engine is built, so that no program converts a
+weight on every call.  Pinned here:
+
+* the mathematics: ``decode_step`` and ``prefill_request`` return from the
+  held pytree, to the bit, what they return from the float32 one, on a
+  model whose norm gains bfloat16 cannot hold (a cast of every leaf moves
+  the logits);
+* the built engine, dense and ``models/jamba.py``'s: no float32 leaf where
+  the forward casts, nothing in the step as the engine hands it to the
+  compiler that converts to a weight's shape (``tests/test_chip_smoke.py``
+  pins the same on the step compiled for the chip), a leaf already in its
+  type held as the given buffer, ``hvd_serve_param_bytes{dtype}``;
+* a mesh: the held leaves keep the sharding of the given ones.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from chip_probes import serve_cache_programs
+from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
+                         dims_key, serve_cache_programs, weight_dims)
+from horovod_tpu.models import jamba
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
 from horovod_tpu.serving.decode import DecodeEngine
+from horovod_tpu.telemetry import registry as tmx
 
 L, B, S, H, HD, V = 6, 4, 256, 2, 16, 64
 LANE_ELEMS = B * S * H * HD
@@ -85,3 +105,159 @@ def test_mesh_keeps_the_cache_sharding_through_both_programs(model):
     for cache in sharded.state["kv"]:
         assert cache.sharding.is_equivalent_to(want, 5)
         assert len(cache.sharding.device_set) == 2
+
+
+# -- the parameters an engine holds ---------------------------------------------
+
+BF16 = jnp.dtype(jnp.bfloat16)
+F32 = jnp.dtype(jnp.float32)
+
+
+def _dense_bf16():
+    """Float32 weights of a bfloat16 model, as a trainer hands them over,
+    with seeded norm gains that bfloat16 cannot hold."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=V, d_model=H * HD, n_layers=L, n_heads=H, d_ff=64,
+        max_seq_len=S, remat=False)
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 3))
+
+    def gain(g):
+        return 1.0 + 0.1 * jax.random.normal(next(keys), g.shape, g.dtype)
+
+    params["ln_f"] = gain(params["ln_f"])
+    for name in ("ln1", "ln2"):
+        params["layers"][name] = gain(params["layers"][name])
+    assert cfg.compute_dtype == jnp.bfloat16
+    return cfg, params, DENSE_CAST_LEAVES
+
+
+def _jamba_bf16():
+    """Weights in the published bfloat16, as the benchmark's job hands
+    them over."""
+    cfg = jamba.JambaConfig(
+        vocab_size=V, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=1,
+        attn_layer_period=4, attn_layer_offset=1, mamba_d_state=4,
+        mamba_expand=2, mamba_dt_rank=6, max_seq_len=S)
+    assert cfg.compute_dtype == cfg.param_dtype == jnp.bfloat16
+    return cfg, jamba.init(jax.random.PRNGKey(0), cfg), JAMBA_CAST_LEAVES
+
+
+@pytest.fixture(scope="module", params=[_dense_bf16, _jamba_bf16],
+                ids=["dense", "jamba"])
+def built(request):
+    """(cfg, the given parameters, the names of the leaves the forward
+    casts, the engine built from them, the gauges it set)."""
+    cfg, given, cast = request.param()
+    tmx.configure(True)
+    try:
+        engine = DecodeEngine(given, cfg, max_batch=B, cache_len=S)
+        gauges = tmx.snapshot()["gauges"]
+    finally:
+        tmx.configure(False)
+    return cfg, given, cast, engine, gauges
+
+
+def _named(params):
+    return [(path[-1].key, leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(params)]
+
+
+def test_engine_holds_in_the_compute_type_what_the_forward_casts(built):
+    cfg, given, cast, engine, _ = built
+    assert jax.tree.structure(engine.params) == jax.tree.structure(given)
+    for (name, held), (_, was) in zip(_named(engine.params), _named(given)):
+        if name in cast:
+            assert held.dtype == cfg.compute_dtype, name
+            np.testing.assert_array_equal(
+                held, was.astype(cfg.compute_dtype), err_msg=name)
+        else:
+            assert held is was, name
+    assert {name for name, _ in _named(given)} >= cast
+
+
+def test_step_the_engine_compiles_converts_no_weight(built):
+    """Nothing in the step, as the engine hands it to the compiler,
+    produces an array of the compute type with a weight's dimensions (the
+    whole stacked leaf's, one layer's slice's, or a transpose of either)
+    by a convert; the float32 pytree's step has one for every use."""
+    cfg, given, cast, engine, _ = built
+    weights = weight_dims(given, cast)
+
+    def weight_converts(params):
+        text = engine._step.lower(
+            params, engine.tok, engine.pos, engine.state
+        ).compiler_ir(dialect="hlo").as_hlo_text()
+        return [dims for dims in converts_to(text, BF16.name)
+                if dims_key(dims) in weights]
+
+    assert weight_converts(engine.params) == []
+    as_float32 = jax.tree.map(lambda a: a.astype(F32), given)
+    assert len(weight_converts(as_float32)) >= len(cast)
+
+
+def test_leaf_already_in_its_type_is_held_as_the_given_buffer(built):
+    cfg, _, _, engine, _ = built
+    again = DecodeEngine(engine.params, cfg, max_batch=1, cache_len=S)
+    for held, was in zip(jax.tree.leaves(again.params),
+                         jax.tree.leaves(engine.params)):
+        assert held is was
+
+
+def test_param_bytes_gauge_reads_what_the_engine_holds_by_dtype(built):
+    cfg, given, cast, _, gauges = built
+    want = {}
+    for name, leaf in _named(given):
+        dtype = BF16 if name in cast else leaf.dtype
+        want[dtype.name] = want.get(dtype.name, 0) + leaf.size * dtype.itemsize
+    got = {series: v for series, v in gauges.items()
+           if series.startswith("hvd_serve_param_bytes")}
+    assert got == {'hvd_serve_param_bytes{dtype="%s"}' % name: float(n)
+                   for name, n in want.items()}
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_request"])
+def test_held_parameters_give_the_float32_results_to_the_bit(program):
+    cfg, given, _ = _dense_bf16()
+    held = tfm.serving_params(given, cfg)
+    gains = [given["ln_f"], given["layers"]["ln1"], given["layers"]["ln2"]]
+    assert all((g.astype(BF16).astype(F32) != g).any() for g in gains)
+    every_leaf = jax.tree.map(lambda a: a.astype(BF16), given)
+    if program == "decode_step":
+        def run(params):
+            zeros = jnp.zeros((L, B, S, H, HD), cfg.compute_dtype)
+            tok, pos = jnp.asarray([5, 9, 0, 33]), jnp.asarray([0, 3, 0, 7])
+            out = None
+            for i in range(3):   # the later steps read what the first wrote
+                out = tfm.decode_step(params, tok + i, pos + i,
+                                      *(out[1:] if out else (zeros, zeros)),
+                                      cfg)
+            return out
+    else:
+        def run(params):
+            prompt = jnp.asarray([3, 14, 15, 9, 26, 5], jnp.int32)
+            return tfm.prefill_request(params, prompt, cfg, S)
+    want, got, blanket = (jax.jit(run)(p) for p in (given, held, every_leaf))
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    assert not np.array_equal(blanket[0], want[0])
+
+
+def test_mesh_keeps_the_sharding_of_the_parameters_it_casts():
+    cfg, given, cast = _dense_bf16()
+    mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
+    shardings = jax.tree.map(
+        lambda spec: sharding_for(mesh, spec), tfm.param_specs(cfg),
+        is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    sharded = jax.device_put(given, shardings)
+    engine = DecodeEngine(sharded, cfg, max_batch=B, cache_len=S, mesh=mesh)
+    for (name, held), (_, was) in zip(_named(engine.params),
+                                      _named(sharded)):
+        assert held.sharding.is_equivalent_to(was.sharding, was.ndim), name
+        assert held.dtype == (BF16 if name in cast else F32), name
+    assert len(engine.params["layers"]["wq"].sharding.device_set) == 2
+    plain = DecodeEngine(given, cfg, max_batch=B, cache_len=S)
+    assert engine.prefill(2, [5, 14, 15, 9]) == plain.prefill(2, [5, 14, 15, 9])
+    np.testing.assert_array_equal(engine.step()[2], plain.step()[2])
