@@ -439,3 +439,14 @@ def decode_step(params: Params, tok, pos, state: State, cfg: JambaConfig):
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
     x, state = _stack(params, x, cfg, state, pos)
     return _logits(params, x)[:, 0], state
+
+
+# The state's sharding: none is written (recurrent state under tp), so
+# serving/decode.py refuses a mesh.
+STATE_SPEC = None
+
+
+def serving_params(params: Params, cfg: JambaConfig) -> Params:
+    """``params`` as a serving engine holds them: as given.  The weights
+    come in ``param_dtype``, which is for the caller to choose."""
+    return params

@@ -19,13 +19,24 @@ experts (ep).  This model is built so that every one of those axes is a
   replaces that with neighbor ``ppermute`` exchanges when activated.
 * bf16 compute, fp32 params/norms, RoPE positions, pre-RMSNorm blocks,
   causal masking via static ``lax`` ops only — no dynamic shapes anywhere.
+* One attention (``_attention``: projections, rotation, output; with a
+  cache or without), one rotation (``_rope``), one softmax core and one
+  layer body (``_layer``).  Training (``apply``), the prefill and the
+  decode step are that layer under three loops, so a change to what
+  attention reads or how heads are grouped is written once.
+* Cached decode is a seam, not a second model: ``init_state``,
+  ``prefill_request``, ``install_request``, ``decode_step``,
+  ``STATE_SPEC`` and ``serving_params``, the names and signatures
+  ``models/jamba.py`` gives its own.  ``serving/decode.py`` builds its
+  engine from them; ``generate()`` is ``decode_step`` with one position
+  for all rows, and the serving tests' oracle.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -172,19 +183,18 @@ def _rmsnorm(x, g):
 
 def _rope(x, theta: float, pos=None):
     """Rotary embedding over head_dim pairs; x: [B, S, H, HD].
-    ``pos``: optional [S] absolute positions (decode steps rotate a
-    single new token at its true position); default ``arange(S)``."""
+    ``pos``: the absolute positions, [S] (shared by the rows) or [B, S]
+    (a row's own: a serving slot rotates its one new token at its own
+    offset); default ``arange(S)``."""
     B, S, H, HD = x.shape
     half = HD // 2
     freqs = jnp.exp(
         -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
     if pos is None:
         pos = jnp.arange(S, dtype=jnp.float32)
-    else:
-        pos = pos.astype(jnp.float32)
-    ang = pos[:, None] * freqs[None, :]          # [S, half]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
+    ang = pos.astype(jnp.float32)[..., None] * freqs     # [(B,) S, half]
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(
@@ -205,64 +215,95 @@ def warn_flash_runs_dense(axis: str, size: int, where: str) -> None:
         where, axis, size)
 
 
-def _attention(x, lp, cfg: TransformerConfig, mesh=None):
+def _softmax_attention(q, k, v, valid, cfg: TransformerConfig):
+    """Dense attention: q [B, S, H, HD] against k, v [B, T, H, HD] where
+    ``valid`` (broadcastable to [B, H, S, T]) says so.  Scores and softmax
+    in float32, probabilities and context in the compute type."""
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    logits = jnp.einsum("bshk,bthk->bhst", q, k).astype(jnp.float32) * scale
+    logits = jnp.where(valid, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(cfg.compute_dtype)
+    return jnp.einsum("bhst,bthk->bshk", probs, v)
+
+
+def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
+    """Causal self-attention, the model's only one.  x: [B, S, D].
+
+    ``cache`` None: the S positions (0 to S - 1) attend among themselves
+    by ``cfg.attn_impl``; returns (out, (k, v)) with the rotated k, v
+    [B, S, H, HD] for whoever keeps them.
+    ``cache`` = (ks, vs, layer, pos), stacked caches [L, B, Smax, H, HD]
+    and the positions [B] of THIS token in each row (S = 1): writes the B
+    new rows at [layer, b, pos[b]] and reads layer ``layer``'s lane where
+    it lies, up to ``pos``.  Nothing of the cache's or a lane's size is
+    produced besides the caches themselves, which the caller carries (and
+    donates), so XLA updates them in place.  Returns (out, (ks, vs)).
+    Rows never mix, so a row's output depends on its own lane alone."""
     B, S, D = x.shape
     dtype = cfg.compute_dtype
+    own = None if cache is None else cache[3][:, None]   # [B, 1] positions
     q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
     kk = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
-    q = _rope(q, cfg.rope_theta)
-    kk = _rope(kk, cfg.rope_theta)
+    q = _rope(q, cfg.rope_theta, own)
+    kk = _rope(kk, cfg.rope_theta, own)
+    if cache is None:
+        kept = (kk, v)
+        if cfg.attn_impl not in ("dense", "ring", "ulysses", "flash"):
+            raise ValueError(
+                f"attn_impl must be dense/ring/ulysses/flash, "
+                f"got {cfg.attn_impl!r}")
+        use_sp = (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
+                  and mesh.shape.get("sp", 1) > 1)
+        use_flash = cfg.attn_impl == "flash"
+        if use_flash and mesh is not None:
+            for ax in ("tp", "sp"):
+                if mesh.shape.get(ax, 1) > 1:
+                    warn_flash_runs_dense(ax, mesh.shape[ax], "the model")
+                    use_flash = False
+        if use_flash:
+            from horovod_tpu.ops.pallas_attention import flash_attention
 
-    if cfg.attn_impl not in ("dense", "ring", "ulysses", "flash"):
-        raise ValueError(
-            f"attn_impl must be dense/ring/ulysses/flash, "
-            f"got {cfg.attn_impl!r}")
-    use_sp = (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
-              and mesh.shape.get("sp", 1) > 1)
-    use_flash = cfg.attn_impl == "flash"
-    if use_flash and mesh is not None:
-        for ax in ("tp", "sp"):
-            if mesh.shape.get(ax, 1) > 1:
-                warn_flash_runs_dense(ax, mesh.shape[ax], "the model")
-                use_flash = False
-    if use_flash:
-        from horovod_tpu.ops.pallas_attention import flash_attention
+            if mesh is not None and mesh.size > 1:
+                # A pallas_call has no GSPMD partitioning rule, and Mosaic
+                # refuses a kernel that any automatic mesh axis could split,
+                # so the kernel runs inside a shard_map that is manual over
+                # EVERY axis: the batch split the way the activations are
+                # (ACT_SPEC's batch axes), replicated over the rest
+                # (ep, dcn, ...; tp and sp are 1 here).
+                from horovod_tpu.parallel.mesh import filter_spec
+                from horovod_tpu.parallel.shard import shard_map
 
-        if mesh is not None and mesh.size > 1:
-            # A pallas_call has no GSPMD partitioning rule, and Mosaic
-            # refuses a kernel that any automatic mesh axis could split,
-            # so the kernel runs inside a shard_map that is manual over
-            # EVERY axis: the batch split the way the activations are
-            # (ACT_SPEC's batch axes), replicated over the rest
-            # (ep, dcn, ...; tp and sp are 1 here).
-            from horovod_tpu.parallel.mesh import filter_spec
-            from horovod_tpu.parallel.shard import shard_map
+                batch = filter_spec(P(ACT_SPEC[0]), mesh)
+                ctx = shard_map(
+                    lambda a, b, c: flash_attention(a, b, c, causal=True),
+                    mesh, in_specs=(batch, batch, batch),
+                    out_specs=batch)(q, kk, v)
+            else:
+                ctx = flash_attention(q, kk, v, causal=True)
+        elif use_sp:
+            # Sequence-parallel attention: K/V never gather; blocks rotate
+            # the sp ring (ring) or heads exchange via all-to-all (ulysses).
+            from horovod_tpu.parallel import ring_attention as ra
 
-            batch = filter_spec(P(ACT_SPEC[0]), mesh)
-            ctx = shard_map(
-                lambda a, b, c: flash_attention(a, b, c, causal=True),
-                mesh, in_specs=(batch, batch, batch),
-                out_specs=batch)(q, kk, v)
+            ctx = ra.make_sharded_attention(
+                mesh, impl=cfg.attn_impl, axis="sp", causal=True,
+                head_axis="tp")(q, kk, v)
         else:
-            ctx = flash_attention(q, kk, v, causal=True)
-    elif use_sp:
-        # Sequence-parallel attention: K/V never gather; blocks rotate the
-        # sp ring (ring) or heads exchange via all-to-all (ulysses).
-        from horovod_tpu.parallel import ring_attention as ra
-
-        ctx = ra.make_sharded_attention(
-            mesh, impl=cfg.attn_impl, axis="sp", causal=True,
-            head_axis="tp")(q, kk, v)
+            tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
+            ctx = _softmax_attention(q, kk, v, tri[None, None], cfg)
     else:
-        scale = 1.0 / math.sqrt(cfg.head_dim)
-        logits = jnp.einsum("bshk,bthk->bhst", q, kk).astype(jnp.float32)
-        logits *= scale
-        mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
-        logits = jnp.where(mask[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-        ctx = jnp.einsum("bhst,bthk->bshk", probs, v)
-    return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype))
+        ks, vs, layer, pos = cache
+        rows = jnp.arange(B)
+        ks = ks.at[layer, rows, pos].set(kk[:, 0])
+        vs = vs.at[layer, rows, pos].set(v[:, 0])
+        k_cache = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
+        v_cache = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
+        valid = jnp.arange(k_cache.shape[1])[None, :] <= pos[:, None]
+        ctx = _softmax_attention(q, k_cache, v_cache,
+                                 valid[:, None, None, :], cfg)
+        kept = (ks, vs)
+    return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
 
 
 def _dense_ffn(x, lp, dtype):
@@ -313,8 +354,12 @@ def _moe_ffn(x, lp, cfg: TransformerConfig):
     return y.reshape(B, S, D), aux
 
 
-def _layer(x, lp, cfg: TransformerConfig, mesh):
-    y = _attention(_rmsnorm(x, lp["ln1"]), lp, cfg, mesh)
+def _layer(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
+    """The layer, the model's only one: norm, attention, residual, norm,
+    feed-forward, residual.  ``cache`` as :func:`_attention` takes it.
+    Returns (x, the experts' auxiliary loss, the keys and values
+    :func:`_attention` returns)."""
+    y, kept = _attention(_rmsnorm(x, lp["ln1"]), lp, cfg, mesh, cache)
     x = _constrain(x + y, ACT_SPEC, mesh)
     h = _rmsnorm(x, lp["ln2"])
     if cfg.n_experts:
@@ -322,7 +367,7 @@ def _layer(x, lp, cfg: TransformerConfig, mesh):
     else:
         y, aux = _dense_ffn(h, lp, cfg.compute_dtype), 0.0
     x = _constrain(x + y, ACT_SPEC, mesh)
-    return x, aux
+    return x, aux, kept
 
 
 def apply(params: Params, tokens, cfg: TransformerConfig,
@@ -341,7 +386,7 @@ def apply(params: Params, tokens, cfg: TransformerConfig,
 
     def body(carry, lp):
         h, aux_sum = carry
-        h, aux = layer_fn(h, lp, cfg, mesh)
+        h, aux, _ = layer_fn(h, lp, cfg, mesh)
         return (h, aux_sum + aux), None
 
     (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
@@ -377,80 +422,103 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
 
 
 # ---------------------------------------------------------------------------
-# Autoregressive generation (KV cache)
+# Cached decode: generate() and the serving seam (horovod_tpu.serving)
 # ---------------------------------------------------------------------------
 #
 # The reference is a training framework with no inference path; a complete
 # model family needs one.  Decode is the classic two-phase shape: one
-# prefill pass caches every layer's rotated K/V for the prompt, then a
-# lax.scan emits one token per step, attending a single query against the
-# cache — O(S) per token instead of O(S^2) recompute.  Dense single-host
-# math (generation batches are small; the parallel axes exist for
-# training).
+# prefill pass caches every layer's rotated K/V for the prompt, then one
+# token a step attends a single query against the cache: O(S) per token
+# instead of O(S^2) recompute.  Dense single-host math (the parallel axes
+# exist for training; under a mesh the cache shards its heads over tp).
+#
+# The cache is a STATE, ``{"kv": (ks, vs)}``, each [L, B, Smax, H, HD],
+# and the four functions that make, fill, install and step it are the
+# seam serving/decode.py builds a DecodeEngine from, under the names and
+# signatures models/jamba.py gives its own.  A serving batch is ragged
+# (each slot joined at a different step and sits at its own offset), so
+# decode_step takes a position per row; generate() is the same step with
+# one position for all rows.  Rows never mix, which is what keeps a
+# continuously batched decode bit-identical to a request decoded alone.
 
 
-def _attention_cached(x, lp, cfg, k_cache, v_cache, pos):
-    """One token's attention against the cache.
+KV_CACHE_SPEC = P(None, None, None, "tp", None)  # [L, B, Smax, H, HD]
+STATE_SPEC = {"kv": (KV_CACHE_SPEC, KV_CACHE_SPEC)}
 
-    x: [B, 1, D]; k/v_cache: [B, Smax, H, HD] (valid through ``pos``);
-    ``pos``: scalar index of THIS token.  Returns (out [B, 1, D],
-    updated caches)."""
-    dtype = cfg.compute_dtype
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
-    p = jnp.full((1,), pos)
-    q = _rope(q, cfg.rope_theta, pos=p)
-    k = _rope(k, cfg.rope_theta, pos=p)
-    k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-    v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bshk,bthk->bhst", q, k_cache
-                        ).astype(jnp.float32) * scale
-    Smax = k_cache.shape[1]
-    valid = jnp.arange(Smax) <= pos
-    logits = jnp.where(valid[None, None, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    ctx = jnp.einsum("bhst,bthk->bshk", probs, v_cache)
-    return (jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)),
-            k_cache, v_cache)
+
+def _refuse_experts(cfg: TransformerConfig):
+    """Whoever makes a state says so first: dense-FFN configs only
+    (``n_experts=0``), routing under a one-token capacity is a different
+    decode design."""
+    if cfg.n_experts:
+        raise NotImplementedError(
+            "cached decode (generate() and serving) supports dense-FFN "
+            "configs; MoE decode needs per-step routing with capacity 1")
+
+
+def init_state(cfg: TransformerConfig, max_batch: int, cache_len: int):
+    """Zeros for ``max_batch`` slots."""
+    _refuse_experts(cfg)
+    lane = (cfg.n_layers, max_batch, cache_len, cfg.n_heads, cfg.head_dim)
+    return {"kv": (jnp.zeros(lane, cfg.compute_dtype),
+                   jnp.zeros(lane, cfg.compute_dtype))}
 
 
 def _prefill(params, tokens, cfg, Smax):
-    """Forward over the prompt, returning next-token logits for the last
-    position and per-layer K/V caches [L, B, Smax, H, HD]."""
-    dtype = cfg.compute_dtype
-    B, S = tokens.shape
-    x = params["embed"].astype(dtype)[tokens]
-
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    """Forward over the prompts [B, S]: next-token logits [B, V] of the
+    last position and the rows' state, keys and values at [0, S) and zero
+    past them.  Attends densely whatever ``cfg.attn_impl`` says."""
+    _refuse_experts(cfg)
+    S = tokens.shape[1]
+    cfg = replace(cfg, attn_impl="dense")
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    pad = [(0, 0), (0, Smax - S), (0, 0), (0, 0)]
 
     def body(h, lp):
-        # Per-layer math of _layer with the projections computed ONCE,
-        # attention inlined densely, and the rotated K/V captured for
-        # the cache (so decode and training can't desynchronize on the
-        # projection/RoPE recipe).
-        y = _rmsnorm(h, lp["ln1"])
-        q = jnp.einsum("bsd,dhk->bshk", y, lp["wq"].astype(dtype))
-        k = jnp.einsum("bsd,dhk->bshk", y, lp["wk"].astype(dtype))
-        v = jnp.einsum("bsd,dhk->bshk", y, lp["wv"].astype(dtype))
-        q = _rope(q, cfg.rope_theta)
-        k = _rope(k, cfg.rope_theta)
-        logits = jnp.einsum("bshk,bthk->bhst", q, k
-                            ).astype(jnp.float32) * scale
-        logits = jnp.where(tri[None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-        ctx = jnp.einsum("bhst,bthk->bshk", probs, v)
-        h = h + jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype))
-        h = h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype)
-        pad = [(0, 0), (0, Smax - S), (0, 0), (0, 0)]
+        h, _, (k, v) = _layer(h, lp, cfg)
         return h, (jnp.pad(k, pad), jnp.pad(v, pad))
 
-    x, (ks, vs) = lax.scan(body, x, params["layers"])
+    x, kv = lax.scan(body, x, params["layers"])
     x = _rmsnorm(x, params["ln_f"])
-    logits = vocab_projection(x[:, -1:], params["embed"])[:, 0]
-    return logits, ks, vs
+    return vocab_projection(x[:, -1:], params["embed"])[:, 0], {"kv": kv}
+
+
+def prefill_request(params, prompt, cfg: TransformerConfig, cache_len: int):
+    """Prefill ONE request.  ``prompt``: [S0] int32.  Returns (next-token
+    logits [V] f32, the request's state: ``init_state`` for one slot, its
+    lane [L, 1, cache_len, H, HD] filled through the prompt)."""
+    logits, state = _prefill(params, prompt[None], cfg, cache_len)
+    return logits[0], state
+
+
+def install_request(state, slot, request):
+    """Write a request's lane over slot ``slot``'s, all of it.  ``state``
+    donated, the writes are in place."""
+    (ks, vs), (k1, v1) = state["kv"], request["kv"]
+    at = (0, slot, 0, 0, 0)
+    return {"kv": (lax.dynamic_update_slice(ks, k1, at),
+                   lax.dynamic_update_slice(vs, v1, at))}
+
+
+def decode_step(params, tok, pos, state, cfg: TransformerConfig):
+    """One step of every row: embed ``tok`` [B], attend each row at its
+    own ``pos`` [B], return (next-token logits [B, V] f32, the state
+    updated in place when donated).  The caches are the layer loop's
+    CARRY, indexed by layer, not its ``xs``/``ys``: a scan that slices a
+    lane out and stacks it back copies the whole cache every step."""
+    x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
+
+    def layer(carry, layer_in):
+        h, kv = carry
+        lp, l = layer_in
+        h, _, kv = _layer(h, lp, cfg, cache=(*kv, l, pos))
+        return (h, kv), None
+
+    (x, kv), _ = lax.scan(
+        layer, (x, state["kv"]),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    x = _rmsnorm(x, params["ln_f"])
+    return vocab_projection(x, params["embed"])[:, 0], {"kv": kv}
 
 
 def generate(params, prompt, cfg: TransformerConfig, *,
@@ -466,13 +534,8 @@ def generate(params, prompt, cfg: TransformerConfig, *,
     compare against a fixed-length serving cache (serving/decode.py)
     pass the serving length here to keep the comparison bit-exact.
 
-    Dense-FFN configs only (``n_experts=0``) — MoE routing under a
-    one-token capacity is a different decode design.
+    Dense-FFN configs only (:func:`_refuse_experts`).
     """
-    if cfg.n_experts:
-        raise NotImplementedError(
-            "generate() supports dense-FFN configs; MoE decode needs "
-            "per-step routing with capacity 1")
     if temperature > 0.0 and rng is None:
         raise ValueError("temperature sampling needs rng")
     if max_new_tokens < 1:
@@ -490,8 +553,7 @@ def generate(params, prompt, cfg: TransformerConfig, *,
                 f"cache_len ({cache_len}) is shorter than prompt + new "
                 f"tokens ({Smax})")
         Smax = cache_len
-    dtype = cfg.compute_dtype
-    logits0, ks, vs = _prefill(params, prompt, cfg, Smax)
+    logits0, state = _prefill(params, prompt, cfg, Smax)
     if rng is None:
         rng = jax.random.PRNGKey(0)
 
@@ -502,138 +564,20 @@ def generate(params, prompt, cfg: TransformerConfig, *,
         return jnp.argmax(logits, axis=-1)
 
     def step(carry, key):
-        tok, pos, ks, vs = carry
-        x = params["embed"].astype(dtype)[tok[:, None]]
-
-        def layer(h, layer_in):
-            lp, k_c, v_c = layer_in
-            y = _rmsnorm(h, lp["ln1"])
-            attn, k_c, v_c = _attention_cached(y, lp, cfg, k_c, v_c, pos)
-            h = h + attn
-            h = h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype)
-            return h, (k_c, v_c)
-
-        x, (ks, vs) = lax.scan(layer, x, (params["layers"], ks, vs))
-        x = _rmsnorm(x, params["ln_f"])
-        logits = vocab_projection(x, params["embed"])[:, 0]
+        tok, pos, state = carry
+        logits, state = decode_step(params, tok, jnp.full((B,), pos),
+                                    state, cfg)
         nxt = sample(logits, key).astype(prompt.dtype)
-        return (nxt, pos + 1, ks, vs), nxt
+        return (nxt, pos + 1, state), nxt
 
     keys = jax.random.split(rng, max_new_tokens)
     first = sample(logits0, keys[0]).astype(prompt.dtype)
     if max_new_tokens == 1:
         return jnp.concatenate([prompt, first[:, None]], axis=1)
-    (_, _, _, _), rest = lax.scan(
-        step, (first, jnp.asarray(S0), ks, vs), keys[1:])
+    _, rest = lax.scan(step, (first, jnp.asarray(S0), state), keys[1:])
     out = jnp.concatenate(
         [prompt, first[:, None], rest.swapaxes(0, 1)], axis=1)
     return out
-
-
-# ---------------------------------------------------------------------------
-# Continuous-batching decode (horovod_tpu.serving)
-# ---------------------------------------------------------------------------
-#
-# generate() decodes one request at a time: every row of the batch shares a
-# single scalar position.  A serving batch is ragged — each slot joined at
-# a different step and sits at its own offset in the KV cache — so these
-# entry points carry a per-slot position VECTOR.  The per-row math is that
-# of _attention_cached exactly (same einsums, same mask construction, same
-# f32 softmax), which is what keeps a continuously batched decode
-# bit-identical to the single-request generate() oracle: rows never mix,
-# so a slot's output depends only on its own cache lane.
-
-
-KV_CACHE_SPEC = P(None, None, None, "tp", None)  # [L, B, Smax, H, HD]
-
-
-def _attention_cached_slots(x, lp, cfg, ks, vs, layer, pos):
-    """One token per slot against layer ``layer`` of the stacked caches,
-    at per-slot positions.
-
-    x: [B, 1, D]; ks/vs: [L, B, Smax, H, HD]; ``layer``: scalar int32;
-    ``pos``: [B] int32, the absolute position of THIS token in each slot.
-    Writes the B new rows at [layer, b, pos[b]] and reads layer
-    ``layer``'s lane where it lies: nothing of the cache's or a lane's
-    size is produced besides the caches themselves, which the caller
-    carries (and donates), so XLA updates them in place.  Returns
-    (out [B, 1, D], updated caches)."""
-    dtype = cfg.compute_dtype
-    B = x.shape[0]
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
-    k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
-    # _rope with a per-row angle: ang[b] = pos[b] * freqs — the scalar-pos
-    # rotation of _attention_cached applied row-wise.
-    half = cfg.head_dim // 2
-    freqs = jnp.exp(
-        -math.log(cfg.rope_theta)
-        * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]   # [B, half]
-    cos = jnp.cos(ang)[:, None, None, :]
-    sin = jnp.sin(ang)[:, None, None, :]
-
-    def rot(t):
-        t1, t2 = t[..., :half], t[..., half:]
-        tf1, tf2 = t1.astype(jnp.float32), t2.astype(jnp.float32)
-        return jnp.concatenate(
-            [tf1 * cos - tf2 * sin, tf2 * cos + tf1 * sin], axis=-1
-        ).astype(t.dtype)
-
-    q = rot(q)
-    k = rot(k)
-    rows = jnp.arange(B)
-    ks = ks.at[layer, rows, pos].set(k[:, 0])
-    vs = vs.at[layer, rows, pos].set(v[:, 0])
-    k_cache = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
-    v_cache = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    logits = jnp.einsum("bshk,bthk->bhst", q, k_cache
-                        ).astype(jnp.float32) * scale
-    Smax = k_cache.shape[1]
-    valid = jnp.arange(Smax)[None, :] <= pos[:, None]         # [B, Smax]
-    logits = jnp.where(valid[:, None, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    ctx = jnp.einsum("bhst,bthk->bshk", probs, v_cache)
-    return (jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)),
-            ks, vs)
-
-
-def decode_step(params, tok, pos, ks, vs, cfg: TransformerConfig):
-    """One continuous-batching step: embed ``tok`` [B], attend each slot
-    at its own ``pos`` [B], return (next-token logits [B, V] f32, updated
-    caches [L, B, Smax, H, HD]).  The layer body is generate()'s step
-    with _attention_cached swapped for the per-slot-position variant.
-    The caches are the layer loop's CARRY, indexed by layer, not its
-    ``xs``/``ys``: a scan that slices a lane out and stacks it back
-    copies the whole cache every step.  Dense-FFN configs only (same
-    contract as generate())."""
-    dtype = cfg.compute_dtype
-    x = params["embed"].astype(dtype)[tok[:, None]]
-
-    def layer(carry, layer_in):
-        h, ks, vs = carry
-        lp, l = layer_in
-        y = _rmsnorm(h, lp["ln1"])
-        attn, ks, vs = _attention_cached_slots(y, lp, cfg, ks, vs, l, pos)
-        h = h + attn
-        h = h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype)
-        return (h, ks, vs), None
-
-    (x, ks, vs), _ = lax.scan(
-        layer, (x, ks, vs),
-        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    x = _rmsnorm(x, params["ln_f"])
-    logits = vocab_projection(x, params["embed"])[:, 0]
-    return logits, ks, vs
-
-
-def prefill_request(params, prompt, cfg: TransformerConfig, cache_len: int):
-    """Prefill ONE request.  ``prompt``: [S0] int32.  Returns
-    (next-token logits [V] f32, per-layer K/V [L, 1, cache_len, H, HD])
-    ready to be written into a serving batch's slot lane."""
-    logits, ks, vs = _prefill(params, prompt[None], cfg, cache_len)
-    return logits[0], ks, vs
 
 
 # Every leaf the forward casts to ``cfg.compute_dtype`` at its use: the

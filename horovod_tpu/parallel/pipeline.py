@@ -152,7 +152,7 @@ def pipeline_apply(params, tokens, cfg: tfm.TransformerConfig, mesh,
         def stage_fn(h):
             def layer_body(carry, lp):
                 h, aux_sum = carry
-                h, aux = layer_fn(h, lp, cfg, None)
+                h, aux, _ = layer_fn(h, lp, cfg, None)
                 return (h, aux_sum + aux), None
 
             (h, aux), _ = lax.scan(

@@ -699,6 +699,12 @@ class PyEngine(_EngineBase):
                              if t.kind == "tcp"}
         self._fusion_buf = FusionBuffer()
 
+        # What the receiver threads fill: made before any of them starts
+        # (a frame can arrive before this constructor returns).
+        self._response_inbox: List[bytes] = []
+        self._response_lock = threading.Lock()
+        self._response_cv = threading.Condition(self._response_lock)
+
         # ctrl receiver threads
         if self.rank == 0:
             now = time.monotonic()
@@ -715,9 +721,6 @@ class PyEngine(_EngineBase):
             for r, s in self._tree_child_socks.items():
                 threading.Thread(target=self._tree_child_recv_loop,
                                  args=(r, s), daemon=True).start()
-        self._response_inbox: List[bytes] = []
-        self._response_lock = threading.Lock()
-        self._response_cv = threading.Condition(self._response_lock)
 
     def _ctrl_recv_loop(self, peer_rank: int, sock: socket.socket) -> None:
         try:

@@ -3,9 +3,11 @@
 One :class:`DecodeEngine` lives on every rank of the serving gang and
 holds the state of ``max_batch`` slots and the per-slot current token and
 position vectors.  What that state IS belongs to the model: one pytree
-that the model's side of the seam (:func:`slot_model`) makes, fills from
-a prompt, installs into a slot and steps.  Its top-level keys are the
-kinds of state a slot holds:
+that the model's module makes, fills from a prompt, installs into a slot
+and steps.  This file has no code per model: every served module presents
+the same six names (see ``MODELS``), and :func:`slot_model` binds them to
+a config through one builder.  The state's top-level keys are the kinds
+of state a slot holds:
 
 * ``"kv"``: position-indexed keys and values.  A retired lane is hidden
   by the position mask and overwritten by the next install.
@@ -26,7 +28,7 @@ math is that of the model's single-request path, so a slot's output never
 depends on what its neighbors are decoding (pinned by
 tests/test_serving.py and tests/test_jamba.py oracles).
 
-The parameters an engine holds are the model's ``held(params)``, made
+The parameters an engine holds are the model's ``serving_params``, made
 once when it is built: every leaf that the model's forward casts to the
 compute type at its use, in that type (models/transformer.py's
 ``serving_params``; the norm gains, used in float32, stay float32).  The
@@ -47,10 +49,10 @@ the float32 original lives as long as the caller's own reference
 (``ServingLoop.params``, for the engine of a re-formed gang).
 ``hvd_serve_param_bytes{dtype}`` says what is held.
 
-Under a mesh the state shards by the model's spec (the dense decoder's
-KV_CACHE_SPEC: heads over ``tp``), applied with ``filter_spec`` so a spec
-axis missing from the mesh degrades to replication; a model without a
-spec refuses a mesh.
+Under a mesh the state shards by the model's ``STATE_SPEC`` (the dense
+decoder's: KV_CACHE_SPEC, heads over ``tp``), applied with ``filter_spec``
+so a spec axis missing from the mesh degrades to replication; a model
+whose spec is None refuses a mesh.
 
 Prefill compiles once per distinct prompt length (the serving analogue
 of generate()'s per-shape compile); the install takes the slot as a
@@ -94,54 +96,29 @@ class SlotModel(NamedTuple):
     prefill: Callable
     install: Callable
     step: Callable
-    spec: Any = None
-    held: Callable = lambda params: params
+    spec: Any
+    held: Callable
 
 
-def _dense_install(state, slot, request):
-    (ks, vs), (k1, v1) = state["kv"], request["kv"]
-    at = (0, slot, 0, 0, 0)
-    return {"kv": (jax.lax.dynamic_update_slice(ks, k1, at),
-                   jax.lax.dynamic_update_slice(vs, v1, at))}
-
-
-def _dense_slot_model(cfg: T.TransformerConfig, cache_len: int) -> SlotModel:
-    """models/transformer.py's side: ``{"kv": (ks, vs)}``, a request's
-    lane ``[L, 1, cache_len, H, HD]``, zero past the prompt."""
-    if cfg.n_experts:
-        raise NotImplementedError(
-            "serving supports dense-FFN configs (same contract as "
-            "models.transformer.generate)")
-    shape = (cfg.n_layers, cache_len, cfg.n_heads, cfg.head_dim)
-
-    def init_state(max_batch):
-        full = (shape[0], max_batch) + shape[1:]
-        return {"kv": (jnp.zeros(full, cfg.compute_dtype),
-                       jnp.zeros(full, cfg.compute_dtype))}
-
-    def prefill(params, prompt):
-        logits, k1, v1 = T.prefill_request(params, prompt, cfg, cache_len)
-        return logits, {"kv": (k1, v1)}
-
-    def step(params, tok, pos, state):
-        logits, ks, vs = T.decode_step(params, tok, pos, *state["kv"], cfg)
-        return logits, {"kv": (ks, vs)}
-
-    return SlotModel(init_state, prefill, _dense_install, step,
-                     {"kv": (T.KV_CACHE_SPEC, T.KV_CACHE_SPEC)},
-                     partial(T.serving_params, cfg=cfg))
+# Config type -> the module that serves it.  A module's side of the seam is
+# ``init_state(cfg, max_batch, cache_len)``, ``prefill_request(params,
+# prompt, cfg, cache_len)``, ``install_request(state, slot, request)``,
+# ``decode_step(params, tok, pos, state, cfg)``, ``STATE_SPEC`` and
+# ``serving_params(params, cfg)``: what a slot's state is and how it is
+# installed is the model's to say, and a further model is a line here.
+MODELS = {T.TransformerConfig: T, J.JambaConfig: J}
 
 
 def slot_model(cfg, cache_len: int) -> SlotModel:
     """The model is chosen by the type of its config."""
-    if isinstance(cfg, T.TransformerConfig):
-        return _dense_slot_model(cfg, cache_len)
-    if isinstance(cfg, J.JambaConfig):
-        return SlotModel(
-            partial(J.init_state, cfg, cache_len=cache_len),
-            partial(J.prefill_request, cfg=cfg, cache_len=cache_len),
-            J.install_request, partial(J.decode_step, cfg=cfg))
-    raise TypeError(f"no serving path for a {type(cfg).__name__}")
+    module = MODELS.get(type(cfg))
+    if module is None:
+        raise TypeError(f"no serving path for a {type(cfg).__name__}")
+    return SlotModel(
+        partial(module.init_state, cfg, cache_len=cache_len),
+        partial(module.prefill_request, cfg=cfg, cache_len=cache_len),
+        module.install_request, partial(module.decode_step, cfg=cfg),
+        module.STATE_SPEC, partial(module.serving_params, cfg=cfg))
 
 
 def install(model: SlotModel, state, tok, pos, slot, logits, request,

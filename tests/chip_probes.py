@@ -60,7 +60,6 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.models import jamba, transformer as tfm
     from horovod_tpu.serving import decode
 
     def spec(shape, dtype=jnp.int32):
@@ -69,7 +68,7 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
     def specs(tree):
         return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
 
-    init = jamba.init if isinstance(cfg, jamba.JambaConfig) else tfm.init
+    init = decode.MODELS[type(cfg)].init
     model = decode.slot_model(cfg, cfg.max_seq_len)
     params = specs(jax.eval_shape(lambda k: model.held(init(k, cfg)),
                                   jax.random.PRNGKey(0)))

@@ -297,18 +297,23 @@ def test_dp_matches_single_device(eight_devices):
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_generate_matches_teacher_forced(jax):
+@pytest.mark.parametrize("rows,cache_len", [(2, None), (5, None), (3, 29)],
+                         ids=["2rows", "5rows", "3rows-cache29"])
+def test_generate_matches_teacher_forced(jax, rows, cache_len):
     """KV-cache decode must equal argmax over full-recompute logits at
-    every step — pins cache indexing, RoPE positions, and masking."""
+    every step — pins cache indexing, RoPE positions, and masking: for
+    every row of a batch through decode_step's one shared position, and
+    with a cache longer than prompt + new tokens (the masked tail)."""
     cfg = tfm.TransformerConfig(
         vocab_size=97, d_model=32, n_layers=2, n_heads=4, d_ff=64,
         max_seq_len=32, compute_dtype=jnp.float32)
     params = tfm.init(jax.random.PRNGKey(3), cfg)
     rs = np.random.RandomState(0)
-    prompt = jnp.asarray(rs.randint(0, 97, (2, 5)), jnp.int32)
+    prompt = jnp.asarray(rs.randint(0, 97, (rows, 5)), jnp.int32)
 
-    out = tfm.generate(params, prompt, cfg, max_new_tokens=6)
-    assert out.shape == (2, 11)
+    out = tfm.generate(params, prompt, cfg, max_new_tokens=6,
+                       cache_len=cache_len)
+    assert out.shape == (rows, 11)
     np.testing.assert_array_equal(np.asarray(out[:, :5]),
                                   np.asarray(prompt))
 
@@ -319,6 +324,26 @@ def test_generate_matches_teacher_forced(jax):
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         seq = np.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), seq)
+
+
+def test_rope_rows_at_one_position_equal_the_shared_position(jax):
+    """A position per row ([B, 1], the serving step's) all equal to p
+    rotates, to the bit, as the shared [p] does (generate()'s step, which
+    is the serving step with one position for all rows); and rows at
+    their own positions each rotate as alone."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 1, 4, 16),
+                          jnp.bfloat16)
+    shared = tfm._rope(x, 10000.0, jnp.asarray([7]))
+    np.testing.assert_array_equal(
+        np.asarray(tfm._rope(x, 10000.0, jnp.full((3, 1), 7)), np.float32),
+        np.asarray(shared, np.float32))
+    own = tfm._rope(x, 10000.0, jnp.asarray([[0], [7], [30]]))
+    np.testing.assert_array_equal(np.asarray(own[1], np.float32),
+                                  np.asarray(shared[1], np.float32))
+    np.testing.assert_array_equal(                  # position 0: no turn
+        np.asarray(own[0], np.float32), np.asarray(x[0], np.float32))
+    assert not np.array_equal(np.asarray(own[2], np.float32),
+                              np.asarray(shared[2], np.float32))
 
 
 def test_generate_sampling_and_validation(jax):

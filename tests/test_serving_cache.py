@@ -32,6 +32,8 @@ weight on every call.  Pinned here:
 * a mesh: the held leaves keep the sharding of the given ones.
 """
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,6 +44,7 @@ from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
 from horovod_tpu.models import jamba
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
+from horovod_tpu.serving import decode
 from horovod_tpu.serving.decode import DecodeEngine
 from horovod_tpu.telemetry import registry as tmx
 
@@ -226,20 +229,17 @@ def test_held_parameters_give_the_float32_results_to_the_bit(program):
     every_leaf = jax.tree.map(lambda a: a.astype(BF16), given)
     if program == "decode_step":
         def run(params):
-            zeros = jnp.zeros((L, B, S, H, HD), cfg.compute_dtype)
             tok, pos = jnp.asarray([5, 9, 0, 33]), jnp.asarray([0, 3, 0, 7])
-            out = None
+            out = None, tfm.init_state(cfg, B, S)
             for i in range(3):   # the later steps read what the first wrote
-                out = tfm.decode_step(params, tok + i, pos + i,
-                                      *(out[1:] if out else (zeros, zeros)),
-                                      cfg)
+                out = tfm.decode_step(params, tok + i, pos + i, out[1], cfg)
             return out
     else:
         def run(params):
             prompt = jnp.asarray([3, 14, 15, 9, 26, 5], jnp.int32)
             return tfm.prefill_request(params, prompt, cfg, S)
     want, got, blanket = (jax.jit(run)(p) for p in (given, held, every_leaf))
-    for a, b in zip(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(a, b)
     assert not np.array_equal(blanket[0], want[0])
@@ -261,3 +261,44 @@ def test_mesh_keeps_the_sharding_of_the_parameters_it_casts():
     plain = DecodeEngine(given, cfg, max_batch=B, cache_len=S)
     assert engine.prefill(2, [5, 14, 15, 9]) == plain.prefill(2, [5, 14, 15, 9])
     np.testing.assert_array_equal(engine.step()[2], plain.step()[2])
+
+
+# -- the seam: what a model module presents, and the one builder ----------------
+
+SEAM = {"init_state": ["cfg", "max_batch", "cache_len"],
+        "prefill_request": ["params", "prompt", "cfg", "cache_len"],
+        "install_request": ["state", "slot", "request"],
+        "decode_step": ["params", "tok", "pos", "state", "cfg"],
+        "serving_params": ["params", "cfg"]}
+
+
+@pytest.mark.parametrize("make", [_dense_bf16, _jamba_bf16],
+                         ids=["dense", "jamba"])
+def test_model_module_presents_the_seam_the_one_builder_takes(make):
+    cfg, given, _ = make()
+    module = decode.MODELS[type(cfg)]
+    for name, takes in SEAM.items():
+        assert list(inspect.signature(
+            getattr(module, name)).parameters) == takes, name
+    model = decode.slot_model(cfg, S)
+    for part, name in [(model.init_state, "init_state"),
+                       (model.prefill, "prefill_request"),
+                       (model.step, "decode_step"),
+                       (model.held, "serving_params")]:
+        assert part.func is getattr(module, name), name
+    assert model.install is module.install_request
+    assert model.spec is module.STATE_SPEC
+    # and the parts fit: a request's state installs into a batch's, which
+    # a step takes and gives back in the shapes it came in
+    params = model.held(given)
+    state = model.init_state(2)
+    logits, request = model.prefill(params, jnp.asarray([3, 14, 15]))
+    assert logits.shape == (V,)
+    assert (jax.tree.structure(request) == jax.tree.structure(state)
+            == jax.tree.structure(model.init_state(1)))
+    state = model.install(state, 1, request)
+    logits, after = model.step(params, jnp.asarray([0, 9]),
+                               jnp.asarray([0, 3]), state)
+    assert logits.shape == (2, V)
+    assert (jax.tree.map(lambda a: (a.shape, a.dtype), after)
+            == jax.tree.map(lambda a: (a.shape, a.dtype), state))
