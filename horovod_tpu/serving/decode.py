@@ -54,6 +54,17 @@ decoder's: KV_CACHE_SPEC, heads over ``tp``), applied with ``filter_spec``
 so a spec axis missing from the mesh degrades to replication; a model
 whose spec is None refuses a mesh.
 
+A step has two halves.  ``dispatch()`` queues the jit-ed step and the
+three lazy ops after it (the greedy ``argmax``, the token and the position
+advance) and waits for nothing: the next step's inputs are device arrays.
+``read()`` brings the oldest unread token vector to the host, and waits
+for that step only.  ``ServingLoop`` dispatches step k before it reads
+step k-1, so the chip is never idle for the host's part of a turn;
+``step()`` is the two in order.  A slot cleared between a step's dispatch
+and its read has had one more row computed: the row stays in bounds (the
+position clamp), reaches no other row, and the next install overwrites
+what it wrote.
+
 Prefill compiles once per distinct prompt length (the serving analogue
 of generate()'s per-shape compile); the install takes the slot as a
 traced scalar and compiles once.  Greedy sampling only: determinism
@@ -63,9 +74,9 @@ re-formed gang replay a request to the identical completion.
 
 from __future__ import annotations
 
-from collections import Counter
+from collections import Counter, deque
 from functools import partial
-from typing import Any, Callable, Dict, List, NamedTuple, Optional
+from typing import Any, Callable, Deque, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -175,6 +186,9 @@ class DecodeEngine:
             partial(install, self.model), donate_argnums=(0,),
             out_shardings=(None, sharding, None, None))
         self._prefills: Dict[int, object] = {}  # prompt len -> jit fn
+        # Token vectors of steps dispatched and not read yet, oldest
+        # first (device arrays; ServingLoop keeps at most one).
+        self._unread: Deque[jax.Array] = deque()
 
     def prefill(self, slot: int, prompt: List[int]) -> int:
         """Run the prompt through the model, install its state into the
@@ -196,10 +210,11 @@ class DecodeEngine:
         self.tok = self.tok.at[slot].set(0)
         self.pos = self.pos.at[slot].set(0)
 
-    def step(self) -> np.ndarray:
-        """One decode step for the whole batch; returns the [max_batch]
-        greedy next-token vector (free slots compute harmless garbage —
-        rows are independent)."""
+    def dispatch(self) -> None:
+        """Queue one decode step for the whole batch (free slots compute
+        harmless garbage: rows are independent).  Nothing here waits for
+        the chip: the step's token vector joins the unread ones, and is
+        the next step's input already."""
         logits, self.state = self._step(
             self.params, self.tok, self.pos, self.state)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -207,4 +222,19 @@ class DecodeEngine:
         # Clamp so an idle slot parked at the cap can never scatter out
         # of bounds; an active slot retires before reaching it.
         self.pos = jnp.minimum(self.pos + 1, self.cache_len - 1)
-        return np.asarray(nxt)
+        self._unread.append(nxt)
+
+    @property
+    def unread(self) -> int:
+        """Steps dispatched whose token vector has not been read."""
+        return len(self._unread)
+
+    def read(self) -> np.ndarray:
+        """The oldest unread step's [max_batch] greedy next-token vector,
+        on the host: waits for that step, not for those queued behind."""
+        return np.asarray(self._unread.popleft())
+
+    def step(self) -> np.ndarray:
+        """One decode step, dispatched and read."""
+        self.dispatch()
+        return self.read()

@@ -12,10 +12,47 @@ and retires finished slots.  Greedy decode is deterministic, so
 retirements need no broadcast: every rank computes the same tokens and
 drops the same slots.
 
+A turn (``_turn``) takes one of two orders, and at most one step's token
+vector is ever unread.  *Settling* a vector is reading it back,
+confirming it with the gang and emitting it, in that order.
+
+* No admissions in the frame: dispatch step k, THEN settle step k-1.  The
+  readback, the confirm, the emit and the leader's next frame run while
+  the chip works on a step that is already queued: the step's inputs
+  (token, position, state) are device arrays, and the host reads tokens
+  only to confirm them and hand them to clients.
+* Admissions in the frame: settle step k-1 first, prefill on an empty
+  chip (``serve.prefill`` times the prefill and nothing else), then
+  dispatch step k and leave it unread for the next turn.  A request that
+  arrives therefore waits for the step in flight AND the one queued
+  behind it: up to one device step more until its first token than a
+  loop that reads every step at once (docs/serving.md has the number).
+* If no slot will outlive the unread vector (every remaining count is at
+  its last token), settle and dispatch nothing: no step runs for an
+  empty table, and nothing is unread when the loop sleeps or sends its
+  stop frame.
+
+Either way a frame's admissions enter ``_slots`` (the shadow a promoted
+follower replays from) when the frame is applied, before the turn
+settles or prefills: a leader that dies in that turn's confirm takes no
+admitted request with it.
+
+Which order a turn takes depends only on the frame and on ``_slots``,
+which every rank holds alike: the gang stays in lock step with no new
+field on the wire.  A slot that retires at the settle of k-1 has one
+more row computed in step k.  Nobody reads it: the slot has left
+``_slots``, rows are independent, the position clamp keeps the row in
+bounds, and the next tenant's install overwrites the slot's token,
+position, lane and recurrent state.  Retirement by count is known before
+the dispatch; one by ``eos_id`` is not, and if it empties the table the
+step that ran ahead is read and dropped.
+``hvd_serve_steps_ahead_total`` counts the steps dispatched ahead.
+
 Robustness is composed from the existing machinery, not rebuilt:
 
-* Each step ends in a tiny token-agreement allreduce
-  (``__serve.confirm``, MAX over the next-token vector).  That gives the
+* Each step's tokens pass a tiny token-agreement allreduce before they
+  are emitted (``__serve.confirm``, MAX over the next-token vector; a
+  rank wedged on the device never enters it).  That gives the
   PR-6 collective deadline a data-plane op to bound — a rank wedged in
   the ring trips the hop deadline, the gang-wide abort agreement names
   it, and the survivors raise :class:`CollectiveTimeoutError` out of
@@ -26,11 +63,12 @@ Robustness is composed from the existing machinery, not rebuilt:
   diverged and the step fails loudly rather than serving garbage.
 * The epoch body is wrapped in ``@hvd.elastic.run``: on an abort the
   gang re-forms in process, a fresh :class:`DecodeEngine` is built
-  against the new world, and rank 0 requeues every in-flight request at
-  the front of the queue (``Scheduler.requeue_inflight``) — requests are
-  replayed from their prompts, at-least-once, to the bit-identical
-  completion (greedy).  The HTTP front door and its parked handler
-  threads belong to the process, so clients only observe added latency.
+  against the new world (the step in flight goes with the old one), and
+  rank 0 requeues every in-flight request at the front of the queue
+  (``Scheduler.requeue_inflight``) — requests are replayed from their
+  prompts, at-least-once, to the bit-identical completion (greedy).  The
+  HTTP front door and its parked handler threads belong to the process,
+  so clients only observe added latency.
 
 A rank that stalls *outside* the data plane (``serve.step`` chaos site,
 kind=stall) is invisible to the collective deadline — it never submits,
@@ -339,41 +377,76 @@ class ServingLoop:
 
     def _turn(self, seq: int, admissions, engine: DecodeEngine,
               rank0: bool) -> None:
+        """One of the two orders the module docstring gives, chosen by
+        what every rank sees in the frame and in its own ``_slots``."""
+        if not admissions and not engine.unread:
+            return  # nobody to admit, nothing to settle
         t0 = time.monotonic()
+        live = sorted(self._slots)  # the slots of the unread vector
+        # The shadow knows an admission as soon as its frame is applied:
+        # a leader that dies while this turn settles or prefills must
+        # not take the request with it.
         for slot, req_id, max_new, prompt in admissions:
-            with _trace.span("serve.prefill",
-                             histogram="hvd_serve_prefill_seconds",
-                             slot=slot, prompt_len=len(prompt)):
-                first = engine.prefill(slot, prompt)
-                self._slots[slot] = {"id": req_id, "prompt": list(prompt),
-                                     "max_new": max_new,
-                                     "remaining": max_new}
-                self._emit(slot, first, engine, rank0)
-            _tmx.inc_counter("hvd_serve_prefill_tokens_total", len(prompt))
-        if not self._slots:
-            return
-        with _trace.span("serve.decode", slots=len(self._slots)):
-            toks = engine.step()
-        # The agreement allreduce's own collective spans share this
-        # step's wall window; the serve.confirm span ties them to the
-        # TAG_SERVE seq that caused them.
-        with _trace.span("serve.confirm", step=seq,
-                         slots=len(self._slots)) as confirm:
-            self._confirm(toks)
-        with _trace.span("serve.emit", slots=len(self._slots)):
-            for slot in sorted(self._slots):
-                self._emit(slot, int(toks[slot]), engine, rank0)
-        # Step confirm on the flight recorder: stamped with the span's
-        # entry read when tracing, untimed otherwise (ring order still
-        # sequences it against failure events).
-        _bb.note("serve.confirm", confirm.t0, step=seq,
-                 slots=len(self._slots))
+            self._slots[slot] = {"id": req_id, "prompt": list(prompt),
+                                 "max_new": max_new, "remaining": max_new}
+        if admissions:
+            if engine.unread:
+                self._settle(seq, engine, rank0, live, ahead=False)
+            for slot, _, _, prompt in admissions:
+                with _trace.span("serve.prefill",
+                                 histogram="hvd_serve_prefill_seconds",
+                                 slot=slot, prompt_len=len(prompt)):
+                    first = engine.prefill(slot, prompt)
+                    self._emit(slot, first, engine, rank0)
+                _tmx.inc_counter("hvd_serve_prefill_tokens_total",
+                                 len(prompt))
+            if self._slots:
+                engine.dispatch()
+        else:
+            # No step for an empty table: run ahead only if a slot will
+            # outlive the unread vector (retirement by count is known
+            # now; one by eos_id is not, and costs one unread row).
+            self._settle(seq, engine, rank0, live, ahead=any(
+                st["remaining"] > 1 for st in self._slots.values()))
+        if engine.unread and not self._slots:
+            # The settle met the last live slot's eos_id: the step that
+            # ran ahead holds retired slots' rows only.  Nothing stays
+            # unread while the loop sleeps.
+            with _trace.span("serve.decode", slots=0):
+                engine.read()
         if rank0:
             t1 = time.monotonic()
             _tmx.observe("hvd_serve_token_latency_seconds", t1 - t0)
             # Staleness surface for /stats last_step_age_s — the same
             # clock read the latency observe just took.
             self.scheduler.note_step(t1)
+
+    def _settle(self, seq: int, engine: DecodeEngine, rank0: bool,
+                live, ahead: bool) -> None:
+        """Read the unread token vector, confirm it with the gang, emit
+        it to the ``live`` slots it was computed for: no token reaches a
+        client before the gang agreed on it.  With ``ahead`` the next
+        step is dispatched first, inside the same ``serve.decode`` span
+        (one span a vector read)."""
+        with _trace.span("serve.decode", slots=len(live)):
+            if ahead:
+                engine.dispatch()
+                _tmx.inc_counter("hvd_serve_steps_ahead_total")
+            toks = engine.read()
+        # The agreement allreduce's own collective spans share this
+        # step's wall window; the serve.confirm span ties them to the
+        # TAG_SERVE seq that caused them.
+        with _trace.span("serve.confirm", step=seq,
+                         slots=len(live)) as confirm:
+            self._confirm(toks)
+        with _trace.span("serve.emit", slots=len(live)):
+            for slot in live:
+                self._emit(slot, int(toks[slot]), engine, rank0)
+        # Step confirm on the flight recorder: stamped with the span's
+        # entry read when tracing, untimed otherwise (ring order still
+        # sequences it against failure events).
+        _bb.note("serve.confirm", confirm.t0, step=seq,
+                 slots=len(self._slots))
 
     def _emit(self, slot: int, token: int, engine: DecodeEngine,
               rank0: bool) -> None:

@@ -282,7 +282,8 @@ class Scheduler:
         # SLO rollups from the registry's serve histograms (the same
         # bucket math the gang aggregator uses), when telemetry is on.
         if _tmx.enabled():
-            hists = _tmx.snapshot().get("histograms", {})
+            snap = _tmx.snapshot()
+            hists = snap.get("histograms", {})
             for metric, key in (("hvd_serve_ttft_seconds", "ttft"),
                                 ("hvd_serve_token_latency_seconds",
                                  "step")):
@@ -292,4 +293,12 @@ class Scheduler:
                         1e3 * _tmx.histogram_quantile(h, 0.50), 3)
                     out[f"{key}_p99_ms"] = round(
                         1e3 * _tmx.histogram_quantile(h, 0.99), 3)
+            turns = hists.get("hvd_serve_token_latency_seconds",
+                              {}).get("count")
+            if turns:
+                # Share of turns that dispatched a step ahead of the
+                # unread one (loop.py): low means admissions or drains
+                # on most turns, and the chip waiting for the host.
+                out["ahead_share"] = round(snap.get("counters", {}).get(
+                    "hvd_serve_steps_ahead_total", 0.0) / turns, 4)
         return out

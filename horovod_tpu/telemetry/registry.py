@@ -202,8 +202,14 @@ KNOWN_METRICS: Dict[str, dict] = {
         "state-space and convolution state); set when the engine is "
         "built.", ("kind",)),
     "hvd_serve_token_latency_seconds": _hist(
-        "Wall time of one gang decode step (prefills + batched step + "
-        "token-agreement allreduce).", *_SECONDS),
+        "Wall time of one turn of the serving loop: the unread step's "
+        "readback, token-agreement allreduce and emit, the frame's "
+        "prefills, the next step's dispatch.", *_SECONDS),
+    "hvd_serve_steps_ahead_total": _counter(
+        "Decode steps dispatched while the step before was still unread "
+        "(a turn without admissions); over the count of "
+        "hvd_serve_token_latency_seconds, the share of turns whose "
+        "readback, confirm and emit ran while the chip worked."),
     "hvd_serve_last_step_age_seconds": _gauge(
         "Seconds since the gang last confirmed a decode step (rank 0; "
         "refreshed on each /stats read — a growing value means the gang "

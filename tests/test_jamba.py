@@ -382,6 +382,70 @@ def test_serving_loop_serves_the_config_over_http(params, monkeypatch):
         assert tokens == [int(t) for t in logits.argmax(-1)]
 
 
+@pytest.mark.timeout(240)
+def test_a_retired_slots_extra_row_reaches_nobody(params, monkeypatch):
+    """The loop dispatches a step before it reads the last one's tokens
+    (serving/loop.py ``_turn``), so a slot that meets its EOS has one more
+    row computed: here on both kinds of state.  The request ended by the
+    EOS, its neighbour, and the slot's next tenant (installed over a lane
+    and a recurrent state that the extra row wrote) each get the logits and
+    tokens an engine gives them alone, in the order tests/serve_order.py
+    checks."""
+    import serve_order
+
+    # Output projections scaled up: greedy decode then leaves the prompt's
+    # last token, and an EOS can fall in the middle of an answer.
+    loud = jax.tree_util.tree_map_with_path(
+        lambda path, a: a * 12.0 if path[-1].key in (
+            "w_out", "out_proj", "wo") else a, params)
+
+    def alone(prompt, max_new, eos_id=None):
+        spy = Spy(loud, 1)
+        tokens = [spy.engine.prefill(0, prompt)]
+        while len(tokens) < max_new and tokens[-1] != eos_id:
+            tokens.append(int(spy.engine.step()[0]))
+        return tokens, _served(spy, 0, 0, len(tokens) - 1)
+
+    ended, beside, tenant = _prompt(41, 8), _prompt(40, 5), _prompt(42, 3)
+    answer, _ = alone(ended, 12)
+    eos_id = answer[-1]
+    eos_at = answer.index(eos_id)
+    assert 2 < eos_at < 11, answer          # it ends in the middle
+    steps, installs = [], []                # the loop's engine, tapped
+
+    def tap(engine):
+        step, install = engine._step, engine._install
+
+        def spy_step(*args):
+            out = step(*args)
+            steps.append(np.asarray(out[0]))
+            return out
+
+        def spy_install(state, tok, pos, slot, logits, *rest):
+            installs.append((int(slot), len(steps), np.asarray(logits)))
+            return install(state, tok, pos, slot, logits, *rest)
+
+        engine._step, engine._install = spy_step, spy_install
+
+    asked = [(ended, 12), (beside, 16), (tenant, 9)]
+    served = serve_order.serve(monkeypatch, loud, CFG, [asked],
+                               max_batch=2, cache_len=CACHE_LEN,
+                               eos_id=eos_id, on_engine=tap)
+    assert len(served.tokens[0]) == eos_at + 1
+    assert [slot for slot, _, _ in installs] == [0, 1, 0]
+    for (prompt, max_new), tokens, (slot, start, first) in zip(
+            asked, served.tokens, installs):
+        want_tokens, want_logits = alone(prompt, max_new, eos_id)
+        assert tokens == want_tokens, prompt
+        got = np.stack([first] + [s[slot] for s in
+                                  steps[start:start + len(tokens) - 1]])
+        np.testing.assert_allclose(got, want_logits, rtol=1e-5, atol=1e-6)
+    assert 0 < serve_order.check_order(served) < served.turns
+    # the step in flight when slot 0 met its EOS computed a row for it
+    assert len(steps) > sum(len(t) - 1 for t in served.tokens[:1]) \
+        and installs[2][1] == eos_at + 1
+
+
 def test_the_seam_refuses_what_no_model_serves():
     from horovod_tpu.serving import TransformerConfig
 

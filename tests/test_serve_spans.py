@@ -183,6 +183,31 @@ def test_turn_leaves_lie_inside_serve_apply(served):
         assert not any(a[2] < s[3] and s[2] < a[3] for a in applies), s
 
 
+def test_the_order_of_leaves_in_both_kinds_of_turn(served):
+    """A turn without admissions is decode (the next step's dispatch, then
+    the unread vector's readback), confirm, emit.  A turn with admissions
+    settles the unread vector the same way FIRST, then prefills on an
+    empty chip; the step it dispatches after them has no span of its own
+    (its readback is the next turn's ``serve.decode``)."""
+    spans = sorted((r for r in served["jsonl"]
+                    if r["ph"] in INSIDE_APPLY + ("serve.apply",)),
+                   key=lambda r: (r["t0"], -r["t1"]))
+    settle = ["serve.decode", "serve.confirm", "serve.emit"]
+    seen = Counter()
+    for turn in (r for r in spans if r["ph"] == "serve.apply"):
+        inner = [r["ph"] for r in spans if r is not turn
+                 and turn["t0"] <= r["t0"] and r["t1"] <= turn["t1"]]
+        prefills = ["serve.prefill"] * turn["admitted"]
+        if inner[:3] == settle:
+            assert inner[3:] == prefills, (turn, inner)
+            seen["settled", bool(prefills)] += 1
+        else:   # nothing was unread: the first turn after silence
+            assert prefills and inner == prefills, (turn, inner)
+            seen["nothing unread", True] += 1
+    assert all(seen[k] for k in (("settled", False), ("settled", True),
+                                 ("nothing unread", True))), seen
+
+
 @pytest.mark.parametrize("phase", ("serve.prefill", "serve.queued",
                                    "serve.active"))
 def test_one_span_a_request(served, phase):

@@ -456,6 +456,112 @@ def test_single_process_selftest_matches_generate(scenario):
 
 
 # ---------------------------------------------------------------------------
+# the order of a turn: a step is dispatched before the last one is read
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def varied():
+    """The tiny model with its matrices scaled up, so that greedy decode
+    wanders over the vocabulary (the seed-0 model repeats one token) and
+    an ``eos_id`` can fall in the middle of an answer.  Returns (params,
+    cfg, oracle)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(
+        max_seq_len=CACHE_LEN, compute_dtype=jnp.float32, remat=False,
+        **MODEL)
+    params = jax.tree.map(lambda a: a * 4.0 if a.ndim >= 2 else a,
+                          tfm.init(jax.random.PRNGKey(1), cfg))
+
+    def oracle(prompt, max_new, eos_id=None):
+        out = tfm.generate(params, jnp.asarray([prompt], jnp.int32), cfg,
+                           max_new_tokens=max_new, cache_len=CACHE_LEN)
+        tokens = [int(t) for t in np.asarray(out)[0, len(prompt):]]
+        if eos_id in tokens:
+            tokens = tokens[:tokens.index(eos_id) + 1]
+        return tokens
+
+    return params, cfg, oracle
+
+
+A, B, NEIGHBOUR = [3, 14, 15], [20, 30, 40, 1, 2], [9, 8, 7, 6]
+# what the loop serves -> (waves of (prompt, max_new), slots, eos of A's
+# answer at this index or None, stop at the first token)
+ORDER_SCENARIOS = {
+    # A meets its EOS while NEIGHBOUR decodes on; B, queued, is the
+    # slot's next tenant.
+    "eos_beside_a_neighbour": (
+        [[(A, 12), (NEIGHBOUR, 20), (B, 8)]], 2, 5, False),
+    # A alone meets its EOS: the step that ran ahead is nobody's.
+    "eos_alone_then_next_tenant": ([[(A, 12)], [(B, 8)]], 1, 5, False),
+    # A retires by count; B takes its slot in the very next frame.
+    "retired_by_count_readmitted_next_frame": (
+        [[(A, 4), (NEIGHBOUR, 16), (B, 6)]], 2, None, False),
+    "lone_silence_lone": ([[(A, 7)], [(B, 5)]], 2, None, False),
+    "stop_with_requests_in_flight": (
+        [[(A, 9), (NEIGHBOUR, 12), (B, 6)]], 2, None, True),
+}
+
+
+@pytest.mark.timeout(240)
+@pytest.mark.parametrize("scenario", sorted(ORDER_SCENARIOS))
+def test_loop_runs_a_step_ahead_and_serves_generates_tokens(
+        scenario, varied, monkeypatch):
+    """Through ``ServingLoop`` on one rank, with a spy on the engine's two
+    halves (tests/serve_order.py): every request gets ``generate()``'s
+    tokens, cut at the EOS; on a turn without admissions step k is
+    dispatched before vector k-1 is read, on a turn with admissions the
+    read precedes the prefill, confirm precedes emit, nothing is unread
+    while the loop sleeps or stops, no step is dispatched for an empty
+    table, and ``hvd_serve_steps_ahead_total`` is what the spy counted."""
+    import serve_order
+
+    params, cfg, oracle = varied
+    waves, slots, eos_at, stop = ORDER_SCENARIOS[scenario]
+    eos_id = None
+    if eos_at is not None:
+        answer = oracle(A, 12)
+        eos_id = answer[eos_at]
+        assert eos_id not in answer[:eos_at]    # it ends A in the middle
+    served = serve_order.serve(
+        monkeypatch, params, cfg, waves, max_batch=slots,
+        cache_len=CACHE_LEN, eos_id=eos_id, stop_at_first_token=stop)
+    asked = [r for wave in waves for r in wave]
+    for (prompt, max_new), tokens in zip(asked, served.tokens):
+        assert tokens == oracle(prompt, max_new, eos_id), prompt
+    ahead = serve_order.check_order(served)
+    turns = serve_order.turns(served.events)
+    kinds = [[e[0] for e in t] for t in turns]
+    # each step but a drained table's last ran ahead of its turn's read
+    assert 0 < ahead < served.turns
+    assert served.stats["ahead_share"] < 1
+    if scenario == "eos_alone_then_next_tenant":
+        # the dropped step: dispatched ahead, read with nobody to emit to
+        assert any(k[-4:] == ["read", "confirm", "emit", "read"]
+                   for k in kinds), kinds
+    elif scenario == "retired_by_count_readmitted_next_frame":
+        # B's frame follows the turn that retired A, a step in flight
+        b = next(i for i, t in enumerate(turns) if i and t[0][1] == 1)
+        assert turns[b][0][3] == 1 and kinds[b - 1][:3] == \
+            ["frame", "dispatch", "read"], (kinds[b - 1], kinds[b])
+        assert kinds[b][1:3] == ["read", "confirm"]
+    elif scenario == "lone_silence_lone":
+        # no step but those whose tokens were emitted; asleep in between
+        steps = sum(k.count("dispatch") for k in kinds)
+        assert steps == sum(n - 1 for _, n in asked)
+        first_b = next(i for i, e in enumerate(served.events)
+                       if e[0] == "frame" and e[1] == 1 and i)
+        assert any(e[0] == "sleep" for e in served.events[:first_b])
+    elif scenario == "stop_with_requests_in_flight":
+        assert turns[-1][0][2] is True      # the stop frame, last
+        assert [len(t) for t in served.tokens] == [9, 12, 6]
+
+
+# ---------------------------------------------------------------------------
 # the acceptance gangs
 # ---------------------------------------------------------------------------
 
