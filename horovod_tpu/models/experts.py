@@ -1,0 +1,93 @@
+"""A routed feed-forward that drops no row: top-k of many experts, grouped
+matrix products over the experts that have rows.
+
+One function, :func:`routed_ffn`, for a prompt's thousands of rows and
+for a serving turn's few dozen:
+
+1. ``s = sigmoid(x W_r)`` in float32; the ``k`` experts of a row are the
+   top-k of ``s + b`` (``b``: a bias that *selects only*), their weights
+   ``scale * s_i / (sum of the chosen s + 1e-20)`` (the ``noaux_tc``
+   router of the DeepSeek-V3 line, one group: no group limit);
+2. the ``rows x k`` (row, expert) pairs are sorted by expert, the rows
+   gathered in that order, and three ``jax.lax.ragged_dot`` products (up,
+   gate, down) run over the groups: an expert with no row costs nothing,
+   one with many rows gets them all.  **No capacity, no dropped row, no
+   auxiliary loss**, so a row's output depends on that row alone: what a
+   served slot returns never depends on its neighbours;
+3. the results go back to their rows with their weights.
+
+The experts' weights arrive stacked over LAYERS as well, ``[L, E, D, F]``,
+with the layer a traced index: the groups are laid over the ``L x E``
+leading axis (a free reshape) and only layer ``l``'s are non-empty, so a
+layer loop with a dynamic index never cuts a layer's 1.2 GB of experts
+out of their stack (a grouped product is a custom call and a slice in
+front of it is a copy).
+
+Rows marked not ``live`` (a serving batch's free slots) are routed
+nowhere: they sort behind the last group, no expert's weights are read
+for them, and their output is zero.
+
+``stats`` counts what was really routed, as ``[3]`` int32: (row, expert)
+pairs, experts with at least one row, the fullest expert's rows.
+
+models/transformer.py's ``_moe_ffn`` (top-1, a capacity that drops, a
+``[T, E, C]`` one-hot dispatch, the ``ep`` exchange) is the trained path
+and is not replaced here; this module imports nothing of it so that it
+can take this one later.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def route(x, router, bias, top_k: int, scale: float
+          ) -> Tuple[jax.Array, jax.Array]:
+    """x: [T, D] -> (chosen [T, k] int32, weights [T, k] float32).
+    Selection by the biased score, weight from the unbiased one."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), router.astype(jnp.float32),
+        preferred_element_type=jnp.float32))
+    _, chosen = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    weights = scale * picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    return chosen, weights
+
+
+def routed_ffn(x, experts, layer, chosen, weights, dtype,
+               live: Optional[jax.Array] = None):
+    """x: [T, D]; ``experts``: ``w_in``, ``w_gate`` [L, E, D, F] and
+    ``w_out`` [L, E, F, D]; ``layer``: which of the L (may be traced);
+    ``chosen``, ``weights``: [T, k] from :func:`route`; ``live``: [T]
+    bool or None (all).  Returns (y [T, D] in ``dtype``, stats [3])."""
+    T, k = chosen.shape
+    L, E = experts["w_in"].shape[:2]
+    flat = chosen.reshape(T * k)
+    if live is not None:
+        flat = jnp.where(jnp.repeat(live, k), flat, E)     # behind every group
+    order = jnp.argsort(flat, stable=True)                 # pairs by expert
+    counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+    groups = lax.dynamic_update_slice(
+        jnp.zeros((L * E,), jnp.int32), counts, (layer * E,))
+    xs = x.astype(dtype)[order // k]                       # [T k, D]
+
+    def grouped(rows, w):
+        return lax.ragged_dot(
+            rows, w.reshape((L * E,) + w.shape[2:]).astype(dtype), groups)
+
+    h = grouped(xs, experts["w_in"]) * jax.nn.silu(
+        grouped(xs, experts["w_gate"]))
+    ys = grouped(h, experts["w_out"])
+    # Back to (row, choice) order.  Pairs behind the last group were in
+    # no product: what the rows hold there is not a number to weigh.
+    routed = jnp.arange(T * k) < jnp.sum(counts)
+    ys = jnp.where(routed[:, None], ys, 0)[jnp.argsort(order)]
+    y = jnp.sum(ys.reshape(T, k, -1).astype(jnp.float32)
+                * weights[..., None], axis=1)
+    stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                       jnp.max(counts)])
+    return y.astype(dtype), stats

@@ -175,9 +175,9 @@ def _constrain(x, spec: Optional[P], mesh):
         x, jax.sharding.NamedSharding(mesh, fixed))
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (y * g).astype(x.dtype)
 
 
@@ -448,12 +448,16 @@ STATE_SPEC = {"kv": (KV_CACHE_SPEC, KV_CACHE_SPEC)}
 
 def _refuse_experts(cfg: TransformerConfig):
     """Whoever makes a state says so first: dense-FFN configs only
-    (``n_experts=0``), routing under a one-token capacity is a different
-    decode design."""
+    (``n_experts=0``).  ``_moe_ffn`` drops rows over an expert's
+    capacity, which ties a slot's output to its neighbours'; the experts
+    that are served (models/latent_moe.py) drop nothing."""
     if cfg.n_experts:
         raise NotImplementedError(
             "cached decode (generate() and serving) supports dense-FFN "
-            "configs; MoE decode needs per-step routing with capacity 1")
+            "configs: this decoder's experts drop rows over their capacity, "
+            "so a slot's output would depend on its neighbours; experts "
+            "that drop nothing are served by models/latent_moe.py "
+            "(models/experts.py: routed_ffn)")
 
 
 def init_state(cfg: TransformerConfig, max_batch: int, cache_len: int):

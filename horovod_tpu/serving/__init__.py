@@ -6,8 +6,10 @@ with a latency SLO.  The model is chosen by the type of the config handed
 to :class:`ServingLoop`: a :class:`TransformerConfig` (the dense decoder,
 models/transformer.py: a K/V cache a slot) or a :class:`JambaConfig`
 (models/jamba.py: Mamba layers beside attention, so a slot holds
-recurrent state beside a small K/V lane); ``init`` of the same module
-makes its weights.
+recurrent state beside a small K/V lane) or a :class:`LatentMoEConfig`
+(models/latent_moe.py: latent attention, so a slot's lane is a latent and
+one rotary key a position, and routed experts that drop no row); ``init``
+of the same module makes its weights.
 
 Shape of the system (docs/serving.md):
 
@@ -29,6 +31,7 @@ Shape of the system (docs/serving.md):
 """
 
 from horovod_tpu.models.jamba import JambaConfig
+from horovod_tpu.models.latent_moe import LatentMoEConfig
 from horovod_tpu.models.transformer import TransformerConfig
 from horovod_tpu.serving.decode import DecodeEngine, SlotModel, slot_model
 from horovod_tpu.serving.loop import ServingLoop
@@ -39,6 +42,7 @@ __all__ = [
     "DecodeEngine",
     "FrontDoor",
     "JambaConfig",
+    "LatentMoEConfig",
     "QueueFull",
     "Request",
     "Scheduler",

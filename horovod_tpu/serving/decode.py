@@ -14,9 +14,19 @@ of state a slot holds:
 * ``"recurrent"``: fixed-size state with no mask (a state-space layer's).
   The install overwrites ALL of a slot's, so nothing of its last tenant
   reaches the next (pinned by tests/test_jamba.py).
+* ``"counters"``: a dict of the registry's counter names to uint32
+  scalars that the model's step adds to ON THE DEVICE (what it really
+  routed, say).  No slot owns them, an install passes them through, and
+  a turn never reads them: the engine brings them to the host beside the
+  read an admission makes anyway (the prefill's first token) and adds
+  what they grew by to the registry, when the registry is on.
 
 The dense decoder (models/transformer.py) holds ``{"kv": (ks, vs)}``, each
-``[L, max_batch, cache_len, H, HD]``; models/jamba.py holds both kinds.
+``[L, max_batch, cache_len, H, HD]``; models/jamba.py holds both kinds;
+models/latent_moe.py holds as ``"kv"`` a latent and one rotary key a
+position, ``[L, max_batch, cache_len, 512]`` and ``[..., 64]`` where a
+full-width cache would be 20 heads x (256 + 256), beside its routing's
+counters.
 The state is ONE resident set of buffers: the two programs that write it,
 the jit-ed step (B new rows, or one recurrent update, a layer) and the
 install that ends a prefill (one slot's share), take it donated and
@@ -84,6 +94,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from horovod_tpu.models import jamba as J
+from horovod_tpu.models import latent_moe as X
 from horovod_tpu.models import transformer as T
 from horovod_tpu.telemetry import registry as _tmx
 
@@ -117,7 +128,7 @@ class SlotModel(NamedTuple):
 # ``decode_step(params, tok, pos, state, cfg)``, ``STATE_SPEC`` and
 # ``serving_params(params, cfg)``: what a slot's state is and how it is
 # installed is the model's to say, and a further model is a line here.
-MODELS = {T.TransformerConfig: T, J.JambaConfig: J}
+MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X}
 
 
 def slot_model(cfg, cache_len: int) -> SlotModel:
@@ -163,8 +174,9 @@ class DecodeEngine:
             if self.model.spec is None:
                 raise NotImplementedError(
                     f"serving a {type(cfg).__name__} under a mesh: its "
-                    "state has no sharding spec (recurrent state under tp "
-                    "is not written); serve it with mesh=None")
+                    "state has no sharding spec (recurrent state under tp, "
+                    "routed experts under ep are not written); serve it "
+                    "with mesh=None")
             from horovod_tpu.parallel.mesh import sharding_for
 
             sharding = jax.tree.map(
@@ -176,6 +188,8 @@ class DecodeEngine:
                 "hvd_serve_state_bytes",
                 sum(a.nbytes for a in jax.tree.leaves(
                     self.state.get(kind, ()))), labels=(kind,))
+        # The device counters' values when the registry last heard of them.
+        self._published: Dict[str, int] = {}
         self.tok = jnp.zeros((max_batch,), jnp.int32)
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         # The state leaves both programs as it entered them: the same
@@ -185,6 +199,12 @@ class DecodeEngine:
         self._install = jax.jit(
             partial(install, self.model), donate_argnums=(0,),
             out_shardings=(None, sharding, None, None))
+        # A live slot moves one position on, clamped so that it can never
+        # scatter out of bounds (it retires before the cap); a free slot
+        # stays at 0, which is how a model's step can tell it is free.
+        cap = self.cache_len - 1
+        self._advance = jax.jit(lambda pos: jnp.where(
+            pos > 0, jnp.minimum(pos + 1, cap), 0))
         self._prefills: Dict[int, object] = {}  # prompt len -> jit fn
         # Token vectors of steps dispatched and not read yet, oldest
         # first (device arrays; ServingLoop keeps at most one).
@@ -201,27 +221,46 @@ class DecodeEngine:
         first, self.state, self.tok, self.pos = self._install(
             self.state, self.tok, self.pos, np.int32(slot), logits,
             request, np.int32(len(prompt)))
-        return int(first)
+        first = int(first)          # waits for the install: so is the state
+        if _tmx.enabled():
+            self.publish_counters()
+        return first
+
+    def counters(self) -> Dict[str, int]:
+        """The state's device counters, read to the host (waits for the
+        steps dispatched); {} for a model that counts nothing."""
+        return {name: int(v) for name, v in jax.device_get(
+            self.state.get("counters", {})).items()}
+
+    def publish_counters(self) -> None:
+        """Add to the registry what the device counters grew by since the
+        last call (uint32: they wrap, the difference does not)."""
+        for name, value in self.counters().items():
+            grown = (value - self._published.get(name, 0)) % (1 << 32)
+            self._published[name] = value
+            if grown:
+                _tmx.inc_counter(name, grown)
 
     def clear(self, slot: int) -> None:
-        """Retire a slot.  Its state is left as-is — the position mask
-        hides a key/value lane, an idle slot's recurrent state reaches no
-        other row, and the next admission's install overwrites both."""
+        """Retire a slot: its position goes to 0 and stays there.  Its
+        state is left as-is — the position mask hides a key/value lane,
+        an idle slot's recurrent state reaches no other row, and the next
+        admission's install overwrites both."""
         self.tok = self.tok.at[slot].set(0)
         self.pos = self.pos.at[slot].set(0)
 
     def dispatch(self) -> None:
-        """Queue one decode step for the whole batch (free slots compute
-        harmless garbage: rows are independent).  Nothing here waits for
-        the chip: the step's token vector joins the unread ones, and is
-        the next step's input already."""
+        """Queue one decode step for the whole batch.  Free slots, at
+        position 0, compute harmless garbage: rows are independent, and a
+        model leaves them out of work that is not harmless
+        (models/latent_moe.py: out of its routing).  Nothing here waits
+        for the chip: the step's token vector joins the unread ones, and
+        is the next step's input already."""
         logits, self.state = self._step(
             self.params, self.tok, self.pos, self.state)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self.tok = nxt
-        # Clamp so an idle slot parked at the cap can never scatter out
-        # of bounds; an active slot retires before reaching it.
-        self.pos = jnp.minimum(self.pos + 1, self.cache_len - 1)
+        self.pos = self._advance(self.pos)
         self._unread.append(nxt)
 
     @property

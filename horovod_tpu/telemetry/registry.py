@@ -201,6 +201,22 @@ KNOWN_METRICS: Dict[str, dict] = {
         "(position-indexed keys and values) and recurrent (fixed-size "
         "state-space and convolution state); set when the engine is "
         "built.", ("kind",)),
+    "hvd_moe_rows_routed_total": _counter(
+        "(row, expert) pairs the decode steps routed: live rows x experts "
+        "a token x expert layers, a step.  The four hvd_moe_* counters are "
+        "summed ON THE DEVICE inside the step (models/latent_moe.py) and "
+        "read by the engine beside an admission's own read, never on a "
+        "turn."),
+    "hvd_moe_experts_touched_total": _counter(
+        "Experts with at least one row, summed over expert layers and "
+        "steps; over hvd_moe_layer_turns_total, the mean number of experts "
+        "whose weights a layer's grouped product reads a turn."),
+    "hvd_moe_max_expert_rows_total": _counter(
+        "The fullest expert's rows, summed over expert layers and steps; "
+        "over hvd_moe_layer_turns_total against rows routed over experts "
+        "touched, the straggler a grouped product waits for."),
+    "hvd_moe_layer_turns_total": _counter(
+        "Expert layers stepped: expert layers x decode steps."),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one turn of the serving loop: the unread step's "
         "readback, token-agreement allreduce and emit, the frame's "

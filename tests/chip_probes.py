@@ -111,6 +111,15 @@ SERVE_STATE = dict(slots=64, vocab_size=1024, hidden_size=2560,
                    max_seq_len=1536)
 
 
+# The glm-4.7-flash cell's lanes (64 slots x 4608 positions x a latent
+# of 512 and a rotary key of 64) and its attention's widths, with three
+# layers (one dense, two of experts), eight narrow experts and a narrow
+# vocabulary, so that the probe compiles in seconds.
+SERVE_LATENT = dict(slots=64, vocab_size=1024, intermediate_size=512,
+                    moe_intermediate_size=256, num_hidden_layers=3,
+                    n_routed_experts=8, max_seq_len=4608)
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
@@ -118,7 +127,8 @@ def probe_lower_for_tpu(meshes_json):
     benchmark's per-kernel metrics, tell the kernels apart by); and what
     the two programs that write the serving slots' state produce there
     (:func:`serve_cache_programs`), for the dense decoder's cache and for
-    models/jamba.py's two kinds of state.  One process for everything compiled
+    models/jamba.py's two kinds of state and for models/latent_moe.py's
+    latent lanes.  One process for everything compiled
     for the chip (libtpu's lockfile); the compiles run in threads, XLA
     works outside the interpreter lock."""
     from concurrent.futures import ThreadPoolExecutor
@@ -128,7 +138,7 @@ def probe_lower_for_tpu(meshes_json):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.models import jamba
+    from horovod_tpu.models import jamba, latent_moe
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
@@ -159,7 +169,13 @@ def probe_lower_for_tpu(meshes_json):
     state_sizes = dict(SERVE_STATE)
     state_slots = state_sizes.pop("slots")
     jcfg = jamba.JambaConfig(**state_sizes)
+    latent_sizes = dict(SERVE_LATENT)
+    latent_slots = latent_sizes.pop("slots")
+    lcfg = latent_moe.LatentMoEConfig(**latent_sizes)
     one_chip = SingleDeviceSharding(topo.devices[0])
+    # One thread fewer than submissions: the last compile (the latent
+    # lanes') takes the first thread that falls free, so that the probe
+    # loads the machine no more than before it had it.
     with ThreadPoolExecutor(len(meshes) + 2) as pool:
         serve_cache = pool.submit(
             serve_cache_programs, cfg, slots,
@@ -169,11 +185,16 @@ def probe_lower_for_tpu(meshes_json):
             serve_cache_programs, jcfg, state_slots,
             # one layer's recurrent state: [slots, d_state, d_inner]
             state_slots * jcfg.mamba_d_state * jcfg.d_inner, one_chip)
+        serve_latent = pool.submit(
+            serve_cache_programs, lcfg, latent_slots,
+            # one layer's lane of latents: [slots, cache_len, kv_lora_rank]
+            latent_slots * lcfg.max_seq_len * lcfg.kv_lora_rank, one_chip)
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
         "serve_cache": serve_cache.result(),
         "serve_state": serve_state.result(),
+        "serve_latent": serve_latent.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
 
@@ -224,6 +245,9 @@ DENSE_CAST_LEAVES = frozenset(
     {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
 JAMBA_CAST_LEAVES = DENSE_CAST_LEAVES | {
     "in_proj", "x_proj", "dt_proj", "out_proj"}
+LATENT_MOE_CAST_LEAVES = frozenset(
+    {"embed", "head", "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "wo", "w_in",
+     "w_gate", "w_out", "shared_in", "shared_gate", "shared_out"})
 
 _HLO_TYPES = {"bfloat16": "bf16", "float32": "f32"}
 

@@ -224,7 +224,37 @@ def test_serving_state_programs_update_in_place_on_the_chip(
     assert got["alias_bytes"] == held, got
 
 
-@pytest.mark.parametrize("probe", ["serve_cache", "serve_state"])
+@pytest.mark.parametrize("program,updates", [
+    ("step", {"fusion:scatter"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_latent_lanes_programs_update_in_place_on_the_chip(
+        probes, program, updates):
+    """models/latent_moe.py's lanes (a latent and one rotary key a
+    position), compiled for ``v5e`` at the benchmark cell's lane shapes:
+    the step (the absorbed form, the experts' grouped products) and the
+    install produce nothing of one layer's lane of latents besides the
+    in-place updates of the two caches they were given and the
+    compiler's own asynchronous moves of weights into fast memory: no
+    copy of a stack, no lane cut out of it, no expanded key; the two
+    caches and the four counters are aliased from input to output and the
+    temporaries stay under one lane."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_latent"][program]
+    c = chip_probes.SERVE_LATENT
+    lane_bytes = 2 * c["slots"] * c["max_seq_len"] * 512        # bfloat16
+    # the rotary keys' lane is an eighth of the latents'; a counter is a
+    # scalar, and the chip gives a scalar a buffer of 512 bytes
+    held = c["num_hidden_layers"] * (lane_bytes + lane_bytes // 8) + 4 * 512
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    assert got["temp_bytes"] < lane_bytes, got
+    assert got["alias_bytes"] == held, got
+
+
+@pytest.mark.parametrize("probe", ["serve_cache", "serve_state",
+                                   "serve_latent"])
 def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
     """The engine holds the weights in the compute type (the dense
     decoder's float32 ones rounded once when it is built), so the decode
@@ -233,7 +263,7 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
     those converts out of the layer loop and runs them on every turn."""
     import jax
 
-    from horovod_tpu.models import jamba
+    from horovod_tpu.models import jamba, latent_moe
     from horovod_tpu.models import transformer as tfm
 
     rc, out, err = probes.result("lower_for_tpu")
@@ -243,7 +273,10 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
         "serve_cache": (chip_probes.SERVE_CACHE, tfm, tfm.TransformerConfig,
                         chip_probes.DENSE_CAST_LEAVES),
         "serve_state": (chip_probes.SERVE_STATE, jamba, jamba.JambaConfig,
-                        chip_probes.JAMBA_CAST_LEAVES)}[probe]
+                        chip_probes.JAMBA_CAST_LEAVES),
+        "serve_latent": (chip_probes.SERVE_LATENT, latent_moe,
+                         latent_moe.LatentMoEConfig,
+                         chip_probes.LATENT_MOE_CAST_LEAVES)}[probe]
     cfg = config(**{k: v for k, v in sizes.items() if k != "slots"})
     weights = chip_probes.weight_dims(
         jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0)),
