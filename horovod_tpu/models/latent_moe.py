@@ -33,7 +33,10 @@ supported (the grouped product has no backward pass written for it).
       ctx_h     = (sum_t p_h,t c_t) W_uv,h
 
   the step reads a cached position once for all heads and never expands
-  a key.  Both give the same attention (tests/test_latent_moe.py).
+  a key: ONE kernel (ops/pallas_decode_attention.py) walks each slot's
+  lane in blocks as far as the slot's position, and a block of latents
+  it has fetched serves the scores and the context both.  Both forms
+  give the same attention (tests/test_latent_moe.py).
 * Feed-forward: the first ``first_k_dense_replace`` layers the dense
   gated one; the others ``experts.routed_ffn`` (top-k of a biased
   sigmoid score, nothing dropped) plus a shared expert.
@@ -45,7 +48,7 @@ State of a served batch (``init_state``)::
 
     {"kv": (c, k_r)     [L, B, cache_len, kv_lora_rank],
                         [L, B, cache_len, qk_rope_head_dim]  compute_dtype
-     "counters": {...}  four uint32 scalars, summed on the device by
+     "counters": {...}  six uint32 scalars, summed on the device by
                         ``decode_step``: see ``COUNTERS``}
 
 A request's state (``prefill_request``) is the ``"kv"`` part with B = 1.
@@ -66,17 +69,24 @@ from jax import lax
 
 from horovod_tpu.models import experts
 from horovod_tpu.models.jamba import _at
-from horovod_tpu.models.transformer import (_dense_ffn, _rmsnorm, _rope,
+from horovod_tpu.models.transformer import (ATTN_COUNTERS, _dense_ffn,
+                                            _rmsnorm, _rope,
+                                            count_attention_reads,
                                             vocab_projection)
+from horovod_tpu.ops.pallas_decode_attention import (block_for,
+                                                     decode_attention,
+                                                     work_list)
 
 Params = Dict[str, Any]
 State = Dict[str, Any]
 
 # What ``decode_step`` adds to ``state["counters"]`` a step, over the
 # expert layers: (row, expert) pairs routed, experts with at least one
-# row, the fullest expert's rows, and the expert layers stepped.
-COUNTERS = ("hvd_moe_rows_routed_total", "hvd_moe_experts_touched_total",
-            "hvd_moe_max_expert_rows_total", "hvd_moe_layer_turns_total")
+# row, the fullest expert's rows, and the expert layers stepped; and, over
+# all layers, what the attention read of the lanes (ATTN_COUNTERS).
+MOE_COUNTERS = ("hvd_moe_rows_routed_total", "hvd_moe_experts_touched_total",
+                "hvd_moe_max_expert_rows_total", "hvd_moe_layer_turns_total")
+COUNTERS = MOE_COUNTERS + ATTN_COUNTERS
 
 
 @dataclass(frozen=True)
@@ -184,10 +194,12 @@ def _attention(x, lp, cfg: LatentMoEConfig, cache=None):
     ``cache`` None, the expanded form: the S positions start at 0 and
     attend among themselves; returns (out, (c, k_r)), the latents
     [B, S, kv_lora_rank] and rotated keys [B, S, rope] for whoever keeps
-    them.  ``cache`` = (cs, krs, layer, pos), the stacked caches
-    [L, B, Smax, .] and the position [B] of THIS token (S = 1), the
+    them.  ``cache`` = (cs, krs, layer, pos, work), the stacked caches
+    [L, B, Smax, .], the position [B] of THIS token (S = 1) and the
+    kernel's ``work_list`` of those positions, the
     absorbed form: writes the B new rows at [layer, b, pos[b]] in place
-    and attends lane ``layer`` up to ``pos``; returns (out, (cs, krs))."""
+    and attends lane ``layer``, each slot's as far as its ``pos``;
+    returns (out, (cs, krs))."""
     dtype, f32 = cfg.compute_dtype, jnp.float32
     eps, theta = cfg.rms_norm_eps, cfg.rope_theta
     B, S, _ = x.shape
@@ -222,21 +234,21 @@ def _attention(x, lp, cfg: LatentMoEConfig, cache=None):
         ctx = jnp.concatenate(blocks, axis=1)                   # [B, S, H, v]
         kept = (c, k_r)
     else:
-        cs, krs, layer, pos = cache
+        cs, krs, layer, pos, work = cache
         rows = jnp.arange(B)
         cs = cs.at[layer, rows, pos].set(c[:, 0])
-        krs = krs.at[layer, rows, pos].set(k_r[:, 0])
-        lat = lax.dynamic_index_in_dim(cs, layer, 0, keepdims=False)
-        rot = lax.dynamic_index_in_dim(krs, layer, 0, keepdims=False)
+        # Row by row, not one scatter: XLA keeps rows of 64 values with
+        # the positions minor in HBM, a scatter wants its rows minor, and
+        # the two layouts of the whole array are a copy each way a step.
+        for b in range(B):
+            krs = lax.dynamic_update_slice(
+                krs, k_r[b][None, None], (layer, b, pos[b], 0))
         q_c = jnp.einsum("bhk,chk->bhc", q_n[:, 0], w_uk)       # absorbed
-        scores = (
-            jnp.einsum("bhc,btc->bht", q_c, lat, preferred_element_type=f32)
-            + jnp.einsum("bhk,btk->bht", q_r[:, 0], rot,
-                         preferred_element_type=f32)) * scale
-        valid = jnp.arange(lat.shape[1])[None, :] <= pos[:, None]   # [B, T]
-        probs = jax.nn.softmax(
-            jnp.where(valid[:, None], scores, -1e30), axis=-1)
-        ctx_c = jnp.einsum("bht,btc->bhc", probs.astype(dtype), lat)
+        # The latents are keys and values both, fetched once a block;
+        # the rotary keys are the keys' second part, read as they lie.
+        ctx_c = decode_attention(
+            (q_c, q_r[:, 0]), (cs, krs.swapaxes(2, 3)), None, layer, pos,
+            scale=scale, work=work, positions_last=(False, True))
         ctx = jnp.einsum("bhc,chk->bhk", ctx_c, w_uv)[:, None]  # [B, 1, H, v]
         kept = (cs, krs)
     return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
@@ -273,6 +285,9 @@ def _stack(params: Params, x, cfg: LatentMoEConfig, kv=None, pos=None):
     start = pos is None
     keeps = kv is not None
     live = None if start else pos > 0
+    if not start:       # the attention kernel's blocks, once for all layers
+        cache_len = kv[0].shape[2]
+        work = work_list(pos, cache_len, block_for(cache_len, shared=True))
     B, S, D = x.shape
     Ld = cfg.n_layers("dense")
     # The routed experts stay in their stack (experts.routed_ffn indexes
@@ -290,7 +305,7 @@ def _stack(params: Params, x, cfg: LatentMoEConfig, kv=None, pos=None):
                       lax.dynamic_update_slice(kv[1], k_r[None],
                                                (at, 0, 0, 0)))
         else:
-            y, kv = _attention(y, lp, cfg, (*kv, at, pos))
+            y, kv = _attention(y, lp, cfg, (*kv, at, pos, work))
         return h + y, kv
 
     def dense_layer(l, carry):
@@ -372,8 +387,13 @@ def decode_step(params: Params, tok, pos, state: State,
     x, kv, stats = _stack(params, x, cfg, state["kv"], pos)
     add = (*stats.astype(jnp.uint32),
            jnp.uint32(cfg.n_layers("moe")))
-    counters = {name: state["counters"][name] + a
-                for name, a in zip(COUNTERS, add)}
+    counters = {**state["counters"],
+                **{name: state["counters"][name] + a
+                   for name, a in zip(MOE_COUNTERS, add)}}
+    cache_len = kv[0].shape[2]
+    counters = count_attention_reads(
+        counters, pos, cache_len, cfg.num_hidden_layers,
+        block_for(cache_len, shared=True))
     return _logits(params, x, cfg)[:, 0], {"kv": kv, "counters": counters}
 
 

@@ -232,11 +232,14 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     ``cache`` None: the S positions (0 to S - 1) attend among themselves
     by ``cfg.attn_impl``; returns (out, (k, v)) with the rotated k, v
     [B, S, H, HD] for whoever keeps them.
-    ``cache`` = (ks, vs, layer, pos), stacked caches [L, B, Smax, H, HD]
-    and the positions [B] of THIS token in each row (S = 1): writes the B
-    new rows at [layer, b, pos[b]] and reads layer ``layer``'s lane where
-    it lies, up to ``pos``.  Nothing of the cache's or a lane's size is
-    produced besides the caches themselves, which the caller carries (and
+    ``cache`` = (ks, vs, layer, pos, read), stacked caches
+    [L, B, Smax, H, HD] and the positions [B] of THIS token in each row
+    (S = 1): writes the B new rows at [layer, b, pos[b]] and reads layer
+    ``layer``'s lane where it lies: by ``read`` (:func:`lane_reader`: the
+    kernel of ops/pallas_decode_attention.py, each row's lane as far as
+    its ``pos`` and no further) or, ``read`` None, the whole lane under a
+    position mask.  Nothing of the cache's or a lane's size is produced
+    besides the caches themselves, which the caller carries (and
     donates), so XLA updates them in place.  Returns (out, (ks, vs)).
     Rows never mix, so a row's output depends on its own lane alone."""
     B, S, D = x.shape
@@ -293,17 +296,50 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
             tri = jnp.tril(jnp.ones((S, S), jnp.bool_))
             ctx = _softmax_attention(q, kk, v, tri[None, None], cfg)
     else:
-        ks, vs, layer, pos = cache
+        ks, vs, layer, pos, read = cache
         rows = jnp.arange(B)
         ks = ks.at[layer, rows, pos].set(kk[:, 0])
         vs = vs.at[layer, rows, pos].set(v[:, 0])
-        k_cache = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
-        v_cache = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
-        valid = jnp.arange(k_cache.shape[1])[None, :] <= pos[:, None]
-        ctx = _softmax_attention(q, k_cache, v_cache,
-                                 valid[:, None, None, :], cfg)
+        if read is not None:
+            ctx = read(q[:, 0], ks, vs, layer)[:, None]
+        else:
+            k_cache = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
+            v_cache = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
+            valid = jnp.arange(k_cache.shape[1])[None, :] <= pos[:, None]
+            ctx = _softmax_attention(q, k_cache, v_cache,
+                                     valid[:, None, None, :], cfg)
         kept = (ks, vs)
     return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
+
+
+def lane_reader(cfg: TransformerConfig, mesh, pos, cache_len: int):
+    """How a decode step under ``mesh`` reads its slots' lanes, slots at
+    ``pos`` [B] in lanes of ``cache_len``: the kernel over (q [B, H, HD],
+    ks, vs, layer), each lane as far as the slot has written it, its list
+    of blocks made here once for all the layers; or None, the masked read
+    of the whole lane, where ``tp`` or ``sp`` split the cache's heads or
+    the sequence (a pallas_call is not partitioned over them), which is
+    said once.  Under a mesh of several devices the kernel runs inside a
+    shard_map that is manual over every axis, all operands replicated
+    (see the training kernel's, above)."""
+    for ax in ("tp", "sp"):
+        if mesh is not None and mesh.shape.get(ax, 1) > 1:
+            warn_flash_runs_dense(ax, mesh.shape[ax], "the decode step")
+            return None
+    from horovod_tpu.ops import pallas_decode_attention as pda
+
+    work = pda.work_list(pos, cache_len, pda.block_for(cache_len, shared=False))
+
+    def read(q, ks, vs, layer, pos, work):
+        return pda.decode_attention(
+            (q,), (ks,), vs, layer, pos, work=work,
+            scale=1.0 / math.sqrt(cfg.head_dim))
+
+    if mesh is not None and mesh.size > 1:
+        from horovod_tpu.parallel.shard import shard_map
+
+        read = shard_map(read, mesh, in_specs=(P(),) * 6, out_specs=P())
+    return lambda q, ks, vs, layer: read(q, ks, vs, layer, pos, work)
 
 
 def _dense_ffn(x, lp, dtype):
@@ -443,7 +479,32 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
 
 
 KV_CACHE_SPEC = P(None, None, None, "tp", None)  # [L, B, Smax, H, HD]
-STATE_SPEC = {"kv": (KV_CACHE_SPEC, KV_CACHE_SPEC)}
+
+# What a decode step adds to ``state["counters"]``, over its layers:
+# positions of the slots' lanes in the blocks its attention fetched, and
+# positions those lanes hold (``max_batch x cache_len`` a layer).  uint32
+# and read as differences, so they may wrap between two reads but not
+# twice (2**32 positions: thousands of turns of the largest table here).
+ATTN_COUNTERS = ("hvd_serve_attn_positions_read_total",
+                 "hvd_serve_attn_positions_held_total")
+STATE_SPEC = {"kv": (KV_CACHE_SPEC, KV_CACHE_SPEC),
+              "counters": {name: P() for name in ATTN_COUNTERS}}
+
+
+def count_attention_reads(counters, pos, cache_len: int, n_layers: int,
+                          block: Optional[int]):
+    """``counters`` with ATTN_COUNTERS moved on by one decode step of
+    ``n_layers`` layers over slots at ``pos`` [B] in lanes of
+    ``cache_len``: ``block`` is the kernel's (the blocks up to each
+    slot's position are read), or None where the whole lane is."""
+    held = jnp.uint32(n_layers * pos.shape[0] * cache_len)
+    read = held
+    if block is not None:
+        from horovod_tpu.ops.pallas_decode_attention import pairs_run
+
+        read = (pairs_run(pos, block) * (n_layers * block)).astype(jnp.uint32)
+    return {**counters, ATTN_COUNTERS[0]: counters[ATTN_COUNTERS[0]] + read,
+            ATTN_COUNTERS[1]: counters[ATTN_COUNTERS[1]] + held}
 
 
 def _refuse_experts(cfg: TransformerConfig):
@@ -465,7 +526,9 @@ def init_state(cfg: TransformerConfig, max_batch: int, cache_len: int):
     _refuse_experts(cfg)
     lane = (cfg.n_layers, max_batch, cache_len, cfg.n_heads, cfg.head_dim)
     return {"kv": (jnp.zeros(lane, cfg.compute_dtype),
-                   jnp.zeros(lane, cfg.compute_dtype))}
+                   jnp.zeros(lane, cfg.compute_dtype)),
+            "counters": {name: jnp.zeros((), jnp.uint32)
+                         for name in ATTN_COUNTERS}}
 
 
 def _prefill(params, tokens, cfg, Smax):
@@ -497,32 +560,43 @@ def prefill_request(params, prompt, cfg: TransformerConfig, cache_len: int):
 
 def install_request(state, slot, request):
     """Write a request's lane over slot ``slot``'s, all of it.  ``state``
-    donated, the writes are in place."""
+    donated, the writes are in place; the counters pass through."""
     (ks, vs), (k1, v1) = state["kv"], request["kv"]
     at = (0, slot, 0, 0, 0)
-    return {"kv": (lax.dynamic_update_slice(ks, k1, at),
-                   lax.dynamic_update_slice(vs, v1, at))}
+    return {**state, "kv": (lax.dynamic_update_slice(ks, k1, at),
+                            lax.dynamic_update_slice(vs, v1, at))}
 
 
-def decode_step(params, tok, pos, state, cfg: TransformerConfig):
+def decode_step(params, tok, pos, state, cfg: TransformerConfig, mesh=None):
     """One step of every row: embed ``tok`` [B], attend each row at its
     own ``pos`` [B], return (next-token logits [B, V] f32, the state
     updated in place when donated).  The caches are the layer loop's
     CARRY, indexed by layer, not its ``xs``/``ys``: a scan that slices a
-    lane out and stacks it back copies the whole cache every step."""
+    lane out and stacks it back copies the whole cache every step.
+    ``mesh``: the one the state is sharded over, if any.  A state that
+    has ``"counters"`` (``init_state``'s) gets them moved on."""
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
+    read = lane_reader(cfg, mesh, pos, state["kv"][0].shape[2])
 
     def layer(carry, layer_in):
         h, kv = carry
         lp, l = layer_in
-        h, _, kv = _layer(h, lp, cfg, cache=(*kv, l, pos))
+        h, _, kv = _layer(h, lp, cfg, cache=(*kv, l, pos, read))
         return (h, kv), None
 
     (x, kv), _ = lax.scan(
         layer, (x, state["kv"]),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = _rmsnorm(x, params["ln_f"])
-    return vocab_projection(x, params["embed"])[:, 0], {"kv": kv}
+    after = {"kv": kv}
+    if "counters" in state:
+        from horovod_tpu.ops.pallas_decode_attention import block_for
+
+        cache_len = kv[0].shape[2]
+        after["counters"] = count_attention_reads(
+            state["counters"], pos, cache_len, cfg.n_layers,
+            None if read is None else block_for(cache_len, shared=False))
+    return vocab_projection(x, params["embed"])[:, 0], after
 
 
 def generate(params, prompt, cfg: TransformerConfig, *,

@@ -9,20 +9,23 @@ the same six names (see ``MODELS``), and :func:`slot_model` binds them to
 a config through one builder.  The state's top-level keys are the kinds
 of state a slot holds:
 
-* ``"kv"``: position-indexed keys and values.  A retired lane is hidden
-  by the position mask and overwritten by the next install.
+* ``"kv"``: position-indexed keys and values.  A step attends a slot's
+  lane as far as the slot's position and no further, so a retired lane
+  is never read; the next install overwrites it.
 * ``"recurrent"``: fixed-size state with no mask (a state-space layer's).
   The install overwrites ALL of a slot's, so nothing of its last tenant
   reaches the next (pinned by tests/test_jamba.py).
 * ``"counters"``: a dict of the registry's counter names to uint32
   scalars that the model's step adds to ON THE DEVICE (what it really
-  routed, say).  No slot owns them, an install passes them through, and
+  routed; what its attention read of the lanes).  No slot owns them, an install passes them through, and
   a turn never reads them: the engine brings them to the host beside the
   read an admission makes anyway (the prefill's first token) and adds
   what they grew by to the registry, when the registry is on.
 
 The dense decoder (models/transformer.py) holds ``{"kv": (ks, vs)}``, each
-``[L, max_batch, cache_len, H, HD]``; models/jamba.py holds both kinds;
+``[L, max_batch, cache_len, H, HD]``, beside two counters of what its
+steps' attention read and held; models/jamba.py holds both kinds of slot
+state;
 models/latent_moe.py holds as ``"kv"`` a latent and one rotary key a
 position, ``[L, max_batch, cache_len, 512]`` and ``[..., 64]`` where a
 full-width cache would be 20 heads x (256 + 256), beside its routing's
@@ -131,15 +134,18 @@ class SlotModel(NamedTuple):
 MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X}
 
 
-def slot_model(cfg, cache_len: int) -> SlotModel:
-    """The model is chosen by the type of its config."""
+def slot_model(cfg, cache_len: int, mesh=None) -> SlotModel:
+    """The model is chosen by the type of its config.  A module whose
+    state can be sharded (``STATE_SPEC``) is told the ``mesh`` its step
+    runs under, if there is one."""
     module = MODELS.get(type(cfg))
     if module is None:
         raise TypeError(f"no serving path for a {type(cfg).__name__}")
+    under = {} if mesh is None or module.STATE_SPEC is None else {"mesh": mesh}
     return SlotModel(
         partial(module.init_state, cfg, cache_len=cache_len),
         partial(module.prefill_request, cfg=cfg, cache_len=cache_len),
-        module.install_request, partial(module.decode_step, cfg=cfg),
+        module.install_request, partial(module.decode_step, cfg=cfg, **under),
         module.STATE_SPEC, partial(module.serving_params, cfg=cfg))
 
 
@@ -161,7 +167,7 @@ class DecodeEngine:
         self.max_batch = max_batch
         self.cache_len = cache_len or cfg.max_seq_len
         self.mesh = mesh
-        self.model = slot_model(cfg, self.cache_len)
+        self.model = slot_model(cfg, self.cache_len, mesh)
         self.params = self.model.held(params)
         held_bytes: Counter = Counter()
         for leaf in jax.tree.leaves(self.params):
@@ -243,9 +249,9 @@ class DecodeEngine:
 
     def clear(self, slot: int) -> None:
         """Retire a slot: its position goes to 0 and stays there.  Its
-        state is left as-is — the position mask hides a key/value lane,
-        an idle slot's recurrent state reaches no other row, and the next
-        admission's install overwrites both."""
+        state is left as-is — a key/value lane is read no further than
+        the slot's position, an idle slot's recurrent state reaches no
+        other row, and the next admission's install overwrites both."""
         self.tok = self.tok.at[slot].set(0)
         self.pos = self.pos.at[slot].set(0)
 

@@ -295,10 +295,18 @@ class Scheduler:
                         1e3 * _tmx.histogram_quantile(h, 0.99), 3)
             turns = hists.get("hvd_serve_token_latency_seconds",
                               {}).get("count")
+            counters = snap.get("counters", {})
             if turns:
                 # Share of turns that dispatched a step ahead of the
                 # unread one (loop.py): low means admissions or drains
                 # on most turns, and the chip waiting for the host.
-                out["ahead_share"] = round(snap.get("counters", {}).get(
+                out["ahead_share"] = round(counters.get(
                     "hvd_serve_steps_ahead_total", 0.0) / turns, 4)
+            held = counters.get("hvd_serve_attn_positions_held_total")
+            if held:
+                # Share of the slots' lanes the decode steps' attention
+                # read: 1.0 is the whole cache every step (a full table
+                # of full lanes, or the masked read under tp / sp).
+                out["attn_read_share"] = round(counters.get(
+                    "hvd_serve_attn_positions_read_total", 0.0) / held, 4)
         return out

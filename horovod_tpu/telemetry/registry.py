@@ -217,6 +217,18 @@ KNOWN_METRICS: Dict[str, dict] = {
         "touched, the straggler a grouped product waits for."),
     "hvd_moe_layer_turns_total": _counter(
         "Expert layers stepped: expert layers x decode steps."),
+    "hvd_serve_attn_positions_read_total": _counter(
+        "Positions of the slots' lanes in the blocks the decode steps' "
+        "attention fetched, summed over layers and steps: a slot's lane "
+        "is read as far as the slot has written it "
+        "(ops/pallas_decode_attention.py).  Summed on the device from the "
+        "positions, like the hvd_moe_* counters, and read beside an "
+        "admission's own read, never on a turn."),
+    "hvd_serve_attn_positions_held_total": _counter(
+        "Positions the slots' lanes hold (max_batch x cache_len), summed "
+        "over layers and steps: what a masked read of the whole cache "
+        "reads.  hvd_serve_attn_positions_read_total over this is "
+        "attn_read_share on GET /stats."),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one turn of the serving loop: the unread step's "
         "readback, token-agreement allreduce and emit, the frame's "

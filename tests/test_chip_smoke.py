@@ -191,7 +191,9 @@ def test_serving_cache_programs_update_in_place_on_the_chip(
     lane_bytes = 2 * c["slots"] * c["max_seq_len"] * c["d_model"]  # bfloat16
     assert [op for _, op in got["big_ops"]] == [update, update], got
     assert got["temp_bytes"] < lane_bytes, got
-    assert got["alias_bytes"] == 2 * c["n_layers"] * lane_bytes, got
+    # both caches, and the two counters beside them (the chip gives a
+    # scalar a buffer of 512 bytes)
+    assert got["alias_bytes"] == 2 * c["n_layers"] * lane_bytes + 2 * 512, got
 
 
 @pytest.mark.parametrize("program,updates", [
@@ -236,7 +238,7 @@ def test_latent_lanes_programs_update_in_place_on_the_chip(
     in-place updates of the two caches they were given and the
     compiler's own asynchronous moves of weights into fast memory: no
     copy of a stack, no lane cut out of it, no expanded key; the two
-    caches and the four counters are aliased from input to output and the
+    caches and the six counters are aliased from input to output and the
     temporaries stay under one lane."""
     rc, out, err = probes.result("lower_for_tpu")
     assert rc == 0, err[-3000:]
@@ -245,7 +247,7 @@ def test_latent_lanes_programs_update_in_place_on_the_chip(
     lane_bytes = 2 * c["slots"] * c["max_seq_len"] * 512        # bfloat16
     # the rotary keys' lane is an eighth of the latents'; a counter is a
     # scalar, and the chip gives a scalar a buffer of 512 bytes
-    held = c["num_hidden_layers"] * (lane_bytes + lane_bytes // 8) + 4 * 512
+    held = c["num_hidden_layers"] * (lane_bytes + lane_bytes // 8) + 6 * 512
     prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
     assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
     assert {op for _, op in got["big_ops"]} & updates, got

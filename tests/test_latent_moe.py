@@ -16,6 +16,9 @@ throughout, seeded weights, on the CPU:
   turns into neighbouring slots get, through ``DecodeEngine``, the logits
   ``forward`` gives each alone; a reused slot holds nothing of its last
   tenant;
+* the step's attention kernel (ops/pallas_decode_attention.py) gives the
+  logits of the masked read of the whole lane it replaced, over 40 steps
+  of uneven slots, and counts what it read;
 * the compiled step and install alias the donated state; the engine says
   what it holds; the device counters add up and reach the registry;
 * ``ServingLoop`` end to end over HTTP, chosen by the config's type.
@@ -32,9 +35,11 @@ import pytest
 
 from chip_probes import serve_cache_programs
 from horovod_tpu.models import experts, latent_moe
+from horovod_tpu.ops import pallas_decode_attention as pda
 from horovod_tpu.serving import DecodeEngine, LatentMoEConfig, ServingLoop
 from horovod_tpu.telemetry import registry as tmx
 from perfbench.reference import moe_lm as ref
+from test_pallas_decode_attention import masked_read, uneven_steps
 
 SIZES = dict(vocab_size=96, hidden_size=32, intermediate_size=64,
              moe_intermediate_size=16, num_hidden_layers=3,
@@ -456,6 +461,39 @@ PIN = LatentMoEConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
                       max_seq_len=S_PIN, compute_dtype=jnp.float32,
                       param_dtype=jnp.float32)
 LANE_ELEMS = B_PIN * S_PIN * 16          # one layer's latents [B, S, 16]
+
+
+def test_step_with_the_kernel_equals_the_masked_read_of_the_whole_lane(
+        monkeypatch):
+    """Blocks of 32 positions in a lane of 256: over the 40 steps the
+    three live slots cross block ends at 32, 64, 96 and 224, beside a
+    free slot; the counters say what the blocks held."""
+    params = latent_moe.init(jax.random.PRNGKey(5), PIN)
+    monkeypatch.setattr(pda, "BLOCK_SHARED", 32)
+    lengths = [3, 0, 61, 200]
+
+    def logits():
+        return uneven_steps(
+            jax.jit(lambda p: latent_moe.prefill_request(params, p, PIN,
+                                                         S_PIN)),
+            latent_moe.install_request,
+            jax.jit(lambda tok, pos, state: latent_moe.decode_step(
+                params, tok, pos, state, PIN)),
+            latent_moe.init_state(PIN, B_PIN, S_PIN), lengths,
+            PIN.vocab_size)
+
+    got, state = logits()
+    read, held = (int(state["counters"][name])
+                  for name in latent_moe.COUNTERS[-2:])
+    assert held == 40 * 3 * B_PIN * S_PIN
+    assert read == 3 * 32 * sum(n // 32 + 1 for length in lengths if length
+                                for n in range(length, length + 40))
+    monkeypatch.setattr(latent_moe, "decode_attention", masked_read)
+    want, _ = logits()
+    live = [b for b, n in enumerate(lengths) if n]
+    np.testing.assert_allclose(got[:, live], want[:, live], rtol=2e-4,
+                               atol=2e-5)
+    assert np.isfinite(got).all()
 
 
 @pytest.mark.parametrize("program", ["step", "install"])

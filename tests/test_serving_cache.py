@@ -13,7 +13,11 @@ donated and update them in place.  What is pinned here, on the CPU:
 * the engine: after ``prefill`` and after ``step`` the buffers it held
   before are deleted (donated, not copied);
 * a mesh: the caches leave both programs sharded as ``KV_CACHE_SPEC`` says,
-  and the tokens are those of the engine without a mesh.
+  and the tokens are those of the engine without a mesh;
+* what the step reads: its logits with the attention kernel
+  (ops/pallas_decode_attention.py) are those of the masked read of the
+  whole lane it replaced, over 40 steps of uneven slots; the two device
+  counters of what was read and held, and ``attn_read_share`` on ``/stats``.
 
 And the parameters an engine holds (``engine.params``): every leaf the
 model's forward casts to the compute type at its use is held in that type,
@@ -43,10 +47,13 @@ from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
                          dims_key, serve_cache_programs, weight_dims)
 from horovod_tpu.models import jamba
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import pallas_decode_attention as pda
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
 from horovod_tpu.serving import decode
 from horovod_tpu.serving.decode import DecodeEngine
+from horovod_tpu.serving.scheduler import Scheduler
 from horovod_tpu.telemetry import registry as tmx
+from test_pallas_decode_attention import masked_read, uneven_steps
 
 L, B, S, H, HD, V = 6, 4, 256, 2, 16, 64
 LANE_ELEMS = B * S * H * HD
@@ -60,20 +67,26 @@ def model():
     return cfg, tfm.init(jax.random.PRNGKey(0), cfg)
 
 
-# The CPU backend's matrix product wants its K and V lane transposed, which
-# costs the step two lanes of temporaries; the chip's compiler reads the
-# lane where it lies (tests/test_chip_smoke.py).
+# On the CPU the interpreter stands in for the step's attention kernel
+# (ops/pallas_decode_attention.py) and copies what it reads, so what is
+# pinned of the step here is the row scatter a cache and the aliasing; that
+# nothing of a lane's size comes out of it is pinned on the program compiled
+# for the chip (tests/test_chip_smoke.py), where Mosaic compiles the kernel.
 @pytest.mark.parametrize("program,update,temp_lanes", [
-    ("step", "fusion:scatter", 3),
+    ("step", "fusion:scatter", None),
     ("install", "fusion:dynamic-update-slice", 1)])
 def test_compiled_program_updates_the_donated_caches_in_place(
         model, program, update, temp_lanes):
     cfg, _ = model
     got = serve_cache_programs(cfg, B, L * LANE_ELEMS)[program]
     lane_bytes = 4 * LANE_ELEMS
-    assert [op for _, op in got["big_ops"]] == [update, update], got
-    assert got["alias_bytes"] == 2 * L * lane_bytes
-    assert got["temp_bytes"] < temp_lanes * lane_bytes
+    ops = [op for _, op in got["big_ops"]]
+    assert ops.count(update) == 2, got
+    # both caches, and the two uint32 counters beside them
+    assert got["alias_bytes"] == 2 * L * lane_bytes + 2 * 4
+    if temp_lanes is not None:
+        assert ops == [update, update], got
+        assert got["temp_bytes"] < temp_lanes * lane_bytes
 
 
 def test_prefill_and_step_donate_the_caches_they_were_given(model):
@@ -108,6 +121,92 @@ def test_mesh_keeps_the_cache_sharding_through_both_programs(model):
     for cache in sharded.state["kv"]:
         assert cache.sharding.is_equivalent_to(want, 5)
         assert len(cache.sharding.device_set) == 2
+
+
+def test_step_with_the_kernel_equals_the_masked_read_of_the_whole_lane(
+        model, monkeypatch):
+    """Blocks of 32 positions in a lane of 256: over the 40 steps the
+    three live slots cross block ends at 32, 64, 96 and 224, beside a
+    free slot."""
+    cfg, params = model
+    monkeypatch.setattr(pda, "BLOCK", 32)
+    lengths = [3, 0, 61, 200]
+
+    def logits():
+        return uneven_steps(
+            jax.jit(lambda p: tfm.prefill_request(params, p, cfg, S)),
+            tfm.install_request,
+            jax.jit(lambda tok, pos, state: tfm.decode_step(
+                params, tok, pos, state, cfg)),
+            tfm.init_state(cfg, B, S), lengths, V)[0]
+
+    got = logits()
+    monkeypatch.setattr(pda, "decode_attention", masked_read)
+    want = logits()
+    live = [b for b, n in enumerate(lengths) if n]
+    np.testing.assert_allclose(got[:, live], want[:, live], rtol=2e-4,
+                               atol=2e-5)
+    assert np.isfinite(got).all()
+
+
+def test_mesh_without_tp_runs_the_kernel_and_with_tp_the_masked_read(model):
+    """The code observes the mesh, there is no knob: under ``dp`` alone the
+    kernel runs (inside a shard_map, everything replicated), under ``tp``
+    the masked read of the whole lane; the tokens are the same, and only
+    the counters tell which ran."""
+    cfg, params = model
+
+    def served(mesh):
+        engine = DecodeEngine(params, cfg, max_batch=B, cache_len=S,
+                              mesh=mesh)
+        tokens = [engine.prefill(2, [5, 14, 15, 9])]
+        tokens += [int(engine.step()[2]) for _ in range(3)]
+        return tokens, engine.counters()
+
+    plain, counted = served(None)
+    read, held = (counted[name] for name in tfm.ATTN_COUNTERS)
+    assert held == 3 * L * B * S
+    assert read == 3 * L * pda.block_for(S, shared=False)
+    for axes, reads in [({"dp": 2}, read), ({"tp": 2}, held)]:
+        tokens, counted = served(
+            make_mesh(axes, devices=jax.devices()[:2]))
+        assert tokens == plain, axes
+        assert counted[tfm.ATTN_COUNTERS[0]] == reads, axes
+
+
+def test_attn_read_share_is_what_the_steps_read_of_what_the_lanes_hold(
+        model):
+    """1.0 for a full table at the lanes' end; for one live slot the
+    blocks up to its position over the table's; the counters reach the
+    registry beside an admission's read and only when it is on."""
+    cfg, params = model
+    block = pda.block_for(S, shared=False)
+    sched = Scheduler(max_batch=B, max_queue=4, cache_len=S)
+    engine = DecodeEngine(params, cfg, max_batch=B, cache_len=S)
+    engine.pos = jnp.full((B,), S - 1, jnp.int32)
+    engine.step()
+    assert engine._published == {}              # registry off: never read
+    tmx.configure(True)
+    try:
+        engine.publish_counters()
+        assert sched.stats()["attn_read_share"] == 1.0
+        counters = tmx.snapshot()["counters"]
+        assert counters["hvd_serve_attn_positions_held_total"] == L * B * S
+        assert counters["hvd_serve_attn_positions_read_total"] == L * B * S
+    finally:
+        tmx.configure(False)
+    fresh = DecodeEngine(params, cfg, max_batch=B, cache_len=S)
+    tmx.configure(True)
+    try:
+        fresh.prefill(2, list(range(1, 1 + 70)))    # position 70: 1 block
+        for _ in range(3):
+            fresh.step()
+        fresh.publish_counters()
+        assert sched.stats()["attn_read_share"] == pytest.approx(
+            (70 // block + 1) * block / (B * S))
+        assert set(tfm.ATTN_COUNTERS) <= set(tmx.known_metrics())
+    finally:
+        tmx.configure(False)
 
 
 # -- the parameters an engine holds ---------------------------------------------
@@ -278,8 +377,12 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
     cfg, given, _ = make()
     module = decode.MODELS[type(cfg)]
     for name, takes in SEAM.items():
-        assert list(inspect.signature(
-            getattr(module, name)).parameters) == takes, name
+        has = inspect.signature(getattr(module, name)).parameters
+        assert list(has)[:len(takes)] == takes, name
+        # what a module takes besides (the dense decoder's step: the
+        # mesh its state is sharded over) the builder may leave out
+        assert all(p.default is not p.empty
+                   for p in list(has.values())[len(takes):]), name
     model = decode.slot_model(cfg, S)
     for part, name in [(model.init_state, "init_state"),
                        (model.prefill, "prefill_request"),
@@ -294,7 +397,11 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
     state = model.init_state(2)
     logits, request = model.prefill(params, jnp.asarray([3, 14, 15]))
     assert logits.shape == (V,)
-    assert (jax.tree.structure(request) == jax.tree.structure(state)
+    # a request's state is a slot's share of the batch's: every kind
+    # but the counters, which no slot owns
+    slots = {k: v for k, v in state.items() if k != "counters"}
+    assert jax.tree.structure(request) == jax.tree.structure(slots)
+    assert (jax.tree.structure(state)
             == jax.tree.structure(model.init_state(1)))
     state = model.install(state, 1, request)
     logits, after = model.step(params, jnp.asarray([0, 9]),
