@@ -98,6 +98,7 @@ from jax.sharding import PartitionSpec
 
 from horovod_tpu.models import jamba as J
 from horovod_tpu.models import latent_moe as X
+from horovod_tpu.models import retention as R
 from horovod_tpu.models import transformer as T
 from horovod_tpu.telemetry import registry as _tmx
 
@@ -131,7 +132,8 @@ class SlotModel(NamedTuple):
 # ``decode_step(params, tok, pos, state, cfg)``, ``STATE_SPEC`` and
 # ``serving_params(params, cfg)``: what a slot's state is and how it is
 # installed is the model's to say, and a further model is a line here.
-MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X}
+MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X,
+          R.RetentionConfig: R}
 
 
 def slot_model(cfg, cache_len: int, mesh=None) -> SlotModel:

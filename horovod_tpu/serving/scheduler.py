@@ -309,4 +309,10 @@ class Scheduler:
                 # of full lanes, or the masked read under tp / sp).
                 out["attn_read_share"] = round(counters.get(
                     "hvd_serve_attn_positions_read_total", 0.0) / held, 4)
+            held = counters.get("hvd_serve_state_rows_held_total")
+            if held:
+                # Share of the slots whose recurrent state the decode
+                # steps stepped that held a request (models/retention.py).
+                out["state_live_share"] = round(counters.get(
+                    "hvd_serve_state_rows_live_total", 0.0) / held, 4)
         return out

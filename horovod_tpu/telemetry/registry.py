@@ -229,6 +229,18 @@ KNOWN_METRICS: Dict[str, dict] = {
         "over layers and steps: what a masked read of the whole cache "
         "reads.  hvd_serve_attn_positions_read_total over this is "
         "attn_read_share on GET /stats."),
+    "hvd_serve_state_rows_live_total": _counter(
+        "Slots with a request in them (position > 0) whose recurrent state "
+        "a decode step read and wrote, summed over layers and steps "
+        "(models/retention.py).  Summed on the device from the positions, "
+        "like the hvd_moe_* counters, and read beside an admission's own "
+        "read, never on a turn."),
+    "hvd_serve_state_rows_held_total": _counter(
+        "Slots whose recurrent state a decode step read and wrote "
+        "(max_batch: a free slot's is stepped too), summed over layers and "
+        "steps.  hvd_serve_state_rows_live_total over this is "
+        "state_live_share on GET /stats: the share of the state pass that "
+        "served a request."),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one turn of the serving loop: the unread step's "
         "readback, token-agreement allreduce and emit, the frame's "

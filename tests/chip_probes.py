@@ -120,6 +120,14 @@ SERVE_LATENT = dict(slots=64, vocab_size=1024, intermediate_size=512,
                     n_routed_experts=8, max_seq_len=4608)
 
 
+# The brumby-14b cell's state (32 slots x 8 key/value heads x a
+# [128, 8320] float32 matrix a layer) and its retention's widths, with two
+# layers and a narrow feed-forward and vocabulary, so that the probe
+# compiles in seconds.
+SERVE_RETENTION = dict(slots=32, vocab_size=1024, intermediate_size=512,
+                       num_hidden_layers=2, max_seq_len=4736)
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
@@ -127,8 +135,9 @@ def probe_lower_for_tpu(meshes_json):
     benchmark's per-kernel metrics, tell the kernels apart by); and what
     the two programs that write the serving slots' state produce there
     (:func:`serve_cache_programs`), for the dense decoder's cache and for
-    models/jamba.py's two kinds of state and for models/latent_moe.py's
-    latent lanes.  One process for everything compiled
+    models/jamba.py's two kinds of state, for models/latent_moe.py's
+    latent lanes and for models/retention.py's state matrices.  One
+    process for everything compiled
     for the chip (libtpu's lockfile); the compiles run in threads, XLA
     works outside the interpreter lock."""
     from concurrent.futures import ThreadPoolExecutor
@@ -138,7 +147,7 @@ def probe_lower_for_tpu(meshes_json):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.models import jamba, latent_moe
+    from horovod_tpu.models import jamba, latent_moe, retention
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
@@ -172,6 +181,9 @@ def probe_lower_for_tpu(meshes_json):
     latent_sizes = dict(SERVE_LATENT)
     latent_slots = latent_sizes.pop("slots")
     lcfg = latent_moe.LatentMoEConfig(**latent_sizes)
+    retention_sizes = dict(SERVE_RETENTION)
+    retention_slots = retention_sizes.pop("slots")
+    rcfg = retention.RetentionConfig(**retention_sizes)
     one_chip = SingleDeviceSharding(topo.devices[0])
     # One thread fewer than submissions: the last compile (the latent
     # lanes') takes the first thread that falls free, so that the probe
@@ -189,12 +201,18 @@ def probe_lower_for_tpu(meshes_json):
             serve_cache_programs, lcfg, latent_slots,
             # one layer's lane of latents: [slots, cache_len, kv_lora_rank]
             latent_slots * lcfg.max_seq_len * lcfg.kv_lora_rank, one_chip)
+        serve_retention = pool.submit(
+            serve_cache_programs, rcfg, retention_slots,
+            # one layer's state matrices: [slots, KVH, head_dim, rows]
+            retention_slots * rcfg.num_key_value_heads * rcfg.head_dim
+            * rcfg.state_rows, one_chip)
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
         "serve_cache": serve_cache.result(),
         "serve_state": serve_state.result(),
         "serve_latent": serve_latent.result(),
+        "serve_retention": serve_retention.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
 
@@ -245,6 +263,7 @@ DENSE_CAST_LEAVES = frozenset(
     {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
 JAMBA_CAST_LEAVES = DENSE_CAST_LEAVES | {
     "in_proj", "x_proj", "dt_proj", "out_proj"}
+RETENTION_CAST_LEAVES = DENSE_CAST_LEAVES | {"head", "wg"}
 LATENT_MOE_CAST_LEAVES = frozenset(
     {"embed", "head", "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "wo", "w_in",
      "w_gate", "w_out", "shared_in", "shared_gate", "shared_out"})

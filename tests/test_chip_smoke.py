@@ -255,8 +255,40 @@ def test_latent_lanes_programs_update_in_place_on_the_chip(
     assert got["alias_bytes"] == held, got
 
 
+@pytest.mark.parametrize("program,updates", [
+    ("step", {"custom-call"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_retention_state_programs_pass_over_the_state_once_on_the_chip(
+        probes, program, updates):
+    """models/retention.py's state (a [128, 8320] float32 matrix a slot a
+    key/value head a layer: 34 MB), compiled for ``v5e`` at the benchmark
+    cell's state shapes: the step produces nothing of one layer's state
+    matrices besides the kernel ``retention_step``'s in-place pass (no
+    dot over the state and then an update of it, no layer cut out of the
+    stack), the install nothing besides its in-place writes; both state
+    arrays and the two counters are aliased from input to output and the
+    temporaries stay under one layer's state."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_retention"][program]
+    c = chip_probes.SERVE_RETENTION
+    layer_bytes = 4 * c["slots"] * 8 * 128 * 8320
+    # S and z (1/128 of it) a layer; a counter is a scalar, and the chip
+    # gives a scalar a buffer of 512 bytes
+    held = c["num_hidden_layers"] * (layer_bytes + layer_bytes // 128) \
+        + 2 * 512
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    if program == "step":
+        assert [n.split(".")[0] for n, op in got["big_ops"]
+                if op == "custom-call"] == ["retention_step"], got
+    assert got["temp_bytes"] < layer_bytes, got
+    assert got["alias_bytes"] == held, got
+
+
 @pytest.mark.parametrize("probe", ["serve_cache", "serve_state",
-                                   "serve_latent"])
+                                   "serve_latent", "serve_retention"])
 def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
     """The engine holds the weights in the compute type (the dense
     decoder's float32 ones rounded once when it is built), so the decode
@@ -265,7 +297,7 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
     those converts out of the layer loop and runs them on every turn."""
     import jax
 
-    from horovod_tpu.models import jamba, latent_moe
+    from horovod_tpu.models import jamba, latent_moe, retention
     from horovod_tpu.models import transformer as tfm
 
     rc, out, err = probes.result("lower_for_tpu")
@@ -278,7 +310,10 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
                         chip_probes.JAMBA_CAST_LEAVES),
         "serve_latent": (chip_probes.SERVE_LATENT, latent_moe,
                          latent_moe.LatentMoEConfig,
-                         chip_probes.LATENT_MOE_CAST_LEAVES)}[probe]
+                         chip_probes.LATENT_MOE_CAST_LEAVES),
+        "serve_retention": (chip_probes.SERVE_RETENTION, retention,
+                            retention.RetentionConfig,
+                            chip_probes.RETENTION_CAST_LEAVES)}[probe]
     cfg = config(**{k: v for k, v in sizes.items() if k != "slots"})
     weights = chip_probes.weight_dims(
         jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0)),
