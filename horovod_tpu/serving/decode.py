@@ -80,7 +80,13 @@ what it wrote.
 
 Prefill compiles once per distinct prompt length (the serving analogue
 of generate()'s per-shape compile); the install takes the slot as a
-traced scalar and compiles once.  Greedy sampling only: determinism
+traced scalar and compiles once.  The three programs carry names of the
+engine's own, the same for every model behind the seam (``STEP_PROGRAM``,
+``PREFILL_PROGRAM`` with the prompt length, ``INSTALL_PROGRAM``): a
+profile's ``XLA Modules`` line reads ``jit_serve_step(...)``,
+``jit_serve_prefill_s1024(...)`` and ``jit_serve_install(...)``, and the
+benchmark's readers of the device's own times match them
+(``perfbench/readers/module_ms.py``).  Greedy sampling only: determinism
 is what lets every rank step without exchanging tokens and lets a
 re-formed gang replay a request to the identical completion.
 """
@@ -103,6 +109,26 @@ from horovod_tpu.models import transformer as T
 from horovod_tpu.telemetry import registry as _tmx
 
 STATE_KINDS = ("kv", "recurrent")
+
+# What the engine's three compiled programs are called, in HLO
+# (``module @jit_serve_step``) and on a profile's ``XLA Modules`` line.  A
+# prefill program's name ends in its prompt length: ``serve_prefill_s1024``.
+STEP_PROGRAM = "serve_step"
+PREFILL_PROGRAM = "serve_prefill"
+INSTALL_PROGRAM = "serve_install"
+
+
+def named(name: str, fn: Callable) -> Callable:
+    """``fn`` as a function called ``name``.  jax names a jit-ed program
+    after its function's ``__name__``, and a ``functools.partial``, which
+    is what :func:`slot_model` binds, has none: every program of every
+    model would be ``jit__unknown``.  Nothing else of the program
+    changes."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class SlotModel(NamedTuple):
@@ -202,11 +228,12 @@ class DecodeEngine:
         self.pos = jnp.zeros((max_batch,), jnp.int32)
         # The state leaves both programs as it entered them: the same
         # buffers (donated), under the same sharding.
-        self._step = jax.jit(self.model.step, donate_argnums=(3,),
+        self._step = jax.jit(named(STEP_PROGRAM, self.model.step),
+                             donate_argnums=(3,),
                              out_shardings=(None, sharding))
         self._install = jax.jit(
-            partial(install, self.model), donate_argnums=(0,),
-            out_shardings=(None, sharding, None, None))
+            named(INSTALL_PROGRAM, partial(install, self.model)),
+            donate_argnums=(0,), out_shardings=(None, sharding, None, None))
         # A live slot moves one position on, clamped so that it can never
         # scatter out of bounds (it retires before the cap); a free slot
         # stays at 0, which is how a model's step can tell it is free.
@@ -217,6 +244,10 @@ class DecodeEngine:
         # Token vectors of steps dispatched and not read yet, oldest
         # first (device arrays; ServingLoop keeps at most one).
         self._unread: Deque[jax.Array] = deque()
+        # Steps dispatched so far: the ordinal (from 0) of the step the
+        # next dispatch() queues; the next read() is of step
+        # ``steps - unread``.
+        self.steps = 0
 
     def prefill(self, slot: int, prompt: List[int]) -> int:
         """Run the prompt through the model, install its state into the
@@ -224,7 +255,8 @@ class DecodeEngine:
         live from the next step() on."""
         fn = self._prefills.get(len(prompt))
         if fn is None:
-            fn = self._prefills[len(prompt)] = jax.jit(self.model.prefill)
+            fn = self._prefills[len(prompt)] = jax.jit(named(
+                f"{PREFILL_PROGRAM}_s{len(prompt)}", self.model.prefill))
         logits, request = fn(self.params, jnp.asarray(prompt, jnp.int32))
         first, self.state, self.tok, self.pos = self._install(
             self.state, self.tok, self.pos, np.int32(slot), logits,
@@ -270,6 +302,7 @@ class DecodeEngine:
         self.tok = nxt
         self.pos = self._advance(self.pos)
         self._unread.append(nxt)
+        self.steps += 1
 
     @property
     def unread(self) -> int:

@@ -47,6 +47,11 @@ position, lane and recurrent state.  Retirement by count is known before
 the dispatch; one by ``eos_id`` is not, and if it empties the table the
 step that ran ahead is read and dropped.
 ``hvd_serve_steps_ahead_total`` counts the steps dispatched ahead.
+Every dispatch has a ``serve.dispatch`` span and every read a
+``serve.read`` span, each with the ordinal ``step`` of the engine step it
+queues or reads: on a turn that ran ahead the read's is the dispatch's
+less one.  ``hvd_serve_read_wait_seconds`` takes the reads' lengths:
+over the turns' it is ``chip_wait_share`` on ``GET /stats``.
 
 Robustness is composed from the existing machinery, not rebuilt:
 
@@ -369,7 +374,8 @@ class ServingLoop:
         # device work so the step's collective shows the gap.
         _fi.fire("serve.step", str(seq))
         # serve.apply holds the turn; prefill, decode, confirm and emit
-        # are its disjoint leaves (docs/serving.md "Spans").
+        # are disjoint inside it, and decode holds dispatch and read
+        # (docs/serving.md "Spans").
         with _trace.span("serve.apply", step=seq,
                          admitted=len(admissions)):
             self._turn(seq, admissions, engine, rank0)
@@ -401,7 +407,7 @@ class ServingLoop:
                 _tmx.inc_counter("hvd_serve_prefill_tokens_total",
                                  len(prompt))
             if self._slots:
-                engine.dispatch()
+                self._dispatch(engine)
         else:
             # No step for an empty table: run ahead only if a slot will
             # outlive the unread vector (retirement by count is known
@@ -413,7 +419,7 @@ class ServingLoop:
             # ran ahead holds retired slots' rows only.  Nothing stays
             # unread while the loop sleeps.
             with _trace.span("serve.decode", slots=0):
-                engine.read()
+                self._read(engine)
         if rank0:
             t1 = time.monotonic()
             _tmx.observe("hvd_serve_token_latency_seconds", t1 - t0)
@@ -427,12 +433,13 @@ class ServingLoop:
         it to the ``live`` slots it was computed for: no token reaches a
         client before the gang agreed on it.  With ``ahead`` the next
         step is dispatched first, inside the same ``serve.decode`` span
-        (one span a vector read)."""
+        (one span a vector read; ``serve.dispatch`` and ``serve.read``
+        split it into the host's queueing and the wait for the chip)."""
         with _trace.span("serve.decode", slots=len(live)):
             if ahead:
-                engine.dispatch()
+                self._dispatch(engine)
                 _tmx.inc_counter("hvd_serve_steps_ahead_total")
-            toks = engine.read()
+            toks = self._read(engine)
         # The agreement allreduce's own collective spans share this
         # step's wall window; the serve.confirm span ties them to the
         # TAG_SERVE seq that caused them.
@@ -447,6 +454,23 @@ class ServingLoop:
         # sequences it against failure events).
         _bb.note("serve.confirm", confirm.t0, step=seq,
                  slots=len(self._slots))
+
+    @staticmethod
+    def _dispatch(engine: DecodeEngine) -> None:
+        """Queue engine step ``step``: ``serve.dispatch`` is what queueing
+        it costs the host (the step program and the three lazy ops)."""
+        with _trace.span("serve.dispatch", step=engine.steps):
+            engine.dispatch()
+
+    @staticmethod
+    def _read(engine: DecodeEngine) -> np.ndarray:
+        """Read engine step ``step``, the oldest unread: ``serve.read`` is
+        how long the loop waited for the chip.  On a turn that ran ahead
+        it is the step before the one just queued."""
+        with _trace.span("serve.read",
+                         histogram="hvd_serve_read_wait_seconds",
+                         step=engine.steps - engine.unread):
+            return engine.read()
 
     def _emit(self, slot: int, token: int, engine: DecodeEngine,
               rank0: bool) -> None:

@@ -293,8 +293,8 @@ class Scheduler:
                         1e3 * _tmx.histogram_quantile(h, 0.50), 3)
                     out[f"{key}_p99_ms"] = round(
                         1e3 * _tmx.histogram_quantile(h, 0.99), 3)
-            turns = hists.get("hvd_serve_token_latency_seconds",
-                              {}).get("count")
+            turn = hists.get("hvd_serve_token_latency_seconds", {})
+            turns = turn.get("count")
             counters = snap.get("counters", {})
             if turns:
                 # Share of turns that dispatched a step ahead of the
@@ -302,6 +302,13 @@ class Scheduler:
                 # on most turns, and the chip waiting for the host.
                 out["ahead_share"] = round(counters.get(
                     "hvd_serve_steps_ahead_total", 0.0) / turns, 4)
+            if turn.get("sum"):
+                # Share of the turns' time the loop spent waiting for
+                # the chip (serve.read): near 1 the chip sets the pace,
+                # near 0 the host does and the chip waits for it.
+                out["chip_wait_share"] = round(hists.get(
+                    "hvd_serve_read_wait_seconds",
+                    {}).get("sum", 0.0) / turn["sum"], 4)
             held = counters.get("hvd_serve_attn_positions_held_total")
             if held:
                 # Share of the slots' lanes the decode steps' attention

@@ -245,6 +245,11 @@ KNOWN_METRICS: Dict[str, dict] = {
         "Wall time of one turn of the serving loop: the unread step's "
         "readback, token-agreement allreduce and emit, the frame's "
         "prefills, the next step's dispatch.", *_SECONDS),
+    "hvd_serve_read_wait_seconds": _hist(
+        "Time the serving loop waited for the chip: one observation a "
+        "token vector read (a serve.read span, DecodeEngine.read()).  Its "
+        "sum over the sum of hvd_serve_token_latency_seconds is "
+        "chip_wait_share on GET /stats.", *_SECONDS),
     "hvd_serve_steps_ahead_total": _counter(
         "Decode steps dispatched while the step before was still unread "
         "(a turn without admissions); over the count of "

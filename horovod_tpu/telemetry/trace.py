@@ -222,11 +222,14 @@ class span:
     is the entry stamp (``time.monotonic_ns()`` axis, 0 = untimed) for
     a caller that hands it on (the flight recorder's ``serve.confirm``).
 
-    jax is imported on first entry, not with this module: the launcher
-    and numpy-only workers import the module and must stay off jax.
+    jax is imported on the first entry of the process, not with this
+    module (the launcher and numpy-only workers import the module and
+    must stay off jax), and ``TraceAnnotation`` is bound then, once: the
+    serving loop enters seven spans a turn.
     """
 
     __slots__ = ("phase", "args", "t0", "_histogram", "_annotation")
+    _annotate = None    # jax.profiler.TraceAnnotation, once entered
 
     def __init__(self, phase: str, histogram: Optional[str] = None,
                  **args):
@@ -236,12 +239,14 @@ class span:
         self._histogram = histogram
 
     def __enter__(self) -> "span":
-        from jax.profiler import TraceAnnotation
+        annotate = span._annotate
+        if annotate is None:
+            from jax.profiler import TraceAnnotation
 
+            annotate = span._annotate = TraceAnnotation
         if _TR is not None or (self._histogram and _tmx.enabled()):
             self.t0 = time.monotonic_ns()
-        self._annotation = TraceAnnotation("hvd:" + self.phase,
-                                           **self.args)
+        self._annotation = annotate("hvd:" + self.phase, **self.args)
         self._annotation.__enter__()
         return self
 

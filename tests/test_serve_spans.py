@@ -8,7 +8,11 @@ registry on, so the same run shows both sinks of every call site:
   every ``hvd:serve.*`` span; the loop thread's leaves are disjoint and
   lie inside ``serve.apply``; prefill and queue spans count one a request;
 * the rank's JSONL stream holds the same phases, the same number of times;
-* the two operator histograms are fed from the same spans.
+* the operator histograms are fed from the same spans;
+* ``serve.decode`` holds its two children, ``serve.dispatch`` (the host
+  queueing a step) and ``serve.read`` (the loop waiting for the chip), the
+  dispatch after a turn's prefills has a span too, and each carries the
+  ordinal ``step`` of the engine step it queues or reads.
 
 With neither sink on, ``span()`` reads no clock and writes nothing.  And a
 handler that is still waiting for a slot is released by ``fail_all``, by
@@ -34,6 +38,7 @@ from horovod_tpu.telemetry import trace
 LOOP_LEAVES = ("serve.frame", "serve.prefill", "serve.decode",
                "serve.confirm", "serve.emit")
 INSIDE_APPLY = LOOP_LEAVES[1:]
+IN_DECODE = ("serve.dispatch", "serve.read")    # serve.decode's children
 HANDLER = ("serve.queued", "serve.active")
 N_REQUESTS = 5          # over 2 slots: some wait for a slot
 MODEL = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=2, d_ff=64)
@@ -120,6 +125,7 @@ def served(tmp_path_factory):
         finally:
             jax.profiler.stop_trace()
         hists1 = tmx.snapshot()["histograms"]
+        stats = _http(box["port"], "GET", "/stats")[1]
     finally:
         loop.stop()
         thread.join(60)
@@ -150,7 +156,7 @@ def served(tmp_path_factory):
             if rec["k"] == "span":
                 jsonl.append(rec)
     return {"spans": sorted(spans, key=lambda s: s[2]), "jsonl": jsonl,
-            "hists": (hists0, hists1)}
+            "hists": (hists0, hists1), "stats": stats}
 
 
 def _count(hists, name):
@@ -158,7 +164,8 @@ def _count(hists, name):
 
 
 @pytest.mark.timeout(300)
-@pytest.mark.parametrize("phase", ("serve.apply",) + LOOP_LEAVES + HANDLER)
+@pytest.mark.parametrize("phase", ("serve.apply",) + LOOP_LEAVES + HANDLER
+                         + IN_DECODE)
 def test_every_span_is_in_the_profiler_trace(served, phase):
     assert any(s[0] == phase for s in served["spans"]), \
         sorted({s[0] for s in served["spans"]})
@@ -183,29 +190,103 @@ def test_turn_leaves_lie_inside_serve_apply(served):
         assert not any(a[2] < s[3] and s[2] < a[3] for a in applies), s
 
 
+def _by_start(records, phases):
+    """The stream's records of ``phases``, a span before what it holds."""
+    return sorted((r for r in records if r["ph"] in phases),
+                  key=lambda r: (r["t0"], -r["t1"]))
+
+
+def _inside(outer, records):
+    return [r for r in records if r is not outer
+            and outer["t0"] <= r["t0"] and r["t1"] <= outer["t1"]]
+
+
 def test_the_order_of_leaves_in_both_kinds_of_turn(served):
     """A turn without admissions is decode (the next step's dispatch, then
     the unread vector's readback), confirm, emit.  A turn with admissions
-    settles the unread vector the same way FIRST, then prefills on an
-    empty chip; the step it dispatches after them has no span of its own
-    (its readback is the next turn's ``serve.decode``)."""
-    spans = sorted((r for r in served["jsonl"]
-                    if r["ph"] in INSIDE_APPLY + ("serve.apply",)),
-                   key=lambda r: (r["t0"], -r["t1"]))
-    settle = ["serve.decode", "serve.confirm", "serve.emit"]
+    settles the unread vector the same way FIRST (a decode that holds a
+    read and no dispatch), then prefills on an empty chip, then
+    dispatches the step the next turn's ``serve.decode`` will read: that
+    dispatch lies outside any decode and has its span all the same."""
+    spans = _by_start(served["jsonl"],
+                      INSIDE_APPLY + IN_DECODE + ("serve.apply",))
+    ahead = ["serve.decode", "serve.dispatch", "serve.read",
+             "serve.confirm", "serve.emit"]
+    settle = ["serve.decode", "serve.read", "serve.confirm", "serve.emit"]
     seen = Counter()
     for turn in (r for r in spans if r["ph"] == "serve.apply"):
-        inner = [r["ph"] for r in spans if r is not turn
-                 and turn["t0"] <= r["t0"] and r["t1"] <= turn["t1"]]
-        prefills = ["serve.prefill"] * turn["admitted"]
-        if inner[:3] == settle:
-            assert inner[3:] == prefills, (turn, inner)
-            seen["settled", bool(prefills)] += 1
+        inner = [r["ph"] for r in _inside(turn, spans)]
+        admitted = ["serve.prefill"] * turn["admitted"] + ["serve.dispatch"]
+        if not turn["admitted"]:
+            # the last turn of a burst reads and queues nothing
+            assert inner in (ahead, settle), (turn, inner)
+            seen["ran ahead" if inner == ahead else "settled", False] += 1
+        elif inner[:4] == settle:
+            assert inner[4:] == admitted, (turn, inner)
+            seen["settled", True] += 1
         else:   # nothing was unread: the first turn after silence
-            assert prefills and inner == prefills, (turn, inner)
+            assert inner == admitted, (turn, inner)
             seen["nothing unread", True] += 1
-    assert all(seen[k] for k in (("settled", False), ("settled", True),
+    assert all(seen[k] for k in (("ran ahead", False), ("settled", True),
                                  ("nothing unread", True))), seen
+
+
+def test_dispatch_and_read_lie_inside_their_decode(served):
+    """In both sinks: every read is in a ``serve.decode``, which holds
+    one read and at most one dispatch before it; the only dispatches
+    outside a decode are those that follow a turn's prefills."""
+    for decodes, children in (
+            ([s[1:] for s in served["spans"] if s[0] == "serve.decode"],
+             [(s[0],) + s[1:] for s in served["spans"] if s[0] in IN_DECODE]),
+            ([(0, r["t0"], r["t1"]) for r in served["jsonl"]
+              if r["ph"] == "serve.decode"],
+             [(r["ph"], 0, r["t0"], r["t1"]) for r in served["jsonl"]
+              if r["ph"] in IN_DECODE])):
+        assert decodes and children
+        outside = Counter()
+        for phase, line, t0, t1 in children:
+            held = [d for d in decodes
+                    if d[0] == line and d[1] <= t0 and t1 <= d[2]]
+            assert len(held) <= 1
+            outside[phase] += not held
+        assert outside["serve.read"] == 0
+        for line, d0, d1 in decodes:
+            inner = [c[0] for c in children
+                     if c[1] == line and d0 <= c[2] and c[3] <= d1]
+            assert inner in (["serve.read"],
+                             ["serve.dispatch", "serve.read"]), inner
+    # the stream holds every turn since the engine was built: one
+    # dispatch outside a decode for every turn that admitted
+    admitting = sum(1 for r in served["jsonl"]
+                    if r["ph"] == "serve.apply" and r["admitted"])
+    assert outside["serve.dispatch"] == admitting > 0
+
+
+def test_step_pairs_a_dispatch_with_its_read_and_states_the_run_ahead(
+        served):
+    """``step`` is the ordinal of the engine step: the dispatches count
+    0, 1, 2 ... and so do the reads (every step is read once, in order,
+    and none is unread when the loop sleeps).  On a turn that ran ahead
+    the step read is the one BEFORE the step just queued."""
+    dispatches = _by_start(served["jsonl"], ("serve.dispatch",))
+    reads = _by_start(served["jsonl"], ("serve.read",))
+    assert [r["step"] for r in dispatches] == list(range(len(dispatches)))
+    assert [r["step"] for r in reads] == list(range(len(reads)))
+    assert len(reads) == len(dispatches) > 0
+    by_step = {r["step"]: r for r in reads}
+    ran_ahead = 0
+    for decode in _by_start(served["jsonl"], ("serve.decode",)):
+        inner = _inside(decode, dispatches + reads)
+        if len(inner) == 2:
+            queued, read = sorted(inner, key=lambda r: r["t0"])
+            assert (queued["ph"], read["ph"]) == ("serve.dispatch",
+                                                  "serve.read")
+            assert read["step"] == queued["step"] - 1
+            ran_ahead += 1
+    assert ran_ahead > 0
+    # a step is read after it was queued, never before
+    for d in dispatches:
+        assert by_step[d["step"]]["t0"] >= d["t1"]
 
 
 @pytest.mark.parametrize("phase", ("serve.prefill", "serve.queued",
@@ -249,6 +330,31 @@ def test_ttft_histograms_count_one_a_request(served, hist):
     assert _count(after, hist) - _count(before, hist) == N_REQUESTS
 
 
+def test_read_wait_histogram_takes_one_observation_a_read(served):
+    """``hvd_serve_read_wait_seconds`` is fed by the ``serve.read`` spans:
+    as many observations as reads in the session, and in all as many as
+    the stream holds, of the same total length."""
+    before, after = served["hists"]
+    hist = "hvd_serve_read_wait_seconds"
+    in_session = sum(s[0] == "serve.read" for s in served["spans"])
+    assert _count(after, hist) - _count(before, hist) == in_session > 0
+    reads = [r for r in served["jsonl"] if r["ph"] == "serve.read"]
+    assert _count(after, hist) == len(reads)
+    assert after[hist]["sum"] == pytest.approx(
+        sum(r["t1"] - r["t0"] for r in reads) * 1e-9)
+
+
+def test_stats_gives_the_share_of_the_turns_the_loop_waited_for_the_chip(
+        served):
+    _, after = served["hists"]
+    waited = after["hvd_serve_read_wait_seconds"]["sum"]
+    turns = after["hvd_serve_token_latency_seconds"]["sum"]
+    assert served["stats"]["chip_wait_share"] == round(waited / turns, 4)
+    # the reads lie inside the turns, beside their confirms and prefills
+    assert 0 < served["stats"]["chip_wait_share"] < 1
+    assert "ahead_share" in served["stats"]
+
+
 # ---------------------------------------------------------------------------
 # off means off
 # ---------------------------------------------------------------------------
@@ -277,11 +383,15 @@ def test_span_with_no_sink_reads_no_clock_and_writes_nothing(
     ct = _CountingTime()
     monkeypatch.setattr(trace, "time", ct)
     with trace.span("serve.decode", slots=3) as sp:
-        pass
+        with trace.span("serve.dispatch", step=7) as queued:
+            pass
+        with trace.span("serve.read", step=6,
+                        histogram="hvd_serve_read_wait_seconds") as read:
+            pass
     with trace.span("serve.prefill", histogram="hvd_serve_prefill_seconds",
                     slot=0, prompt_len=8):
         pass
-    assert ct.calls == 0 and sp.t0 == 0
+    assert ct.calls == 0 and sp.t0 == queued.t0 == read.t0 == 0
     assert os.listdir(tmp_path) == []
     # with a tracer the same call site reads the clock twice and records
     tr = trace.Tracer(0, str(tmp_path / "t.jsonl"))

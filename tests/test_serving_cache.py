@@ -34,9 +34,15 @@ weight on every call.  Pinned here:
   pins the same on the step compiled for the chip), a leaf already in its
   type held as the given buffer, ``hvd_serve_param_bytes{dtype}``;
 * a mesh: the held leaves keep the sharding of the given ones.
+
+And the names of the three programs an engine compiles (``serve_step``,
+``serve_prefill_s<N>``, ``serve_install``: what a profile's ``XLA Modules``
+line shows), with the programs otherwise what the unnamed functions lower
+to.
 """
 
 import inspect
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -297,6 +303,40 @@ def test_step_the_engine_compiles_converts_no_weight(built):
     assert weight_converts(engine.params) == []
     as_float32 = jax.tree.map(lambda a: a.astype(F32), given)
     assert len(weight_converts(as_float32)) >= len(cast)
+
+
+@pytest.mark.parametrize("program", ["step", "prefill", "install"])
+def test_engine_names_the_programs_it_compiles(built, program):
+    """The three programs carry the engine's own names, the same for
+    every model behind the seam (a profile's ``XLA Modules`` line and the
+    benchmark's ``readers/module_ms.py`` read them), and are otherwise, to
+    the letter, what the model's unnamed functions lower to."""
+    cfg, _, _, engine, _ = built
+    prompt = jnp.arange(1, 9, dtype=jnp.int32)
+    if program == "step":
+        name, named = decode.STEP_PROGRAM, engine._step
+        bare = jax.jit(engine.model.step, donate_argnums=(3,))
+        args = (engine.params, engine.tok, engine.pos, engine.state)
+    elif program == "prefill":
+        scratch = DecodeEngine(engine.params, cfg, max_batch=1, cache_len=S)
+        scratch.prefill(0, list(range(1, 9)))
+        name = f"{decode.PREFILL_PROGRAM}_s8"
+        (named,) = scratch._prefills.values()
+        bare = jax.jit(engine.model.prefill)
+        args = (engine.params, prompt)
+    else:
+        name, named = decode.INSTALL_PROGRAM, engine._install
+        bare = jax.jit(partial(decode.install, engine.model),
+                       donate_argnums=(0,))
+        logits, request = jax.eval_shape(
+            engine.model.prefill, engine.params, prompt)
+        args = (engine.state, engine.tok, engine.pos, np.int32(1), logits,
+                request, np.int32(8))
+    text = named.lower(*args).as_text()
+    assert text.startswith(f"module @jit_{name} "), text[:80]
+    unnamed = bare.lower(*args).as_text()
+    assert unnamed.startswith("module @jit__unknown ")   # why it is named
+    assert text.replace(f"@jit_{name} ", "@jit__unknown ", 1) == unnamed
 
 
 def test_leaf_already_in_its_type_is_held_as_the_given_buffer(built):
