@@ -68,9 +68,12 @@ class TransformerConfig:
     #   attention runs instead (XLA cannot split a pallas_call over heads
     #   or sequence) and the swap is logged: see warn_flash_runs_dense.
     attn_impl: str = "dense"
-    # Rematerialize each layer in the backward pass (jax.checkpoint).
-    # Costs ~1 extra forward of compute for O(1)-layer activation
-    # memory; turn off when the model fits without it.
+    # Rematerialize each layer in the backward pass (jax.checkpoint):
+    # a layer keeps its input and, where its attention is the flash
+    # kernel, the kernel's output and log-sum-exp (remat_layer); the rest
+    # of the layer's forward (norms, projections, rotary, feed-forward) is
+    # computed again.  Turn off when the model fits with every activation
+    # kept.
     remat: bool = True
 
     @property
@@ -406,6 +409,22 @@ def _layer(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     return x, aux, kept
 
 
+def remat_layer():
+    """:func:`_layer` under ``jax.checkpoint``: of a layer's forward pass
+    the backward pass is handed the layer's input and whatever carries one
+    of the attention kernel's names (ops/pallas_attention.py:SAVED_NAMES,
+    its output and log-sum-exp), and computes the rest again.  With those
+    two kept the re-forward has no use for the kernel's forward call, so
+    it runs once a layer a step.  Where the attention is not the kernel
+    (dense, ring, ulysses, or flash swapped for dense under tp or sp)
+    nothing carries a name and the layer's input alone is kept."""
+    from horovod_tpu.ops.pallas_attention import SAVED_NAMES
+
+    return jax.checkpoint(
+        _layer, static_argnums=(2, 3),
+        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+
+
 def apply(params: Params, tokens, cfg: TransformerConfig,
           *, mesh=None, remat: Optional[bool] = None):
     """Forward pass.  ``tokens``: [B, S] int32.  Returns
@@ -416,9 +435,7 @@ def apply(params: Params, tokens, cfg: TransformerConfig,
     x = params["embed"].astype(dtype)[tokens]
     x = _constrain(x, ACT_SPEC, mesh)
 
-    layer_fn = _layer
-    if remat:
-        layer_fn = jax.checkpoint(_layer, static_argnums=(2, 3))
+    layer_fn = remat_layer() if remat else _layer
 
     def body(carry, lp):
         h, aux_sum = carry

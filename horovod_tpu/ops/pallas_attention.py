@@ -39,6 +39,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
@@ -338,24 +339,44 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, out_f32):
+# The two residuals of the model's call that are dear to rebuild, by the
+# names a checkpoint policy saves them under (models/transformer.py:
+# remat_layer): the kernel's output and its log-sum-exp.  q, k and v carry
+# no name: a checkpointed layer recomputes them, three cheap matmuls.
+SAVED_NAMES = ("flash_o", "flash_lse")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, out_f32, named):
     return _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32):
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32,
+                   named):
     o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
-    return (o, lse), (q, k, v, o, lse)
+    # lse is kept as [BH, S]: stacked over a scan's layers in the kernel's
+    # [BH, S, 1] the chip would pad each row of one to a 128-lane tile.
+    rows = lse[..., 0]
+    if named:
+        # The NAMED o is also the primal output, so that under a policy
+        # that saves the names nothing downstream of the kernel asks the
+        # re-forward for it and the second flash_fwd call is dead code.
+        o = checkpoint_name(o, SAVED_NAMES[0])
+        rows = checkpoint_name(rows, SAVED_NAMES[1])
+    return (o, lse), (q, k, v, o, rows)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, out_f32, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k)
+def _flash_vjp_bwd(scale, causal, block_q, block_k, out_f32, named, res, g):
+    q, k, v, o, rows = res
+    return _flash_bwd((q, k, v, o, rows[..., None]), g, scale, causal,
+                      block_q, block_k)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _run_flash(q, k, v, causal, scale, block_q, block_k, out_f32=False):
+def _run_flash(q, k, v, causal, scale, block_q, block_k, out_f32=False,
+               named=False):
     B, S, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -365,7 +386,7 @@ def _run_flash(q, k, v, causal, scale, block_q, block_k, out_f32=False):
 
     o, lse = _flash(fold(q), fold(k), fold(v), float(scale),
                     bool(causal), int(block_q), int(block_k),
-                    bool(out_f32))
+                    bool(out_f32), bool(named))
     o = jnp.moveaxis(o.reshape(B, H, S, D), 1, 2)
     lse = jnp.moveaxis(lse.reshape(B, H, S), 1, 2)   # [B, S, H]
     return o, lse
@@ -377,9 +398,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     """Blockwise flash attention.  ``q/k/v``: [B, S, H, D].
 
     Returns [B, S, H, D] context.  Differentiable (custom VJP running the
-    flash backward kernels).
+    flash backward kernels).  Its output and log-sum-exp carry the names
+    :data:`SAVED_NAMES` among the backward kernels' residuals: a
+    ``jax.checkpoint`` whose policy saves those names runs the forward
+    kernel once, not again in its re-forward.
     """
-    o, _ = _run_flash(q, k, v, causal, scale, block_q, block_k)
+    o, _ = _run_flash(q, k, v, causal, scale, block_q, block_k, named=True)
     return o
 
 
@@ -393,6 +417,8 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     ``parallel.ring_attention`` chains this kernel across ``sp`` hops.
     Both outputs carry gradients (the lse cotangent adds the ``p·dlse``
     term in the backward kernels).  The partial output is emitted in
-    fp32 (no per-hop rounding when partials are combined)."""
+    fp32 (no per-hop rounding when partials are combined).  Its
+    residuals carry no name: a checkpointed ring keeps none of its
+    ``sp`` hops' partials."""
     return _run_flash(q, k, v, causal, scale, block_q, block_k,
                       out_f32=True)

@@ -138,9 +138,7 @@ def pipeline_apply(params, tokens, cfg: tfm.TransformerConfig, mesh,
                                   "the pipeline body (pp > 1)")
         cfg = dataclasses.replace(cfg, attn_impl="dense")
 
-    layer_fn = tfm._layer
-    if remat:
-        layer_fn = jax.checkpoint(tfm._layer, static_argnums=(2, 3))
+    layer_fn = tfm.remat_layer() if remat else tfm._layer
 
     def body(params, tokens):
         dtype = cfg.compute_dtype

@@ -175,6 +175,20 @@ def test_flash_kernels_keep_their_names_in_the_lowered_step(probes, kernel):
         assert stems <= {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, names
 
 
+@pytest.mark.parametrize("i", range(len(MESHES)),
+                         ids=[json.dumps(m) for m in MESHES])
+def test_lowered_lm_step_runs_the_forward_kernel_once_a_layer(probes, i):
+    """A checkpointed layer keeps the kernel's output and log-sum-exp
+    (``transformer.remat_layer``), so the backward scan's body holds no
+    second ``flash_fwd``: the compiled step has as many of them as of
+    ``flash_bwd_dq``, one in each scan's body."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    names = json.loads(out.split("RESULT", 1)[1])["kernel_names"][i]
+    stems = [n.rsplit(".", 1)[0] for n in names]
+    assert stems.count("flash_fwd") == stems.count("flash_bwd_dq") > 0, names
+
+
 @pytest.mark.parametrize("program,update", [
     ("step", "fusion:scatter"), ("install", "fusion:dynamic-update-slice")])
 def test_serving_cache_programs_update_in_place_on_the_chip(

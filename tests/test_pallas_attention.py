@@ -106,3 +106,63 @@ def test_transformer_flash_under_dp_mesh(jax, eight_devices):
     ld, _ = tfm.apply(params, toks, cfg_d)
     np.testing.assert_allclose(np.asarray(lf), np.asarray(ld),
                                rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("attn_impl", ["flash", "dense"])
+def test_checkpointed_layer_keeps_the_kernels_two_results(jax, attn_impl):
+    """``remat`` changes what a layer keeps, never the numbers: the loss
+    and every gradient leaf are those of the step that keeps everything.
+    A checkpointed flash layer keeps its arguments, the kernel's output
+    and its log-sum-exp (so its re-forward does not call the kernel
+    again); a dense one keeps what a bare ``jax.checkpoint`` keeps."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from horovod_tpu.models import transformer as tfm
+
+    B, S, H, D = 2, 64, 4, 8
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=H * D, n_layers=2, n_heads=H, d_ff=64,
+        max_seq_len=S, compute_dtype=jnp.float32, attn_impl=attn_impl)
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    toks = jnp.asarray(
+        np.random.RandomState(0).randint(0, 64, (B, S)), jnp.int32)
+
+    def loss_and_grads(remat):
+        return jax.value_and_grad(tfm.loss_fn)(
+            params, toks, jnp.roll(toks, -1, 1),
+            dataclasses.replace(cfg, remat=remat))
+
+    (kept_loss, kept), (loss, grads) = map(loss_and_grads, (False, True))
+    np.testing.assert_allclose(float(loss), float(kept_loss), rtol=1e-6)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(kept)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-7)
+
+    x = jnp.ones((B, S, H * D), jnp.float32)
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+
+    def residuals(layer):
+        return [(str(aval), src) for aval, src in saved_residuals(
+            lambda x, lp: layer(x, lp, cfg, None)[0], x, lp)]
+
+    saved = residuals(tfm.remat_layer())
+    made = [r for r in saved if "from the argument" not in r[1]]
+    assert ("float32[2,64,32]", "from the argument x") in saved
+    if attn_impl == "dense":
+        assert saved == residuals(
+            jax.checkpoint(tfm._layer, static_argnums=(2, 3)))
+        assert not made
+    else:
+        (o, o_src), (lse, lse_src) = made
+        assert o == f"float32[{B * H},{S},{D}]", made
+        assert "pallas_attention.py" in o_src, made
+        assert lse == f"float32[{B * H},{S}]", made
+        assert "named 'flash_lse'" in lse_src, made
+        # the step's program: one forward kernel call a layer, in the
+        # forward scan's body; none in the backward scan's
+        step = str(jax.make_jaxpr(lambda p: loss_and_grads(True))(params))
+        assert step.count("name=flash_fwd") == step.count(
+            "name=flash_bwd_dq") > 0

@@ -96,3 +96,38 @@ def test_transformer_ring_attention_matches_dense(eight_devices):
     np.testing.assert_allclose(np.asarray(ring_logits),
                                np.asarray(dense_logits),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_checkpointed_ring_layer_keeps_no_hop(eight_devices):
+    """The ring calls the flash kernel once a hop through the rule that
+    names the model's own call's output and log-sum-exp for a layer's
+    checkpoint.  A hop's carry no name, so a checkpointed ring layer keeps
+    what a bare ``jax.checkpoint`` keeps, its arguments, and not ``sp``
+    hops' partial outputs."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.ops.pallas_attention import SAVED_NAMES
+
+    mesh = mesh_mod.make_mesh({"sp": 4}, devices=eight_devices[:4])
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+        max_seq_len=32, compute_dtype=jnp.float32, attn_impl="ring")
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    x = jnp.ones((2, 32, 32), jnp.float32)
+
+    def residuals(layer):
+        return [(str(aval), src) for aval, src in saved_residuals(
+            lambda x, lp: layer(x, lp, cfg, mesh)[0], x, lp)]
+
+    saved = residuals(tfm.remat_layer())
+    assert saved == residuals(
+        jax.checkpoint(tfm._layer, static_argnums=(2, 3)))
+    assert all("from the argument" in src for _, src in saved), saved
+    # the names sit in the kernel's forward RULE, which only a
+    # differentiated trace runs: the hops do run it, and name nothing
+    grad = str(jax.make_jaxpr(jax.grad(
+        lambda x, lp: tfm._layer(x, lp, cfg, mesh)[0].sum()))(x, lp))
+    assert "flash_bwd_dq" in grad
+    assert not any(name in grad for name in SAVED_NAMES)
