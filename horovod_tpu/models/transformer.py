@@ -232,6 +232,10 @@ def _softmax_attention(q, k, v, valid, cfg: TransformerConfig):
 def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     """Causal self-attention, the model's only one.  x: [B, S, D].
 
+    ``lp`` holds the input projections as ``init`` makes them (``wq``,
+    ``wk``, ``wv``, each [D, H, HD]: three products) or as a serving
+    engine holds them (``wqkv`` [D, 3 H HD], :func:`serving_params`: one
+    product, split into q, k, v afterwards); which is read off its keys.
     ``cache`` None: the S positions (0 to S - 1) attend among themselves
     by ``cfg.attn_impl``; returns (out, (k, v)) with the rotated k, v
     [B, S, H, HD] for whoever keeps them.
@@ -248,9 +252,14 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     B, S, D = x.shape
     dtype = cfg.compute_dtype
     own = None if cache is None else cache[3][:, None]   # [B, 1] positions
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
-    kk = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
-    v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
+    if "wqkv" in lp:
+        qkv = jnp.einsum("bsd,df->bsf", x, lp["wqkv"].astype(dtype))
+        q, kk, v = (a.reshape(B, S, cfg.n_heads, cfg.head_dim)
+                    for a in jnp.split(qkv, 3, axis=-1))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
+        kk = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(dtype))
+        v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(dtype))
     q = _rope(q, cfg.rope_theta, own)
     kk = _rope(kk, cfg.rope_theta, own)
     if cache is None:
@@ -525,10 +534,11 @@ def count_attention_reads(counters, pos, cache_len: int, n_layers: int,
 
 
 def _refuse_experts(cfg: TransformerConfig):
-    """Whoever makes a state says so first: dense-FFN configs only
-    (``n_experts=0``).  ``_moe_ffn`` drops rows over an expert's
-    capacity, which ties a slot's output to its neighbours'; the experts
-    that are served (models/latent_moe.py) drop nothing."""
+    """Whoever makes a state, or holds parameters for one, says so first:
+    dense-FFN configs only (``n_experts=0``).  ``_moe_ffn`` drops rows
+    over an expert's capacity, which ties a slot's output to its
+    neighbours'; the experts that are served (models/latent_moe.py) drop
+    nothing."""
     if cfg.n_experts:
         raise NotImplementedError(
             "cached decode (generate() and serving) supports dense-FFN "
@@ -679,21 +689,50 @@ def generate(params, prompt, cfg: TransformerConfig, *,
 # operands of the matmuls (the experts' alike) and the tied embedding.  The
 # norm gains and the router are used in float32 and are not among them.
 COMPUTE_DTYPE_LEAVES = frozenset(
-    {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
+    {"embed", "wq", "wk", "wv", "wqkv", "wo", "w_in", "w_gate", "w_out"})
+
+
+def _heads_split(leaf) -> bool:
+    """Whether ``leaf`` [L, D, H, HD] lies split over its heads (under
+    ``param_specs`` with ``tp`` > 1).  A traced leaf has no sharding."""
+    sharding = getattr(leaf, "sharding", None)
+    return (sharding is not None
+            and sharding.shard_shape(leaf.shape)[2] != leaf.shape[2])
 
 
 def serving_params(params: Params, cfg: TransformerConfig) -> Params:
     """``params`` as a serving engine holds them: each of
     COMPUTE_DTYPE_LEAVES rounded to ``cfg.compute_dtype`` once, every other
-    leaf as given.  The rounding is the one the forward's cast at the use
-    makes, so decode_step and prefill_request return from this pytree, to
-    the bit, what they return from ``params``, and their cast is then a
-    no-op: inside decode_step's layer scan XLA otherwise hoists the casts
-    of ALL layers' float32 weights out of the loop and runs them, whole,
-    on every call.  A leaf already in the compute type comes back as the
-    same buffer, and a sharded leaf keeps its sharding (``astype``)."""
+    leaf as given, and the three input projections of the attention as
+    ONE leaf ``wqkv`` [L, D, 3 H HD] (q's columns, then k's, then v's).
+    The rounding is the one the forward's cast at the use makes, so
+    decode_step and prefill_request return from this pytree what they
+    return from ``params``, and their cast is then a no-op: inside
+    decode_step's layer scan XLA otherwise hoists the casts of ALL layers'
+    float32 weights out of the loop and runs them, whole, on every call.
+    A leaf already in the compute type comes back as the same buffer, and
+    a sharded leaf keeps its sharding (``astype``).
+
+    Why one leaf: the chip's compiler stages each layer's ``wq``, ``wk``
+    and ``wv`` in fast memory with an op of its own before the windowed
+    convolution that ``"bsd,dhk->bshk"`` becomes, a seventh of a decode
+    step of the 8-layer OLMo-1B, while the plain 2-D product
+    ``"bsd,df->bsf"`` reads its layer out of the stack in place, as
+    ``wo``'s and the feed-forward's do (serving/decode.py has the compile
+    probe's account).  Where the given ``wq`` lies split over its heads
+    (``tp`` > 1) the joined columns would not split by heads, and the
+    three are held as given."""
+    _refuse_experts(cfg)
+
     def held(path, leaf):
         cast = path[-1].key in COMPUTE_DTYPE_LEAVES
         return leaf.astype(cfg.compute_dtype) if cast else leaf
 
-    return jax.tree_util.tree_map_with_path(held, params)
+    out = jax.tree_util.tree_map_with_path(held, params)
+    layers = out["layers"]
+    if "wq" in layers and not _heads_split(layers["wq"]):
+        L, D = layers["wq"].shape[:2]
+        three = [layers.pop(name) for name in ("wq", "wk", "wv")]
+        layers["wqkv"] = jnp.concatenate(
+            [w.reshape(L, D, -1) for w in three], axis=-1)
+    return out

@@ -54,13 +54,33 @@ cast at the use, the decode step's layer scan has the casts of ALL
 layers' weights hoisted out of the loop by XLA and run whole on every
 turn, and every prefill once more: for the benchmark's 8-layer OLMo-1B
 2.56 GB read and 1.28 GB written before the first matmul, a step of
-12.5 ms on a TPU v5e where it now takes 6.7.  A leaf already in its
+12.5 ms on a TPU v5e where it then took 6.7.  A leaf already in its
 use's type is held as the same buffer (models/jamba.py, whose weights
 come in ``param_dtype``, holds what it is given), a sharded leaf keeps
 its sharding, and the engine keeps no reference to a leaf it replaced:
 the float32 original lives as long as the caller's own reference
 (``ServingLoop.params``, for the engine of a re-formed gang).
 ``hvd_serve_param_bytes{dtype}`` says what is held.
+
+The form of a leaf is the model's to choose as well as its type.  The
+dense decoder holds its attention's three input projections ``wq``,
+``wk``, ``wv`` ``[L, D, H, HD]`` as ONE leaf ``wqkv`` ``[L, D, 3 H HD]``
+(joined once, beside the cast; the same bytes), and its attention makes
+one product where it finds that key and three where it finds the three
+(training and ``generate`` on ``init``'s parameters).  The reason is the
+chip's compiler: ``"bsd,dhk->bshk"`` lowers as a convolution with a
+window over the heads, into which XLA does not fold the layer scan's
+slice of the stack, so each layer's 8.4 MB ``wq``, ``wk`` and ``wv`` were
+staged in fast memory by an op of their own before their products (three
+``constant_dynamic-slice_fusion`` of ``bf16[1,2048,16,128]``, 12.5 us
+each: 0.30 ms of a 2.1 ms step in the benchmark's OLMo-1B cell); three
+2-D leaves ``[L, D, H HD]`` compile to the same (the reshape folds back
+into the product), a 5-D ``[L, D, 3, H, HD]`` too; the plain product
+``"bsd,df->bsf"`` takes the whole stack and the layer's index, as
+``wo``'s and the feed-forward's do (pinned on the step and the prefill
+compiled for the chip by tests/test_chip_smoke.py).  Where the given
+``wq`` lies split over its heads (``tp`` > 1) joined columns would not
+split by heads and the three are held as given.
 
 Under a mesh the state shards by the model's ``STATE_SPEC`` (the dense
 decoder's: KV_CACHE_SPEC, heads over ``tp``), applied with ``filter_spec``
@@ -142,7 +162,8 @@ class SlotModel(NamedTuple):
     * ``spec``: the state's PartitionSpec pytree, or None (no mesh)
     * ``held(params)`` -> the ``params`` that ``prefill`` and ``step``
       take: each leaf the forward casts at its use, in the type of that
-      cast; a leaf already in it is the given buffer
+      cast and the form its products read in place; a leaf already so is
+      the given buffer
     """
     init_state: Callable
     prefill: Callable

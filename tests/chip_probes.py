@@ -47,14 +47,19 @@ def probe_bare_init():
     hvd.shutdown()
 
 
-def serve_cache_programs(cfg, slots, min_elems, sharding=None):
+def serve_cache_programs(cfg, slots, min_elems, sharding=None,
+                         weight_elems=None):
     """The two programs that write the serving slots' state (the decode
     step and the install that ends a prefill) as ``DecodeEngine`` builds
-    them for ``cfg``'s model from the weights its ``init`` makes, compiled
-    from shapes alone for the default device or for ``sharding``'s: what
-    each produces of ``min_elems`` elements or more (:func:`big_ops`), its
-    temporaries, its aliased bytes and what it converts to the compute
-    type (:func:`converts_to`)."""
+    them for ``cfg``'s model from the parameters it HOLDS of those its
+    ``init`` makes, compiled from shapes alone for the default device or
+    for ``sharding``'s: what each produces of ``min_elems`` elements or
+    more (:func:`big_ops`), its temporaries, its aliased bytes and what it
+    converts to the compute type (:func:`converts_to`).  With
+    ``weight_elems``, the size of one layer's weight matrix, also the
+    prefill of a ``PROMPT``-token request, and under ``"weight_ops"``
+    what each program produces of that size or more: a layer's weight
+    cut out of its stack before the product that reads it."""
     from functools import partial
 
     import jax
@@ -81,6 +86,9 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
                            donate_argnums=(0,)).lower(
             state, spec((slots,)), spec((slots,)), spec(()),
             spec((cfg.vocab_size,), jnp.float32), request, spec(()))}
+    if weight_elems is not None:
+        lowered["prefill"] = jax.jit(model.prefill).lower(
+            params, spec((PROMPT,)))
     out = {}
     for name, program in lowered.items():
         compiled = program.compile()
@@ -91,7 +99,15 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None):
                          text, jnp.dtype(cfg.compute_dtype).name),
                      "temp_bytes": mem.temp_size_in_bytes,
                      "alias_bytes": mem.alias_size_in_bytes}
+        if weight_elems is not None:
+            out[name]["weight_ops"] = big_ops(text, weight_elems)
     return out
+
+
+# A prompt short enough that no activation of its prefill is as large as a
+# layer's projection at SERVE_CACHE's widths (128 x 3 x 2048 elements
+# against 2048 x 2048).
+PROMPT = 128
 
 
 # The benchmark's cache shape (32 slots x 1536 x 16 heads of 128) with two
@@ -192,7 +208,9 @@ def probe_lower_for_tpu(meshes_json):
         serve_cache = pool.submit(
             serve_cache_programs, cfg, slots,
             slots * cfg.max_seq_len * cfg.d_model,      # one layer's lane
-            one_chip)
+            one_chip,
+            # one layer's wq, wk or wv
+            cfg.d_model * cfg.n_heads * cfg.head_dim)
         serve_state = pool.submit(
             serve_cache_programs, jcfg, state_slots,
             # one layer's recurrent state: [slots, d_state, d_inner]
@@ -259,11 +277,13 @@ def big_ops(hlo_text, min_elems):
 # (models/transformer.py's COMPUTE_DTYPE_LEAVES, spelled out: the tests hold
 # the model to it; models/jamba.py upcasts a_log, dt_bias, d and its
 # convolution to float32 and is not in the list with them).
-DENSE_CAST_LEAVES = frozenset(
+SHARED_CAST_LEAVES = frozenset(
     {"embed", "wq", "wk", "wv", "wo", "w_in", "w_gate", "w_out"})
-JAMBA_CAST_LEAVES = DENSE_CAST_LEAVES | {
+# The dense decoder's engine holds wq, wk and wv as one leaf, wqkv.
+DENSE_CAST_LEAVES = SHARED_CAST_LEAVES | {"wqkv"}
+JAMBA_CAST_LEAVES = SHARED_CAST_LEAVES | {
     "in_proj", "x_proj", "dt_proj", "out_proj"}
-RETENTION_CAST_LEAVES = DENSE_CAST_LEAVES | {"head", "wg"}
+RETENTION_CAST_LEAVES = SHARED_CAST_LEAVES | {"head", "wg"}
 LATENT_MOE_CAST_LEAVES = frozenset(
     {"embed", "head", "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "wo", "w_in",
      "w_gate", "w_out", "shared_in", "shared_gate", "shared_out"})

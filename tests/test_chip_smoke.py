@@ -210,6 +210,30 @@ def test_serving_cache_programs_update_in_place_on_the_chip(
     assert got["alias_bytes"] == 2 * c["n_layers"] * lane_bytes + 2 * 512, got
 
 
+@pytest.mark.parametrize("program,update", [
+    ("step", "fusion:scatter"), ("prefill", "fusion:dynamic-update-slice")])
+def test_dense_decoder_reads_its_projections_in_place_on_the_chip(
+        probes, program, update):
+    """The engine holds ``wq``, ``wk`` and ``wv`` as one leaf, so its step
+    and its prefill, compiled for ``v5e`` at the benchmark's widths from
+    the HELD parameters, make one plain 2-D product of them that takes
+    the stack and the layer's index: nothing as large as one layer's
+    projection comes out of either besides the writes of the keys and
+    values (the step's row scatters into the caches, the prefill's
+    stacking of its layers' lanes) and the compiler's own asynchronous
+    moves into fast memory.  Held as three ``[L, D, H, HD]`` leaves, each
+    layer's three are cut out of their stacks by a ``dynamic-slice``
+    fusion of their own before a windowed convolution reads them."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_cache"][program]
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done",
+                "custom-call"}
+    ops = [op for _, op in got["weight_ops"]]
+    assert ops.count(update) == 2, got
+    assert set(ops) <= {update} | prefetch, got
+
+
 @pytest.mark.parametrize("program,updates", [
     ("step", {"fusion:dynamic-update-slice", "fusion:scatter"}),
     ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
@@ -329,8 +353,9 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
                             retention.RetentionConfig,
                             chip_probes.RETENTION_CAST_LEAVES)}[probe]
     cfg = config(**{k: v for k, v in sizes.items() if k != "slots"})
-    weights = chip_probes.weight_dims(
-        jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0)),
-        cast)
+    given = jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0))
+    held = jax.eval_shape(lambda p: model.serving_params(p, cfg), given)
+    weights = (chip_probes.weight_dims(given, cast)
+               | chip_probes.weight_dims(held, cast))
     assert converts, "the step rounds its activations at least"
     assert [d for d in converts if chip_probes.dims_key(d) in weights] == []

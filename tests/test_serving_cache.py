@@ -22,18 +22,24 @@ donated and update them in place.  What is pinned here, on the CPU:
 And the parameters an engine holds (``engine.params``): every leaf the
 model's forward casts to the compute type at its use is held in that type,
 rounded once when the engine is built, so that no program converts a
-weight on every call.  Pinned here:
+weight on every call; the dense decoder's ``wq``, ``wk`` and ``wv`` are held
+as one leaf, ``wqkv``, so that its step and its prefill make one product of
+them and read it in place from the stack (``tests/test_chip_smoke.py`` pins
+that on the programs compiled for the chip).  Pinned here:
 
 * the mathematics: ``decode_step`` and ``prefill_request`` return from the
-  held pytree, to the bit, what they return from the float32 one, on a
-  model whose norm gains bfloat16 cannot hold (a cast of every leaf moves
-  the logits);
+  held pytree, to the bit, what they return from the float32 one and from
+  the three leaves in the compute type, on a model whose norm gains bfloat16
+  cannot hold (a cast of every leaf moves the logits);
 * the built engine, dense and ``models/jamba.py``'s: no float32 leaf where
   the forward casts, nothing in the step as the engine hands it to the
   compiler that converts to a weight's shape (``tests/test_chip_smoke.py``
   pins the same on the step compiled for the chip), a leaf already in its
   type held as the given buffer, ``hvd_serve_param_bytes{dtype}``;
-* a mesh: the held leaves keep the sharding of the given ones.
+* a mesh: the held leaves keep the sharding of the given ones, and under
+  ``tp`` the three projections stay three;
+* training and ``generate`` on ``init``'s parameters: three products, the
+  programs they were.
 
 And the names of the three programs an engine compiles (``serve_step``,
 ``serve_prefill_s<N>``, ``serve_install``: what a profile's ``XLA Modules``
@@ -272,17 +278,43 @@ def _named(params):
             in jax.tree_util.tree_leaves_with_path(params)]
 
 
+QKV = ("wq", "wk", "wv")
+
+
+def _three_leaves(params, cfg, cast):
+    """``params`` with every leaf in ``cast`` in the compute type and
+    nothing joined: what the dense decoder's engine held before ``wqkv``,
+    and holds under ``tp``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: (a.astype(cfg.compute_dtype)
+                         if path[-1].key in cast else a), params)
+
+
+def _joined(layers):
+    """q's columns, then k's, then v's: [L, D, 3 H HD]."""
+    return jnp.concatenate(
+        [layers[name].reshape(*layers[name].shape[:2], -1) for name in QKV],
+        axis=-1)
+
+
 def test_engine_holds_in_the_compute_type_what_the_forward_casts(built):
     cfg, given, cast, engine, _ = built
-    assert jax.tree.structure(engine.params) == jax.tree.structure(given)
-    for (name, held), (_, was) in zip(_named(engine.params), _named(given)):
+    want = dict(_named(_three_leaves(given, cfg, cast)))
+    if isinstance(cfg, tfm.TransformerConfig):
+        want["wqkv"] = _joined(want)
+        for name in QKV:
+            del want[name]
+        assert want["wqkv"].shape == (L, H * HD, 3 * H * HD)
+    held = dict(_named(engine.params))
+    assert sorted(held) == sorted(want)
+    was = dict(_named(given))
+    for name, leaf in held.items():
         if name in cast:
-            assert held.dtype == cfg.compute_dtype, name
-            np.testing.assert_array_equal(
-                held, was.astype(cfg.compute_dtype), err_msg=name)
+            assert leaf.dtype == cfg.compute_dtype, name
+            np.testing.assert_array_equal(leaf, want[name], err_msg=name)
         else:
-            assert held is was, name
-    assert {name for name, _ in _named(given)} >= cast
+            assert leaf is was[name], name
+    assert set(was) | {"wqkv"} >= cast
 
 
 def test_step_the_engine_compiles_converts_no_weight(built):
@@ -291,7 +323,7 @@ def test_step_the_engine_compiles_converts_no_weight(built):
     whole stacked leaf's, one layer's slice's, or a transpose of either)
     by a convert; the float32 pytree's step has one for every use."""
     cfg, given, cast, engine, _ = built
-    weights = weight_dims(given, cast)
+    weights = weight_dims(given, cast) | weight_dims(engine.params, cast)
 
     def weight_converts(params):
         text = engine._step.lower(
@@ -301,8 +333,10 @@ def test_step_the_engine_compiles_converts_no_weight(built):
                 if dims_key(dims) in weights]
 
     assert weight_converts(engine.params) == []
-    as_float32 = jax.tree.map(lambda a: a.astype(F32), given)
-    assert len(weight_converts(as_float32)) >= len(cast)
+    for form in (given, engine.params):     # three projections, and one
+        as_float32 = jax.tree.map(lambda a: a.astype(F32), form)
+        assert len(weight_converts(as_float32)) >= len(
+            [name for name, _ in _named(form) if name in cast])
 
 
 @pytest.mark.parametrize("program", ["step", "prefill", "install"])
@@ -340,11 +374,19 @@ def test_engine_names_the_programs_it_compiles(built, program):
 
 
 def test_leaf_already_in_its_type_is_held_as_the_given_buffer(built):
-    cfg, _, _, engine, _ = built
+    """The held form given again is held as it is, ``wqkv`` too; of three
+    projections in the compute type only the joined leaf is new."""
+    cfg, given, cast, engine, _ = built
     again = DecodeEngine(engine.params, cfg, max_batch=1, cache_len=S)
     for held, was in zip(jax.tree.leaves(again.params),
                          jax.tree.leaves(engine.params)):
         assert held is was
+    three = _three_leaves(given, cfg, cast)
+    was = dict(_named(three))
+    fresh = dict(_named(
+        DecodeEngine(three, cfg, max_batch=1, cache_len=S).params))
+    assert {name for name in fresh if fresh[name] is not was.get(name)} == (
+        {"wqkv"} if isinstance(cfg, tfm.TransformerConfig) else set())
 
 
 def test_param_bytes_gauge_reads_what_the_engine_holds_by_dtype(built):
@@ -361,8 +403,10 @@ def test_param_bytes_gauge_reads_what_the_engine_holds_by_dtype(built):
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_request"])
 def test_held_parameters_give_the_float32_results_to_the_bit(program):
-    cfg, given, _ = _dense_bf16()
+    cfg, given, cast = _dense_bf16()
     held = tfm.serving_params(given, cfg)
+    assert "wqkv" in held["layers"] and not set(QKV) & set(held["layers"])
+    three = _three_leaves(given, cfg, cast)
     gains = [given["ln_f"], given["layers"]["ln1"], given["layers"]["ln2"]]
     assert all((g.astype(BF16).astype(F32) != g).any() for g in gains)
     every_leaf = jax.tree.map(lambda a: a.astype(BF16), given)
@@ -377,10 +421,12 @@ def test_held_parameters_give_the_float32_results_to_the_bit(program):
         def run(params):
             prompt = jnp.asarray([3, 14, 15, 9, 26, 5], jnp.int32)
             return tfm.prefill_request(params, prompt, cfg, S)
-    want, got, blanket = (jax.jit(run)(p) for p in (given, held, every_leaf))
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert a.dtype == b.dtype
-        np.testing.assert_array_equal(a, b)
+    want, got, apart, blanket = (
+        jax.jit(run)(p) for p in (given, held, three, every_leaf))
+    for other in (want, apart):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(other)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
     assert not np.array_equal(blanket[0], want[0])
 
 
@@ -396,10 +442,42 @@ def test_mesh_keeps_the_sharding_of_the_parameters_it_casts():
                                       _named(sharded)):
         assert held.sharding.is_equivalent_to(was.sharding, was.ndim), name
         assert held.dtype == (BF16 if name in cast else F32), name
-    assert len(engine.params["layers"]["wq"].sharding.device_set) == 2
+    # heads over tp: joined columns would not split by heads, so the three
+    # projections are held as given
+    assert "wqkv" not in engine.params["layers"]
+    for name in QKV:
+        held = engine.params["layers"][name]
+        assert len(held.sharding.device_set) == 2, name
+        assert held.sharding.shard_shape(held.shape) == (L, H * HD, H // 2, HD)
     plain = DecodeEngine(given, cfg, max_batch=B, cache_len=S)
+    assert "wqkv" in plain.params["layers"]
     assert engine.prefill(2, [5, 14, 15, 9]) == plain.prefill(2, [5, 14, 15, 9])
     np.testing.assert_array_equal(engine.step()[2], plain.step()[2])
+
+
+@pytest.mark.parametrize("program", ["apply", "loss_fn", "generate"])
+def test_init_parameters_lower_to_three_products_and_no_joined_one(program):
+    """Training and ``generate`` take ``init``'s parameters, and
+    ``_attention`` reads off the keys which products to make: their
+    programs hold the three ``[D, H, HD]`` products and nothing of the
+    joined width, which only the held form's program has."""
+    cfg, given, _ = _dense_bf16()
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    run = {"apply": lambda p: tfm.apply(p, tokens, cfg),
+           "loss_fn": lambda p: tfm.loss_fn(p, tokens, tokens, cfg),
+           "generate": lambda p: tfm.generate(
+               p, tokens, cfg, max_new_tokens=4)}[program]
+    joined_width = f"x{3 * H * HD}x"                    # tensor<...x96xbf16>
+    projection = f"tensor<{H * HD}x{H}x{HD}xbf16>"      # one layer's wq
+    text = jax.jit(run).lower(given).as_text()
+    assert joined_width not in text
+    assert text.count(projection) >= 3
+    held = tfm.serving_params(given, cfg)
+    joined = jax.jit(run).lower(held).as_text()
+    assert joined_width in joined and projection not in joined
+    for a, b in zip(jax.tree.leaves(jax.jit(run)(held)),
+                    jax.tree.leaves(jax.jit(run)(given))):
+        np.testing.assert_array_equal(a, b)
 
 
 # -- the seam: what a model module presents, and the one builder ----------------
