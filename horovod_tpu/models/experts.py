@@ -7,7 +7,10 @@ for a serving turn's few dozen:
 1. ``s = sigmoid(x W_r)`` in float32; the ``k`` experts of a row are the
    top-k of ``s + b`` (``b``: a bias that *selects only*), their weights
    ``scale * s_i / (sum of the chosen s + 1e-20)`` (the ``noaux_tc``
-   router of the DeepSeek-V3 line, one group: no group limit);
+   router of the DeepSeek-V3 line).  With ``n_group`` > 1 the choice is
+   limited to groups: the experts lie in ``n_group`` groups of equal
+   size, a group's score is the sum of its two largest ``s + b``, and
+   only the ``topk_group`` best groups' experts stand for the top-k;
 2. the ``rows x k`` (row, expert) pairs are sorted by expert, the rows
    gathered in that order, and three ``jax.lax.ragged_dot`` products (up,
    gate, down) run over the groups: an expert with no row costs nothing,
@@ -27,6 +30,16 @@ Rows marked not ``live`` (a serving batch's free slots) are routed
 nowhere: they sort behind the last group, no expert's weights are read
 for them, and their output is zero.
 
+**A share of the experts.**  The stack may hold fewer experts than the
+router has outputs: experts ``first`` to ``first + E`` of a layer divided
+over chips (``E`` is the stack's own second axis).  The routing is over
+all of them; a pair whose expert lies on another chip goes the way of a
+free slot's (behind the last group, no product, zero), and what comes back
+is this chip's part of the sum.  Over all the shares of a layer the parts
+add up to the whole layer's output (tests/test_sparse_latent_moe.py);
+nothing here stands in for the other chips or for the exchange with
+them.
+
 ``stats`` counts what was really routed, as ``[3]`` int32: (row, expert)
 pairs, experts with at least one row, the fullest expert's rows.
 
@@ -45,28 +58,46 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def route(x, router, bias, top_k: int, scale: float
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(x, router, bias, top_k: int, scale: float, n_group: int = 1,
+          topk_group: int = 1) -> Tuple[jax.Array, jax.Array]:
     """x: [T, D] -> (chosen [T, k] int32, weights [T, k] float32).
-    Selection by the biased score, weight from the unbiased one."""
+    Selection by the biased score (inside the ``topk_group`` best of
+    ``n_group`` groups where there are groups), weight from the unbiased
+    one."""
     s = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", x.astype(jnp.float32), router.astype(jnp.float32),
         preferred_element_type=jnp.float32))
-    _, chosen = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    biased = s + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = biased.shape
+        grouped = biased.reshape(T, n_group, E // n_group)
+        best_two, _ = lax.top_k(grouped, 2)
+        _, kept = lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+        biased = jnp.where(keep[:, :, None], grouped, -jnp.inf
+                           ).reshape(T, E)
+    _, chosen = lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     weights = scale * picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
     return chosen, weights
 
 
 def routed_ffn(x, experts, layer, chosen, weights, dtype,
-               live: Optional[jax.Array] = None):
+               live: Optional[jax.Array] = None,
+               first: Optional[int] = None):
     """x: [T, D]; ``experts``: ``w_in``, ``w_gate`` [L, E, D, F] and
     ``w_out`` [L, E, F, D]; ``layer``: which of the L (may be traced);
     ``chosen``, ``weights``: [T, k] from :func:`route`; ``live``: [T]
-    bool or None (all).  Returns (y [T, D] in ``dtype``, stats [3])."""
+    bool or None (all); ``first``: None where the stack holds every
+    expert the router chooses among, else the router's output that the
+    stack's expert 0 answers to (a share: ``first`` to ``first + E``).
+    Returns (y [T, D] in ``dtype``, stats [3])."""
     T, k = chosen.shape
     L, E = experts["w_in"].shape[:2]
     flat = chosen.reshape(T * k)
+    if first is not None:       # a share: the others' pairs go behind too
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < E), flat, E)
     if live is not None:
         flat = jnp.where(jnp.repeat(live, k), flat, E)     # behind every group
     order = jnp.argsort(flat, stable=True)                 # pairs by expert
@@ -84,8 +115,8 @@ def routed_ffn(x, experts, layer, chosen, weights, dtype,
     ys = grouped(h, experts["w_out"])
     # Back to (row, choice) order.  Pairs behind the last group were in
     # no product: what the rows hold there is not a number to weigh.
-    routed = jnp.arange(T * k) < jnp.sum(counts)
-    ys = jnp.where(routed[:, None], ys, 0)[jnp.argsort(order)]
+    in_a_group = jnp.arange(T * k) < jnp.sum(counts)
+    ys = jnp.where(in_a_group[:, None], ys, 0)[jnp.argsort(order)]
     y = jnp.sum(ys.reshape(T, k, -1).astype(jnp.float32)
                 * weights[..., None], axis=1)
     stats = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),

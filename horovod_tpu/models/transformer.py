@@ -184,14 +184,17 @@ def _rmsnorm(x, g, eps: float = 1e-6):
     return (y * g).astype(x.dtype)
 
 
-def _rope(x, theta: float, pos=None):
+def _rope(x, theta, pos=None):
     """Rotary embedding over head_dim pairs; x: [B, S, H, HD].
+    ``theta``: the base, pair i turning by ``theta^(-i / (HD/2))`` a
+    position, or the table [HD/2] of those frequencies itself (a scaled
+    one: models/latent_moe.py's ``rope_frequencies``).
     ``pos``: the absolute positions, [S] (shared by the rows) or [B, S]
     (a row's own: a serving slot rotates its one new token at its own
     offset); default ``arange(S)``."""
     B, S, H, HD = x.shape
     half = HD // 2
-    freqs = jnp.exp(
+    freqs = theta if hasattr(theta, "shape") else jnp.exp(
         -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
     if pos is None:
         pos = jnp.arange(S, dtype=jnp.float32)

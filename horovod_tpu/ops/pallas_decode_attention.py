@@ -40,6 +40,15 @@ slots have written and not what the table could hold.
   attends the keys of head h alone, by the mask; the MXU then does the
   per-head sums and the context comes out ``[H, HD]`` with nothing to
   transpose.
+* **A selection.**  With ``select`` (what ``ops/pallas_index_select.py``
+  returns: every position's index score, and each slot's cut) a block's
+  positions that are not among the slot's selected are masked like those
+  past ``pos``: the softmax runs over the selected positions alone.  The
+  lane is still walked block by block: a selection by position is
+  scattered over every block, and fetching a lane's rows one by one cost
+  more than reading it whole (XLA's gather of 24 x 2048 rows of 1 KB:
+  2.0 ms a layer on a TPU v5e, 42 ns a row, against 0.54 for this walk
+  of 16 lanes of 14k positions under 128 heads: PERF.md, section 6).
 * Scores, maximum and sum in float32; probabilities and the context's
   operands in the cache's type.  The partial last block is masked by
   position in the scores AND in the value rows, so nothing past
@@ -110,13 +119,29 @@ def work_list(pos, cache_len: int, block: int):
             pos.astype(jnp.int32), pairs_run(pos, block))
 
 
+def ordered(x):
+    """float32 -> int32 with the same order (an involution on the bits)."""
+    u = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(u < 0, u ^ 0x7fffffff, u)
+
+
+def selected(scores, cut, tie, position):
+    """Whether ``position`` (int32, broadcastable to ``scores``) is among
+    a slot's selected, from its index ``scores`` there and its ``cut`` and
+    ``tie`` (ops/pallas_index_select.py): a score above the cut, or at it
+    and no further on than the tie's position."""
+    u = ordered(scores)
+    return (u > cut) | ((u == cut) & (position <= tie))
+
+
 def _kernel(layer_ref, slot_ref, blk_ref, pos_ref, *refs,
-            last, own_value, group, scale, block):
+            last, own_value, group, scale, block, selects):
     """One (slot, block) pair.  ``refs``: the slot's query parts
     [Hq, Dk_i], the block of each key part ([keys, Dk_i], or [Dk_i, keys]
     where ``last[i]``) and of the value [keys, Dv] if it is an array of
-    its own, the slot's output [Hq, Dv], and the softmax's maximum, sum
-    and accumulator."""
+    its own, with ``selects`` the block's index scores [1, keys] and the
+    slot's cut and tie [1, 128], the slot's output [Hq, Dv], and the
+    softmax's maximum, sum and accumulator."""
     n_parts = len(last)
     q_refs, k_refs = refs[:n_parts], refs[n_parts:2 * n_parts]
     v_ref = refs[2 * n_parts] if own_value else k_refs[0]
@@ -145,6 +170,11 @@ def _kernel(layer_ref, slot_ref, blk_ref, pos_ref, *refs,
     if group > 1:
         row = lax.broadcasted_iota(jnp.int32, (hq, keys), 0)
         ok = jnp.logical_and(ok, col % group == row)
+    if selects:
+        score_ref, cut_ref, tie_ref = refs[-7:-4]
+        ok = jnp.logical_and(ok, selected(
+            score_ref[...], cut_ref[:, :1], tie_ref[:, :1],
+            j * block + lax.broadcasted_iota(jnp.int32, (1, keys), 1)))
     s = jnp.where(ok, s, _NEG_INF)
     held = j * block + lax.broadcasted_iota(
         jnp.int32, (keys, 1), 0) // group <= at
@@ -167,7 +197,8 @@ def _kernel(layer_ref, slot_ref, blk_ref, pos_ref, *refs,
 def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
                      value: Optional[jax.Array], layer, pos, *,
                      scale: float, block: Optional[int] = None, work=None,
-                     positions_last: Optional[Sequence[bool]] = None):
+                     positions_last: Optional[Sequence[bool]] = None,
+                     select=None):
     """Attention of one query a slot over that slot's lane, up to ``pos``.
 
     ``q``: the query's parts, each ``[B, Hq, Dk_i]``.  ``keys``: the
@@ -184,7 +215,11 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
     with float32 scores and sums.  ``block`` (positions a fetch, a divisor
     of Smax) defaults to :func:`block_for`.  ``work``: :func:`work_list`
     of (pos, Smax, block), from a caller that made it once for all the
-    layers of its step.
+    layers of its step.  ``select``: (scores [B, 1, Smax], cut, tie
+    [B, 1, 128]) of ``ops/pallas_index_select.py:index_select`` for the
+    same slots and block: slot b attends, of its positions, the selected
+    alone (heads share a position's key); the kernel is then named
+    ``sparse_attn`` in the compiled step and in a profile.
     """
     B, hq = q[0].shape[:2]
     last = tuple(positions_last or (False,) * len(keys))
@@ -198,6 +233,9 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
         block = block_for(smax, shared=value is None)
     if smax % block:
         raise ValueError(f"block {block} does not divide the lane {smax}")
+    if select is not None and heads_own:
+        raise ValueError("a selection is of positions whose key the heads "
+                         "share")
     caches = list(keys) + ([] if value is None else [value])
     if heads_own:       # [L, B, Smax, H, HD] read as [L, B, Smax * H, HD]
         caches = [a.reshape(*a.shape[:2], smax * group, a.shape[4])
@@ -216,12 +254,20 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
     def of_pair_last(i, layer_ref, slot_ref, blk_ref, pos_ref):
         return layer_ref[0], slot_ref[i], 0, blk_ref[i]
 
+    def of_scores(i, layer_ref, slot_ref, blk_ref, pos_ref):
+        return slot_ref[i], 0, blk_ref[i]
+
+    select_specs = [] if select is None else [
+        pl.BlockSpec((None, 1, block), of_scores),
+        pl.BlockSpec((None, 1, select[1].shape[-1]), of_slot),
+        pl.BlockSpec((None, 1, select[2].shape[-1]), of_slot)]
     out = _pallas_call(
-        "decode_attn",
+        "decode_attn" if select is None else "sparse_attn",
         functools.partial(
             _kernel, last=last, own_value=value is not None, group=group,
-            scale=scale, block=block),
+            scale=scale, block=block, selects=select is not None),
         jnp.asarray(layer, jnp.int32).reshape(1), slot, blk, at, *q, *caches,
+        *(select or ()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(pairs,),
             in_specs=[pl.BlockSpec((None, hq, a.shape[-1]), of_slot)
@@ -229,7 +275,8 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
             + [pl.BlockSpec((None, None, a.shape[2], block), of_pair_last)
                if t else
                pl.BlockSpec((None, None, block * group, a.shape[-1]), of_pair)
-               for a, t in zip(caches, last + (False,))],
+               for a, t in zip(caches, last + (False,))]
+            + select_specs,
             out_specs=pl.BlockSpec((None, hq, dv), of_slot),
             scratch_shapes=[_vmem((hq, 1)), _vmem((hq, 1)),
                             _vmem((hq, dv))]),

@@ -15,6 +15,9 @@ of state a slot holds:
 * ``"recurrent"``: fixed-size state with no mask (a state-space layer's).
   The install overwrites ALL of a slot's, so nothing of its last tenant
   reaches the next (pinned by tests/test_jamba.py).
+* ``"index"``: position-indexed keys of a learned indexer, one a position
+  a layer beside the ``"kv"`` lane it selects from (models/latent_moe.py
+  with ``index_topk``); read as far as the slot's position, like a lane.
 * ``"counters"``: a dict of the registry's counter names to uint32
   scalars that the model's step adds to ON THE DEVICE (what it really
   routed; what its attention read of the lanes).  No slot owns them, an install passes them through, and
@@ -29,7 +32,7 @@ state;
 models/latent_moe.py holds as ``"kv"`` a latent and one rotary key a
 position, ``[L, max_batch, cache_len, 512]`` and ``[..., 64]`` where a
 full-width cache would be 20 heads x (256 + 256), beside its routing's
-counters.
+counters, and with an indexer a third array ``"index"`` ``[..., 128]``.
 The state is ONE resident set of buffers: the two programs that write it,
 the jit-ed step (B new rows, or one recurrent update, a layer) and the
 install that ends a prefill (one slot's share), take it donated and
@@ -128,7 +131,7 @@ from horovod_tpu.models import retention as R
 from horovod_tpu.models import transformer as T
 from horovod_tpu.telemetry import registry as _tmx
 
-STATE_KINDS = ("kv", "recurrent")
+STATE_KINDS = ("kv", "recurrent", "index")
 
 # What the engine's three compiled programs are called, in HLO
 # (``module @jit_serve_step``) and on a profile's ``XLA Modules`` line.  A

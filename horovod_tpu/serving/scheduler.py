@@ -316,6 +316,14 @@ class Scheduler:
                 # of full lanes, or the masked read under tp / sp).
                 out["attn_read_share"] = round(counters.get(
                     "hvd_serve_attn_positions_read_total", 0.0) / held, 4)
+            scored = counters.get("hvd_serve_index_positions_scored_total")
+            if scored:
+                # Share of the positions the live slots have written that
+                # the attention saw after the indexer's selection
+                # (models/latent_moe.py with index_topk).
+                out["attn_selected_share"] = round(counters.get(
+                    "hvd_serve_attn_positions_selected_total",
+                    0.0) / scored, 4)
             held = counters.get("hvd_serve_state_rows_held_total")
             if held:
                 # Share of the slots whose recurrent state the decode

@@ -198,9 +198,10 @@ KNOWN_METRICS: Dict[str, dict] = {
         "token costs."),
     "hvd_serve_state_bytes": _gauge(
         "Bytes of slot state the decode engine holds, by kind: kv "
-        "(position-indexed keys and values) and recurrent (fixed-size "
-        "state-space and convolution state); set when the engine is "
-        "built.", ("kind",)),
+        "(position-indexed keys and values), recurrent (fixed-size "
+        "state-space and convolution state) and index (a learned "
+        "indexer's key a position); set when the engine is built.",
+        ("kind",)),
     "hvd_moe_rows_routed_total": _counter(
         "(row, expert) pairs the decode steps routed: live rows x experts "
         "a token x expert layers, a step.  The four hvd_moe_* counters are "
@@ -217,6 +218,12 @@ KNOWN_METRICS: Dict[str, dict] = {
         "touched, the straggler a grouped product waits for."),
     "hvd_moe_layer_turns_total": _counter(
         "Expert layers stepped: expert layers x decode steps."),
+    "hvd_moe_rows_absent_total": _counter(
+        "(row, expert) pairs of live rows whose expert this chip does not "
+        "hold (models/latent_moe.py with experts_held: a share of the "
+        "routed experts, routed over all of them), summed like the other "
+        "hvd_moe_* counters.  Over this plus hvd_moe_rows_routed_total it "
+        "is the share of the routing that other chips' experts answer."),
     "hvd_serve_attn_positions_read_total": _counter(
         "Positions of the slots' lanes in the blocks the decode steps' "
         "attention fetched, summed over layers and steps: a slot's lane "
@@ -229,6 +236,16 @@ KNOWN_METRICS: Dict[str, dict] = {
         "over layers and steps: what a masked read of the whole cache "
         "reads.  hvd_serve_attn_positions_read_total over this is "
         "attn_read_share on GET /stats."),
+    "hvd_serve_index_positions_scored_total": _counter(
+        "Positions a learned indexer scored (models/latent_moe.py with "
+        "index_topk: what the live slots have written, position + 1 a "
+        "slot), summed over layers and steps on the device like the "
+        "hvd_moe_* counters."),
+    "hvd_serve_attn_positions_selected_total": _counter(
+        "Positions the decode steps' attention saw after the indexer's "
+        "selection (min(index_topk, position + 1) a live slot), summed "
+        "the same way.  Over hvd_serve_index_positions_scored_total it is "
+        "attn_selected_share on GET /stats."),
     "hvd_serve_state_rows_live_total": _counter(
         "Slots with a request in them (position > 0) whose recurrent state "
         "a decode step read and wrote, summed over layers and steps "

@@ -136,6 +136,25 @@ SERVE_LATENT = dict(slots=64, vocab_size=1024, intermediate_size=512,
                     n_routed_experts=8, max_seq_len=4608)
 
 
+# The deepseek-v3.2 cell's lanes (24 slots x 18432 positions x a latent
+# of 512, a rotary key of 64 and an index key of 128), its attention's and
+# its indexer's widths and its routing (256 outputs in 8 groups, 16 experts
+# held), with three layers, a narrow feed-forward and a narrow vocabulary,
+# so that the probe compiles in seconds.
+SERVE_SPARSE = dict(slots=24, vocab_size=1024, hidden_size=7168,
+                    intermediate_size=512, moe_intermediate_size=256,
+                    num_hidden_layers=3, num_attention_heads=128,
+                    q_lora_rank=1536, qk_nope_head_dim=128, v_head_dim=128,
+                    n_routed_experts=256, num_experts_per_tok=8, n_group=8,
+                    topk_group=4, experts_held=16, index_n_heads=64,
+                    index_head_dim=128, index_topk=2048, rope_theta=10000.0,
+                    rope_scaling={"type": "yarn", "factor": 40,
+                                  "beta_fast": 32, "beta_slow": 1,
+                                  "mscale": 1, "mscale_all_dim": 1,
+                                  "original_max_position_embeddings": 4096},
+                    max_seq_len=18432)
+
+
 # The brumby-14b cell's state (32 slots x 8 key/value heads x a
 # [128, 8320] float32 matrix a layer) and its retention's widths, with two
 # layers and a narrow feed-forward and vocabulary, so that the probe
@@ -197,6 +216,9 @@ def probe_lower_for_tpu(meshes_json):
     latent_sizes = dict(SERVE_LATENT)
     latent_slots = latent_sizes.pop("slots")
     lcfg = latent_moe.LatentMoEConfig(**latent_sizes)
+    sparse_sizes = dict(SERVE_SPARSE)
+    sparse_slots = sparse_sizes.pop("slots")
+    scfg = latent_moe.LatentMoEConfig(**sparse_sizes)
     retention_sizes = dict(SERVE_RETENTION)
     retention_slots = retention_sizes.pop("slots")
     rcfg = retention.RetentionConfig(**retention_sizes)
@@ -219,6 +241,9 @@ def probe_lower_for_tpu(meshes_json):
             serve_cache_programs, lcfg, latent_slots,
             # one layer's lane of latents: [slots, cache_len, kv_lora_rank]
             latent_slots * lcfg.max_seq_len * lcfg.kv_lora_rank, one_chip)
+        serve_sparse = pool.submit(
+            serve_cache_programs, scfg, sparse_slots,
+            sparse_slots * scfg.max_seq_len * scfg.kv_lora_rank, one_chip)
         serve_retention = pool.submit(
             serve_cache_programs, rcfg, retention_slots,
             # one layer's state matrices: [slots, KVH, head_dim, rows]
@@ -230,6 +255,7 @@ def probe_lower_for_tpu(meshes_json):
         "serve_cache": serve_cache.result(),
         "serve_state": serve_state.result(),
         "serve_latent": serve_latent.result(),
+        "serve_sparse": serve_sparse.result(),
         "serve_retention": serve_retention.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))

@@ -294,6 +294,34 @@ def test_latent_lanes_programs_update_in_place_on_the_chip(
 
 
 @pytest.mark.parametrize("program,updates", [
+    ("step", {"fusion:scatter"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_sparse_lanes_programs_update_in_place_on_the_chip(
+        probes, program, updates):
+    """models/latent_moe.py with an indexer and a share of its experts,
+    compiled for ``v5e`` at the ``deepseek-v3.2`` cell's lane shapes and
+    attention widths (128 heads on one latent, 64 index heads, the 2048
+    best of 18432 positions): Mosaic takes the two kernels (``index_select``
+    with its 46 counting passes in fast memory, ``sparse_attn``), and the
+    step and the install produce nothing of one layer's lane of latents
+    besides the in-place updates of the three caches they were given; the
+    three caches and the nine counters are aliased from input to
+    output."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_sparse"][program]
+    c = chip_probes.SERVE_SPARSE
+    lane_bytes = 2 * c["slots"] * c["max_seq_len"] * 512        # bfloat16
+    held = c["num_hidden_layers"] * (
+        lane_bytes + lane_bytes // 8 + lane_bytes // 4) + 9 * 512
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    assert got["temp_bytes"] < lane_bytes, got
+    assert got["alias_bytes"] == held, got
+
+
+@pytest.mark.parametrize("program,updates", [
     ("step", {"custom-call"}),
     ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
 def test_retention_state_programs_pass_over_the_state_once_on_the_chip(
