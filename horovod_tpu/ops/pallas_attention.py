@@ -24,11 +24,35 @@ lowered for any other platform (tests run on the CPU backend) the same
 kernel body is interpreted.  The choice follows the lowering platform, not
 ``jax.default_backend()``: see :func:`_pallas_call`.
 
-Tuning (measured on one TPU v5e chip, B=8 S=1024 H=16 D=64 bf16):
-dot inputs keep their storage dtype (f32 upcasts before the dots ran
-the MXU at its multi-pass fp32 rate) and the default blocks are
-512x512 — together fwd+bwd went 15.0 ms → 7.8 ms vs 45.4 ms for the
-XLA dense-softmax path on the same shapes.
+The schedule of the three calls (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``; the arithmetic is the recipe's):
+
+* **A list of pairs is the grid** (:func:`block_pairs`, made at trace
+  time, in scalar memory): a causal call steps the (query block, key
+  block) pairs that hold a key at or under a query and no other, so the
+  pairs above the diagonal are neither stepped nor fetched (a ``pl.when``
+  around the body would skip their work and still fetch their blocks); a
+  non-causal call, a ring hop, lists every pair.
+  Every listed pair of a causal call is masked, as on the full grid: a
+  second, unmasked body for the pairs wholly under the diagonal read
+  0.00 ms at the shape below and was not kept.
+* **The per-row numbers lie along the lanes.**  The log-sum-exp, ``delta``
+  and an lse cotangent travel as ``[BH, S // bq, 1, bq]`` (:func:`_rows`),
+  never with a trailing dimension of one, which the chip pads to 128
+  lanes.  ``flash_bwd_dkv`` holds its scores transposed (keys down,
+  queries along the lanes), so a query block's row broadcasts over them as
+  it comes and ``dv``, ``dk`` are plain products; ``flash_fwd`` and
+  ``flash_bwd_dq`` turn a column into a row, or back, once a query block.
+
+Dot inputs keep their storage dtype (f32 upcasts before the dots ran the
+MXU at its multi-pass fp32 rate); the default blocks are 512x512.  Measured
+alone on one TPU v5e chip at the LM training cell's shape (B=8 S=2048 H=16
+D=128 bf16, causal; ``tools/flash_attn_probe.py``, PR 43; ms a call, and
+the share of the call's 2, 3 and 4 products' time at the chip's peak):
+``flash_fwd`` 2.43 (29 %), ``flash_bwd_dq`` 1.95 (54 %), ``flash_bwd_dkv``
+2.22 (63 %); 2.72, 2.80 and 3.63 on the full grid with ``[BH, S, 1]``
+vectors.  The rows' layout gave -0.30 and -0.97 ms to the two backward
+calls, the list of pairs -0.32, -0.55 and -0.45 to the three.
 """
 
 from __future__ import annotations
@@ -39,26 +63,64 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
+# What a step of a call's schedule is, beside its pair of blocks: the first
+# or the last of its row (the blocks that share the call's accumulator).
+_FIRST, _LAST = 1, 2
 
-def _block_needed(qi, ki, block_q, block_k, causal):
-    """False only for key blocks strictly above the causal diagonal."""
-    if not causal:
-        return True
-    return ki * block_k < (qi + 1) * block_q
+# The most pairs a call lists.  Its three vectors ride in scalar memory,
+# which the arithmetic grid before them did not need, so there is a ceiling
+# now and this names it: 4096 pairs (three vectors of 16 KB) Mosaic compiles
+# for a v5e, non-causal S 32768 at the default blocks and S 8192 in blocks
+# of 128; more was not tried.  Every shape the repo runs is under 100.  An S
+# that ``_pick_block`` can only cut into tiny blocks meets it first.
+MAX_PAIRS = 4096
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+@functools.lru_cache(maxsize=None)
+def block_pairs(seq_len: int, block_q: int, block_k: int, causal: bool,
+                by_key: bool = False):
+    """The (query block, key block) pairs one call steps for one head, in
+    the order it steps them, as three int32 vectors (query block, key
+    block, flags) that ride in scalar memory: the call's second grid
+    dimension is their LENGTH, its block index maps read the first two and
+    its body the third.  A causal call lists the pairs that hold a key at
+    or under some query's position and no other (10 of 16 at four blocks a
+    row), so a pair wholly above the diagonal is neither stepped nor
+    fetched; a non-causal call (a ring hop) lists them all.  A query
+    block's pairs are consecutive with their key blocks ascending
+    (``by_key``: a key block's, query blocks ascending: ``flash_bwd_dkv``)
+    and the flags say where such a row starts and ends.  The vectors are
+    shared between calls (the cache) and read-only; more than
+    :data:`MAX_PAIRS` pairs is refused by name."""
+    pairs = [(qi, ki) for qi in range(seq_len // block_q)
+             for ki in range(seq_len // block_k)
+             if not causal or ki * block_k < (qi + 1) * block_q]
+    if len(pairs) > MAX_PAIRS:
+        raise ValueError(
+            f"flash attention over seq_len {seq_len} in blocks of {block_q} x "
+            f"{block_k} steps {len(pairs)} pairs a head, more than the "
+            f"{MAX_PAIRS} its schedule holds in scalar memory: take larger "
+            f"blocks, or pad seq_len to a multiple of them")
+    row = 1 if by_key else 0
+    pairs.sort(key=lambda pair: (pair[row], pair[1 - row]))
+    flags = []
+    for n, (qi, ki) in enumerate(pairs):
+        first = n == 0 or pairs[n - 1][row] != pairs[n][row]
+        last = n + 1 == len(pairs) or pairs[n + 1][row] != pairs[n][row]
+        flags.append(_FIRST * first | _LAST * last)
+    columns = tuple(np.asarray(column, np.int32)
+                    for column in (*zip(*pairs), flags))
+    for column in columns:
+        column.setflags(write=False)
+    return columns
 
 
 def _pick_block(seq_len: int, want: int) -> int:
@@ -86,99 +148,139 @@ def _pallas_call(name, kernel, *args, **kwargs):
         *args, tpu=branch(False), default=branch(True))
 
 
+def _vmem(shape):
+    return pltpu.VMEM(shape, jnp.float32)
+
+
+def _scores(a, b):
+    """``a b^T`` in float32.  Dot inputs keep their storage dtype (bf16 in
+    the flagship model) so the MXU runs at its native rate; accumulation
+    is always f32 via preferred_element_type."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _product(a, b):
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, q_start, k_start, q_axis):
+    """``s`` with the keys past their query's position at ``_NEG_INF``;
+    its queries lie along ``q_axis`` from position ``q_start``, its keys
+    along the other from ``k_start``."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                               1 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _pair(qi_ref, ki_ref, flag_ref):
+    """This grid step of a call over :func:`block_pairs`: its query block,
+    its key block, whether it is the first and the last of its row."""
+    i = pl.program_id(1)
+    flags = flag_ref[i]
+    return qi_ref[i], ki_ref[i], flags & _FIRST != 0, flags & _LAST != 0
+
+
+def _grid_spec(pairs, batch_heads, in_specs, out_specs, scratch_shapes):
+    """A call over ``pairs`` (:func:`block_pairs`) for every head: the
+    three vectors are scalar-prefetch operands, the grid (heads, pairs)."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(pairs), grid=(batch_heads, len(pairs[0])),
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+
+
+# Block index maps of a call over pairs: (head, step, query blocks, key
+# blocks, flags) -> the block of a [BH, S, D] operand that goes with the
+# step's query block or key block, and of a [BH, S // bq, 1, bq] vector of
+# per-row numbers (below).
+def _of_q(b, i, qi_ref, ki_ref, flag_ref):
+    return b, qi_ref[i], 0
+
+
+def _of_k(b, i, qi_ref, ki_ref, flag_ref):
+    return b, ki_ref[i], 0
+
+
+def _of_q_row(b, i, qi_ref, ki_ref, flag_ref):
+    return b, qi_ref[i], 0, 0
+
+
+def _rows(x, block_q):
+    """``[BH, S]`` per-row numbers (log-sum-exp, delta, an lse cotangent)
+    as the kernels take and give them: ``[BH, S // bq, 1, bq]``, a query
+    block's numbers along the LANES of a ``(1, bq)`` block whose two minor
+    dimensions are the array's own, so it tiles whatever ``bq`` is.  A
+    trailing dimension of one (``[BH, S, 1]``, blocks ``(bq, 1)``) the chip
+    pads to 128 lanes: 134 MB a layer at the training cell's shape for
+    1 MB of numbers, fetched on every step of ``flash_bwd_dkv``."""
+    return x.reshape(x.shape[0], x.shape[1] // block_q, 1, block_q)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref,
+                lse_ref, m_scr, l_scr, acc_scr, *, scale, causal):
+    qi, ki, first, last = _pair(qi_ref, ki_ref, flag_ref)
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: key blocks strictly above the diagonal contribute nothing.
-    needed = _block_needed(qi, ki, block_q, block_k, causal)
+    v = v_ref[...]                                      # [bk, d]
+    s = _scores(q_ref[...], k_ref[...]) * scale         # [bq, bk]
+    if causal:
+        s = _causal_mask(s, qi * block_q, ki * block_k, 0)
+    # Softmax math is f32.
+    m_prev = m_scr[...]                                 # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)                              # [bq, bk]
+    corr = jnp.exp(m_prev - m_new)                      # [bq, 1]
+    l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = corr * acc_scr[...] + _product(p.astype(v.dtype), v)
+    m_scr[...] = m_new
 
-    @pl.when(needed)
-    def _tile():
-        # Dot inputs keep their storage dtype (bf16 in the flagship
-        # model) so the MXU runs at its native rate; accumulation is
-        # always f32 via preferred_element_type.  Softmax math is f32.
-        q = q_ref[0]                               # [bq, d]
-        k = k_ref[0]                               # [bk, d]
-        v = v_ref[0]                               # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        m_prev = m_scr[:]                          # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                     # [bq, bk]
-        corr = jnp.exp(m_prev - m_new)             # [bq, 1]
-        l_scr[:] = corr * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = corr * acc_scr[:] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
-        l = l_scr[:]
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l)         # [bq, 1]
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
+        # the one turn of a column into a row, once a QUERY block
+        lse_ref[...] = (m_scr[...] + jnp.log(l)).T          # [1, bq]
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32=False):
+    """``(o [BH, S, D], lse [BH, S // bq, 1, bq])``: :func:`_rows`."""
     BH, S, D = q.shape
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
-    grid = (BH, S // bq, S // bk)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk)
-    o, lse = _pallas_call(
-        "flash_fwd", kernel, q, k, v,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            # lse rides as [BH, S, 1]: a 2-D (1, bq) block over [BH, S]
-            # is not Mosaic-tileable (second-minor must be 8-divisible
-            # or the full dim); a trailing singleton lane dim is.
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
+    pairs = block_pairs(S, bq, bk, causal)
+    return _pallas_call(
+        "flash_fwd",
+        functools.partial(_fwd_kernel, scale=scale, causal=causal),
+        *pairs, q, k, v,
+        grid_spec=_grid_spec(
+            pairs, BH,
+            in_specs=[pl.BlockSpec((None, bq, D), _of_q),
+                      pl.BlockSpec((None, bk, D), _of_k),
+                      pl.BlockSpec((None, bk, D), _of_k)],
+            out_specs=[pl.BlockSpec((None, bq, D), _of_q),
+                       pl.BlockSpec((None, None, 1, bq), _of_q_row)],
+            scratch_shapes=[_vmem((bq, 1)), _vmem((bq, 1)),
+                            _vmem((bq, D))]),
         out_shape=[
             # out_f32: emit fp32 partials (ring composition carries them
             # through the logsumexp combine without per-hop rounding).
             jax.ShapeDtypeStruct((BH, S, D),
                                  jnp.float32 if out_f32 else q.dtype),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            _vmem((bq, 1)),
-            _vmem((bq, 1)),
-            _vmem((bq, D)),
-        ],
-    )
-    return o, lse
-
-
-def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, jnp.float32)
+            jax.ShapeDtypeStruct((BH, S // bq, 1, bq), jnp.float32)])
 
 
 # ---------------------------------------------------------------------------
@@ -186,151 +288,102 @@ def _vmem(shape):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
-               dq_ref, acc_scr, *, scale, causal, block_q, block_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _dq_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, do_ref,
+               lse_ref, delta_ref, dlse_ref, dq_ref, acc_scr, cols_scr,
+               *, scale, causal):
+    qi, ki, first, last = _pair(qi_ref, ki_ref, flag_ref)
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # The query block's three rows as columns, turned once for all of
+        # its key blocks.
+        for n, row_ref in enumerate((lse_ref, delta_ref, dlse_ref)):
+            cols_scr[n] = row_ref[...].T                    # [bq, 1]
 
-    needed = _block_needed(qi, ki, block_q, block_k, causal)
+    k = k_ref[...]
+    s = _scores(q_ref[...], k) * scale                  # [bq, bk]
+    if causal:
+        s = _causal_mask(s, qi * block_q, ki * block_k, 0)
+    p = jnp.exp(s - cols_scr[0])                        # [bq, bk]
+    dp = _scores(do_ref[...], v_ref[...])               # [bq, bk]
+    # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse.
+    ds = p * (dp - cols_scr[1] + cols_scr[2])
+    acc_scr[...] += _product(ds.astype(k.dtype), k) * scale
 
-    @pl.when(needed)
-    def _tile():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                           # [bq, 1]
-        delta = delta_ref[0]                       # [bq, 1]
-        dlse = dlse_ref[0]                         # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bq, bk]
-        # d lse_i / d s_ij = p_ij, so an lse cotangent adds p * dlse.
-        ds = p * (dp - delta + dlse)
-        acc_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(ki == nk - 1)
+    @pl.when(last)
     def _finalize():
-        dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
+        dq_ref[...] = acc_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dlse_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, block_q, block_k):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+def _dkv_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, do_ref,
+                lse_ref, delta_ref, dlse_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                *, scale, causal):
+    qi, ki, first, last = _pair(qi_ref, ki_ref, flag_ref)
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    needed = _block_needed(qi, ki, block_q, block_k, causal)
+    # The scores lie TRANSPOSED, keys down and queries along the lanes: the
+    # query block's rows broadcast over them as they come, and dv and dk are
+    # plain products (contracting p and ds over their first dimension would
+    # turn a tile around twice a step).
+    q = q_ref[...]
+    do = do_ref[...]
+    s = _scores(k_ref[...], q) * scale                  # [bk, bq]
+    if causal:
+        s = _causal_mask(s, qi * block_q, ki * block_k, 1)
+    p = jnp.exp(s - lse_ref[...])                       # [bk, bq]
+    dv_scr[...] += _product(p.astype(do.dtype), do)     # [bk, d]
+    dp = _scores(v_ref[...], do)                        # [bk, bq]
+    ds = p * (dp - delta_ref[...] + dlse_ref[...])
+    dk_scr[...] += _product(ds.astype(q.dtype), q) * scale
 
-    @pl.when(needed)
-    def _tile():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                           # [bq, 1]
-        delta = delta_ref[0]                       # [bq, 1]
-        dlse = dlse_ref[0]                         # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
-        p = jnp.exp(s - lse)                       # [bq, bk]
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta + dlse)
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd(res, g, scale, causal, block_q, block_k):
-    q, k, v, o, lse = res
-    do, dlse = g
+    q, k, v, o, lse = res           # lse [BH, S]
+    do, dlse = g                    # dlse as the forward gave lse: _rows
     BH, S, D = q.shape
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
-    # delta_i = rowsum(dO_i * O_i) — cheap, fused by XLA outside pallas;
-    # keepdims so the [BH, S, 1] layout matches lse's Mosaic-tileable
-    # trailing-singleton blocks.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)         # [BH, S, 1]
-    dlse = dlse.astype(jnp.float32)
+    # delta_i = rowsum(dO_i * O_i) — cheap, fused by XLA outside pallas.
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    operands = (q, k, v, do, _rows(lse, bq), _rows(delta, bq),
+                dlse.astype(jnp.float32))
+    by_q, by_k, by_row = (pl.BlockSpec((None, bq, D), _of_q),
+                          pl.BlockSpec((None, bk, D), _of_k),
+                          pl.BlockSpec((None, None, 1, bq), _of_q_row))
+    in_specs = [by_q, by_k, by_k, by_q, by_row, by_row, by_row]
 
+    pairs = block_pairs(S, bq, bk, causal)
     dq = _pallas_call(
         "flash_bwd_dq",
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
-        q, k, v, do, lse, delta, dlse,
-        grid=(BH, S // bq, S // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        scratch_shapes=[_vmem((bq, D))],
-    )
+        functools.partial(_dq_kernel, scale=scale, causal=causal),
+        *pairs, *operands,
+        grid_spec=_grid_spec(
+            pairs, BH, in_specs=in_specs, out_specs=by_q,
+            scratch_shapes=[_vmem((bq, D)), _vmem((3, bq, 1))]),
+        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype))
 
+    pairs = block_pairs(S, bq, bk, causal, by_key=True)
     dk, dv = _pallas_call(
         "flash_bwd_dkv",
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk),
-        q, k, v, do, lse, delta, dlse,
-        grid=(BH, S // bk, S // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
-        ],
-        scratch_shapes=[_vmem((bk, D)), _vmem((bk, D))],
-    )
+        functools.partial(_dkv_kernel, scale=scale, causal=causal),
+        *pairs, *operands,
+        grid_spec=_grid_spec(
+            pairs, BH, in_specs=in_specs, out_specs=[by_k, by_k],
+            scratch_shapes=[_vmem((bk, D)), _vmem((bk, D))]),
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((BH, S, D), v.dtype)])
     return dq, dk, dv
 
 
@@ -354,9 +407,10 @@ def _flash(q, k, v, scale, causal, block_q, block_k, out_f32, named):
 def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32,
                    named):
     o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
-    # lse is kept as [BH, S]: stacked over a scan's layers in the kernel's
-    # [BH, S, 1] the chip would pad each row of one to a 128-lane tile.
-    rows = lse[..., 0]
+    # lse is kept as [BH, S], a reshape of the kernel's own output (what
+    # tiles a stack of [.., 1, bq] blocks over a scan's layers would get
+    # is XLA's to choose; [L, BH, S] pads nothing).
+    rows = lse.reshape(q.shape[:2])
     if named:
         # The NAMED o is also the primal output, so that under a policy
         # that saves the names nothing downstream of the kernel asks the
@@ -367,9 +421,7 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32,
 
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, out_f32, named, res, g):
-    q, k, v, o, rows = res
-    return _flash_bwd((q, k, v, o, rows[..., None]), g, scale, causal,
-                      block_q, block_k)
+    return _flash_bwd(res, g, scale, causal, block_q, block_k)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

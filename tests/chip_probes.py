@@ -163,11 +163,41 @@ SERVE_RETENTION = dict(slots=32, vocab_size=1024, intermediate_size=512,
                        num_hidden_layers=2, max_seq_len=4736)
 
 
+# Sequence lengths at which the attention kernel's gradient is compiled
+# alone: the training cell's (four blocks of 512 a row) and one whose block
+# falls under the chip's 128 lanes (2112 = 33 x 64).
+FLASH_ALONE = (2048, 2112)
+
+
+def flash_grad_calls(seq_len, sharding):
+    """``jax.grad`` of ``flash_attention`` alone, compiled for one ``v5e``
+    chip at the training cell's block structure (2 heads of 128,
+    bfloat16): each Mosaic call's instruction as ``[name, text]``, the
+    text its results' and its operands' types (the instruction up to its
+    ``backend_config``, which is the kernel itself)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, seq_len, 2, 128), jnp.bfloat16,
+                             sharding=sharding)
+    text = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    return re.findall(
+        r"^\s*%?(\S+) = (.*custom_call_target=\"tpu_custom_call\".*?)"
+        r"(?:, backend_config=.*)?$", text, re.M)
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
     those instructions (what the profiler's ``XLA Ops`` events, and the
-    benchmark's per-kernel metrics, tell the kernels apart by); and what
+    benchmark's per-kernel metrics, tell the kernels apart by); the
+    attention kernel's gradient alone at the training cell's blocks
+    (:func:`flash_grad_calls`: what tells a run without a chip that Mosaic
+    takes the kernels' blocks at the real size); and what
     the two programs that write the serving slots' state produce there
     (:func:`serve_cache_programs`), for the dense decoder's cache and for
     models/jamba.py's two kinds of state, for models/latent_moe.py's
@@ -223,9 +253,9 @@ def probe_lower_for_tpu(meshes_json):
     retention_slots = retention_sizes.pop("slots")
     rcfg = retention.RetentionConfig(**retention_sizes)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    # One thread fewer than submissions: the last compile (the latent
-    # lanes') takes the first thread that falls free, so that the probe
-    # loads the machine no more than before it had it.
+    # Fewer threads than submissions: the later compiles take the threads
+    # that fall free, so that the probe loads the machine no more than
+    # before it had them.
     with ThreadPoolExecutor(len(meshes) + 2) as pool:
         serve_cache = pool.submit(
             serve_cache_programs, cfg, slots,
@@ -249,6 +279,8 @@ def probe_lower_for_tpu(meshes_json):
             # one layer's state matrices: [slots, KVH, head_dim, rows]
             retention_slots * rcfg.num_key_value_heads * rcfg.head_dim
             * rcfg.state_rows, one_chip)
+        flash_alone = [pool.submit(flash_grad_calls, seq_len, one_chip)
+                       for seq_len in FLASH_ALONE]
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
@@ -257,6 +289,7 @@ def probe_lower_for_tpu(meshes_json):
         "serve_latent": serve_latent.result(),
         "serve_sparse": serve_sparse.result(),
         "serve_retention": serve_retention.result(),
+        "flash_alone": [calls.result() for calls in flash_alone],
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
 

@@ -189,6 +189,30 @@ def test_lowered_lm_step_runs_the_forward_kernel_once_a_layer(probes, i):
     assert stems.count("flash_fwd") == stems.count("flash_bwd_dq") > 0, names
 
 
+@pytest.mark.parametrize("i", range(len(chip_probes.FLASH_ALONE)),
+                         ids=[f"s{s}" for s in chip_probes.FLASH_ALONE])
+def test_flash_gradient_alone_compiles_for_the_chip_at_the_cells_blocks(
+        probes, i):
+    """Mosaic takes the three calls at the training cell's block structure
+    (``S`` 2048: four blocks of 512 a row, the pairs in scalar memory) and
+    at an ``S`` whose block falls under the chip's 128 lanes (2112: blocks
+    of 64), one call each under its name; and no call takes or gives the
+    per-row numbers as a column ``f32[..., S, 1]``, which the chip pads to
+    128 lanes: they lie along the lanes of ``[BH, S // bq, 1, bq]``."""
+    import re
+
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    calls = json.loads(out.split("RESULT", 1)[1])["flash_alone"][i]
+    seq_len = chip_probes.FLASH_ALONE[i]
+    assert sorted(name.rsplit(".", 1)[0] for name, _ in calls) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], calls
+    for name, text in calls:
+        assert not re.search(r"f32\[[\d,]*\b%d,1\]" % seq_len, text), (
+            name, text)
+        assert re.search(r"f32\[2,\d+,1,\d+\]", text), (name, text)
+
+
 @pytest.mark.parametrize("program,update", [
     ("step", "fusion:scatter"), ("install", "fusion:dynamic-update-slice")])
 def test_serving_cache_programs_update_in_place_on_the_chip(

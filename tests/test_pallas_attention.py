@@ -6,20 +6,27 @@ import math
 import numpy as np
 import pytest
 
-from horovod_tpu.ops.pallas_attention import flash_attention
+from horovod_tpu.ops.pallas_attention import (block_pairs, flash_attention,
+                                              flash_attention_lse)
+
+
+def _ref_attn_lse(jax, q, k, v, causal):
+    """The dense oracle in float32: (context, log-sum-exp [B, S, H])."""
+    import jax.numpy as jnp
+
+    S, D = q.shape[1], q.shape[3]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    logits = jnp.einsum("bshk,bthk->bhst", q, k) / math.sqrt(D)
+    if causal:
+        logits = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None],
+                           logits, -1e30)
+    lse = jax.nn.logsumexp(logits, axis=-1)                  # [B, H, S]
+    out = jnp.einsum("bhst,bthk->bshk", jnp.exp(logits - lse[..., None]), v)
+    return out, jnp.moveaxis(lse, 1, 2)
 
 
 def _ref_attn(jax, q, k, v, causal=True):
-    import jax.numpy as jnp
-
-    B, S, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    logits = jnp.einsum("bshk,bthk->bhst", q, k) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        logits = jnp.where(mask[None, None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhst,bthk->bshk", p, v)
+    return _ref_attn_lse(jax, q, k, v, causal)[0]
 
 
 def _qkv(jax, seed=0, B=2, S=128, H=4, D=32):
@@ -56,6 +63,152 @@ def test_flash_grads_match_dense(jax):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+# blocks a row: one; four; four query blocks on two key blocks and the
+# other way round (the diagonal crosses a pair by its bounds, not qi == ki)
+BLOCKS = {"one": (128, 128), "four": (32, 32), "q32_k64": (32, 64),
+          "q64_k32": (64, 32)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lse_cotangent", [False, True],
+                         ids=["o_only", "lse_cotangent"])
+@pytest.mark.parametrize("blocks", list(BLOCKS))
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+def test_flash_matches_dense_whatever_the_schedule(jax, causal, blocks,
+                                                   lse_cotangent, dtype):
+    """Output, log-sum-exp and the three gradients against the dense
+    oracle, over what decides the calls' schedule: causal or not (the list
+    of pairs), the blocks a row (none skipped or masked with one; the
+    diagonal through unequal blocks), a cotangent on the log-sum-exp (a
+    ring hop's) or none, and the inputs' type (bfloat16 rounds the
+    probabilities before their products, the oracle does not)."""
+    import jax.numpy as jnp
+
+    bq, bk = BLOCKS[blocks]
+    q, k, v = (x.astype(dtype) for x in _qkv(jax, seed=3, B=1, H=2))
+    rs = np.random.RandomState(4)
+    w = jnp.asarray(rs.randn(*q.shape), jnp.float32)
+    u = jnp.asarray(rs.randn(1, 128, 2), jnp.float32) * lse_cotangent
+
+    def loss(attend):
+        def f(q, k, v):
+            o, lse = attend(q, k, v)
+            return jnp.sum(o * w) + jnp.sum(lse * u), (o, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, got), got_grads = loss(lambda q, k, v: flash_attention_lse(
+        q, k, v, causal=causal, block_q=bq, block_k=bk))(q, k, v)
+    (_, want), want_grads = loss(lambda q, k, v: _ref_attn_lse(
+        jax, q, k, v, causal))(q, k, v)
+    fwd, bwd = {"float32": (2e-5, 2e-4), "bfloat16": (2e-2, 5e-2)}[dtype]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=fwd, atol=fwd)
+    for a, b in zip(got_grads, want_grads):
+        assert a.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=bwd, atol=bwd)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (a kernel's
+    body too)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("causal,steps", [(True, 10), (False, 16)])
+def test_causal_calls_step_only_the_pairs_the_mask_needs(jax, causal, steps):
+    """At four blocks a row a causal call's grid is 10 pairs a head of the
+    16, each of the three calls', and a non-causal call's all 16; a query
+    block's pairs (for ``flash_bwd_dkv`` a key block's) are consecutive,
+    ascending, flagged first and last."""
+    import jax.numpy as jnp
+
+    q, k, v = _qkv(jax, B=1, H=2)
+    grids = {}
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, block_q=32, block_k=32)), argnums=(0, 1, 2))
+    for eqn in _eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            grids.setdefault(eqn.params["name"], set()).add(
+                tuple(eqn.params["grid_mapping"].grid))
+    assert grids == {name: {(2, steps)} for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+
+    for by_key in (False, True):
+        qi, ki, flags = block_pairs(128, 32, 32, causal, by_key)
+        pairs = list(zip(qi.tolist(), ki.tolist()))
+        assert len(pairs) == steps
+        assert set(pairs) == {(i, j) for i in range(4) for j in range(4)
+                              if j <= i or not causal}
+        order = [(j, i) for i, j in pairs] if by_key else pairs
+        assert order == sorted(order)
+        rows = [row for row, _ in order]
+        assert [bool(f & 1) for f in flags] == [
+            n == 0 or rows[n - 1] != row for n, row in enumerate(rows)]
+        assert [bool(f & 2) for f in flags] == [
+            n + 1 == steps or rows[n + 1] != row
+            for n, row in enumerate(rows)]
+        assert not (flags & ~3).any()
+    # unequal blocks: a pair is needed by the blocks' bounds
+    qi, ki, _ = block_pairs(128, 32, 64, True)
+    assert list(zip(qi.tolist(), ki.tolist())) == [
+        (0, 0), (1, 0), (2, 0), (2, 1), (3, 0), (3, 1)]
+
+
+def test_block_pairs_are_read_only_and_bounded():
+    """The cache hands every call the same vectors, so they cannot be
+    written; and a schedule too long for scalar memory (the full grid
+    before it needed none) is refused by name, not by Mosaic."""
+    from horovod_tpu.ops.pallas_attention import MAX_PAIRS
+
+    for column in block_pairs(128, 32, 32, True):
+        with pytest.raises(ValueError, match="read-only"):
+            column[0] = 7
+    side = math.isqrt(MAX_PAIRS)
+    assert len(block_pairs(8 * side, 8, 8, False)[0]) == MAX_PAIRS
+    with pytest.raises(ValueError, match="pairs a head"):
+        block_pairs(8 * side + 8, 8, 8, False)
+
+
+def test_flash_layer_grad_holds_no_column_of_row_numbers(jax):
+    """The log-sum-exp, ``delta`` and the lse cotangent travel along the
+    lanes: nowhere in the gradient of a flash layer, the kernels' operands
+    and results included, is there an array of ``BH * S`` numbers whose
+    last dimension is 1 (the chip pads such a one to 128 lanes: 134 MB a
+    layer at the training cell's shape for 1 MB of numbers)."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tfm
+
+    B, S, H, D = 2, 128, 4, 8
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=H * D, n_layers=2, n_heads=H, d_ff=64,
+        max_seq_len=S, compute_dtype=jnp.float32, attn_impl="flash",
+        remat=True)
+    params = tfm.init(jax.random.PRNGKey(0), cfg)
+    toks = jnp.zeros((B, S), jnp.int32)
+    step = jax.make_jaxpr(jax.grad(
+        lambda p: tfm.loss_fn(p, toks, toks, cfg)))(params)
+    calls, columns = 0, []
+    for eqn in _eqns(step.jaxpr):
+        calls += eqn.primitive.name == "pallas_call"
+        for var in (*eqn.invars, *eqn.outvars):
+            shape = getattr(var.aval, "shape", ())
+            if shape and shape[-1] == 1 and math.prod(shape) == B * H * S:
+                columns.append((eqn.primitive.name, shape))
+    assert calls >= 3
+    assert not columns, columns
 
 
 def test_flash_uneven_blocks(jax):

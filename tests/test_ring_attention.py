@@ -107,7 +107,7 @@ def test_checkpointed_ring_layer_keeps_no_hop(eight_devices):
     from jax._src.ad_checkpoint import saved_residuals
 
     from horovod_tpu.models import transformer as tfm
-    from horovod_tpu.ops.pallas_attention import SAVED_NAMES
+    from horovod_tpu.ops.pallas_attention import SAVED_NAMES, block_pairs
 
     mesh = mesh_mod.make_mesh({"sp": 4}, devices=eight_devices[:4])
     cfg = tfm.TransformerConfig(
@@ -124,7 +124,13 @@ def test_checkpointed_ring_layer_keeps_no_hop(eight_devices):
     saved = residuals(tfm.remat_layer())
     assert saved == residuals(
         jax.checkpoint(tfm._layer, static_argnums=(2, 3)))
-    assert all("from the argument" in src for _, src in saved), saved
+    # ... and, for the re-forward of the hops' kernel, the three vectors of
+    # its schedule (pallas_attention.block_pairs: one pair at a shard of 8
+    # positions): constants of the trace, those three and nothing else
+    steps = len(block_pairs(32 // 4, 8, 8, False)[0])
+    constants = [(aval, src) for aval, src in saved
+                 if "from the argument" not in src]
+    assert constants == [(f"int32[{steps}]", "from a constant")] * 3, saved
     # the names sit in the kernel's forward RULE, which only a
     # differentiated trace runs: the hops do run it, and name nothing
     grad = str(jax.make_jaxpr(jax.grad(
