@@ -23,7 +23,14 @@ experts (ep).  This model is built so that every one of those axes is a
   cache or without), one rotation (``_rope``), one softmax core and one
   layer body (``_layer``).  Training (``apply``), the prefill and the
   decode step are that layer under three loops, so a change to what
-  attention reads or how heads are grouped is written once.
+  attention reads or how heads are grouped is written once.  The one
+  branch that holds its arrays otherwise is the training kernel's
+  (``_flash_attention``: ``attn_impl="flash"``, no cache): q, k, v and
+  the context stay [B, S, H * HD] from the projections (one 2-D product
+  each) through the rotation (``_rope_flat``: ``_rope``'s numbers along
+  the lanes) to ``wo``, because the kernel reads that array in place and
+  on a TPU [B, S, H, HD] is a copy away from it.  The parameters keep
+  their shapes; the other branches' programs do not change.
 * Cached decode is a seam, not a second model: ``init_state``,
   ``prefill_request``, ``install_request``, ``decode_step``,
   ``STATE_SPEC`` and ``serving_params``, the names and signatures
@@ -184,6 +191,16 @@ def _rmsnorm(x, g, eps: float = 1e-6):
     return (y * g).astype(x.dtype)
 
 
+def _rope_angles(theta, half: int, seq_len: int, pos):
+    """The angle [(B,) S, half] that pair i of a head is turned by at each
+    position: see :func:`_rope`."""
+    freqs = theta if hasattr(theta, "shape") else jnp.exp(
+        -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    if pos is None:
+        pos = jnp.arange(seq_len, dtype=jnp.float32)
+    return pos.astype(jnp.float32)[..., None] * freqs
+
+
 def _rope(x, theta, pos=None):
     """Rotary embedding over head_dim pairs; x: [B, S, H, HD].
     ``theta``: the base, pair i turning by ``theta^(-i / (HD/2))`` a
@@ -194,11 +211,7 @@ def _rope(x, theta, pos=None):
     offset); default ``arange(S)``."""
     B, S, H, HD = x.shape
     half = HD // 2
-    freqs = theta if hasattr(theta, "shape") else jnp.exp(
-        -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    if pos is None:
-        pos = jnp.arange(S, dtype=jnp.float32)
-    ang = pos.astype(jnp.float32)[..., None] * freqs     # [(B,) S, half]
+    ang = _rope_angles(theta, half, S, pos)
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -206,6 +219,27 @@ def _rope(x, theta, pos=None):
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def _rope_flat(x, n_heads: int, theta):
+    """:func:`_rope` of ``x`` [B, S, H * HD], a head's HD values side by
+    side, at positions 0 to S - 1, without leaving that shape: the same
+    products and sums (bit for bit in bfloat16; in float32 to the last
+    bit, where a compiler contracts the multiply-adds its own way).
+    Where HD is a whole number of the chip's
+    128-lane tiles it is a kernel's one pass (ops/pallas_rope.py: each
+    lane's partner is HD/2 lanes away, which XLA only reaches through
+    memory or through another tiling of the array); any other HD goes
+    through [B, S, H, HD] and back."""
+    B, S, F = x.shape
+    head_dim = F // n_heads
+    if head_dim % 128:
+        return _rope(x.reshape(B, S, n_heads, head_dim), theta).reshape(
+            B, S, F)
+    from horovod_tpu.ops.pallas_rope import rotate
+
+    ang = _rope_angles(theta, head_dim // 2, S, None)
+    return rotate(x, jnp.cos(ang), jnp.sin(ang), n_heads)
 
 
 @functools.lru_cache(maxsize=None)
@@ -232,6 +266,56 @@ def _softmax_attention(q, k, v, valid, cfg: TransformerConfig):
     return jnp.einsum("bhst,bthk->bshk", probs, v)
 
 
+def _flash_attention(x, lp, cfg: TransformerConfig, mesh):
+    """:func:`_attention`'s branch for the kernel of
+    ops/pallas_attention.py (no cache, ``tp`` = ``sp`` = 1), the same
+    numbers with q, k, v and the context held as [B, S, H * HD] from the
+    projections to the output projection: each projection is ONE 2-D
+    product against its weight reshaped at the use ([D, H, HD] is
+    [D, H * HD] laid out otherwise; ``wqkv`` is cut where its three
+    parts lie), the rotation is :func:`_rope_flat`, the kernel cuts a
+    head's block out of that array where it lies, and ``wo`` contracts
+    it whole.  On a TPU [B, S, H, HD] tiles its last TWO dimensions, so
+    the 4-D form cost a transposition of every operand, result and
+    gradient on its way into and out of the kernel's three calls."""
+    from horovod_tpu.ops.pallas_attention import flash_attention
+
+    B, S, D = x.shape
+    H, HD = cfg.n_heads, cfg.head_dim
+    dtype = cfg.compute_dtype
+    if "wqkv" in lp:
+        qkv = jnp.einsum("bsd,df->bsf", x, lp["wqkv"].astype(dtype))
+        q, kk, v = jnp.split(qkv, 3, axis=-1)
+    else:
+        q, kk, v = (
+            jnp.einsum("bsd,df->bsf", x,
+                       lp[name].astype(dtype).reshape(D, H * HD))
+            for name in ("wq", "wk", "wv"))
+
+    def core(q, kk, v):
+        q = _rope_flat(q, H, cfg.rope_theta)
+        kk = _rope_flat(kk, H, cfg.rope_theta)
+        return flash_attention(q, kk, v, causal=True, n_heads=H), kk
+
+    if mesh is not None and mesh.size > 1:
+        # A pallas_call has no GSPMD partitioning rule, and Mosaic
+        # refuses a kernel that any automatic mesh axis could split,
+        # so the kernels run inside a shard_map that is manual over
+        # EVERY axis: the batch split the way the activations are
+        # (ACT_SPEC's batch axes), replicated over the rest
+        # (ep, dcn, ...; tp and sp are 1 here).
+        from horovod_tpu.parallel.mesh import filter_spec
+        from horovod_tpu.parallel.shard import shard_map
+
+        batch = filter_spec(P(ACT_SPEC[0]), mesh)
+        core = shard_map(core, mesh, in_specs=(batch, batch, batch),
+                         out_specs=(batch, batch))
+    ctx, kk = core(q, kk, v)
+    out = jnp.einsum("bsf,fd->bsd", ctx,
+                     lp["wo"].astype(dtype).reshape(H * HD, D))
+    return out, (kk.reshape(B, S, H, HD), v.reshape(B, S, H, HD))
+
+
 def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     """Causal self-attention, the model's only one.  x: [B, S, D].
 
@@ -255,6 +339,18 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     B, S, D = x.shape
     dtype = cfg.compute_dtype
     own = None if cache is None else cache[3][:, None]   # [B, 1] positions
+    if cache is None:
+        if cfg.attn_impl not in ("dense", "ring", "ulysses", "flash"):
+            raise ValueError(
+                f"attn_impl must be dense/ring/ulysses/flash, "
+                f"got {cfg.attn_impl!r}")
+        if cfg.attn_impl == "flash":
+            split = [ax for ax in ("tp", "sp")
+                     if mesh is not None and mesh.shape.get(ax, 1) > 1]
+            for ax in split:
+                warn_flash_runs_dense(ax, mesh.shape[ax], "the model")
+            if not split:
+                return _flash_attention(x, lp, cfg, mesh)
     if "wqkv" in lp:
         qkv = jnp.einsum("bsd,df->bsf", x, lp["wqkv"].astype(dtype))
         q, kk, v = (a.reshape(B, S, cfg.n_heads, cfg.head_dim)
@@ -267,39 +363,9 @@ def _attention(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     kk = _rope(kk, cfg.rope_theta, own)
     if cache is None:
         kept = (kk, v)
-        if cfg.attn_impl not in ("dense", "ring", "ulysses", "flash"):
-            raise ValueError(
-                f"attn_impl must be dense/ring/ulysses/flash, "
-                f"got {cfg.attn_impl!r}")
         use_sp = (cfg.attn_impl in ("ring", "ulysses") and mesh is not None
                   and mesh.shape.get("sp", 1) > 1)
-        use_flash = cfg.attn_impl == "flash"
-        if use_flash and mesh is not None:
-            for ax in ("tp", "sp"):
-                if mesh.shape.get(ax, 1) > 1:
-                    warn_flash_runs_dense(ax, mesh.shape[ax], "the model")
-                    use_flash = False
-        if use_flash:
-            from horovod_tpu.ops.pallas_attention import flash_attention
-
-            if mesh is not None and mesh.size > 1:
-                # A pallas_call has no GSPMD partitioning rule, and Mosaic
-                # refuses a kernel that any automatic mesh axis could split,
-                # so the kernel runs inside a shard_map that is manual over
-                # EVERY axis: the batch split the way the activations are
-                # (ACT_SPEC's batch axes), replicated over the rest
-                # (ep, dcn, ...; tp and sp are 1 here).
-                from horovod_tpu.parallel.mesh import filter_spec
-                from horovod_tpu.parallel.shard import shard_map
-
-                batch = filter_spec(P(ACT_SPEC[0]), mesh)
-                ctx = shard_map(
-                    lambda a, b, c: flash_attention(a, b, c, causal=True),
-                    mesh, in_specs=(batch, batch, batch),
-                    out_specs=batch)(q, kk, v)
-            else:
-                ctx = flash_attention(q, kk, v, causal=True)
-        elif use_sp:
+        if use_sp:
             # Sequence-parallel attention: K/V never gather; blocks rotate
             # the sp ring (ring) or heads exchange via all-to-all (ulysses).
             from horovod_tpu.parallel import ring_attention as ra

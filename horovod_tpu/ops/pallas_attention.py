@@ -27,6 +27,23 @@ kernel body is interpreted.  The choice follows the lowering platform, not
 The schedule of the three calls (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``; the arithmetic is the recipe's):
 
+* **The operands are read where they lie.**  q, k, v, the context, its
+  cotangent and the three gradients are ``[B, S, H * D]``, a head's ``D``
+  values side by side: what a 2-D projection writes and the output
+  projection reads (``models/transformer.py:_flash_attention``).  A call's
+  block stays ``(bq, D)`` / ``(bk, D)``; its index map picks head ``h`` of
+  row ``b`` as lane block ``h`` of row ``b`` (:func:`_block_specs`), ``bq``
+  rows of ``D`` lanes at a stride of ``H * D``.  That needs ``D`` to be a
+  whole number of the chip's 128-lane tiles.  With any other ``D`` (64: no
+  model the repo measures) the heads are folded in front of the SAME
+  calls, ``[B * H, S, D]``, the case ``H = 1`` of the same index maps, at
+  the cost of a transposition of every operand and result; so is the 4-D
+  form ``[B, S, H, D]`` reshaped, which on a TPU tiles its last two
+  dimensions and is a copy away from ``[B, S, H * D]``.  Before, every
+  caller's arrays were folded: 67 MB a transposition at the training
+  cell's shape (134 MB for a float32 gradient), eight of them a layer in
+  the step compiled for the chip.  The rotary embedding of such an array
+  is ``ops/pallas_rope.py``'s.
 * **A list of pairs is the grid** (:func:`block_pairs`, made at trace
   time, in scalar memory): a causal call steps the (query block, key
   block) pairs that hold a key at or under a query and no other, so the
@@ -52,7 +69,9 @@ the share of the call's 2, 3 and 4 products' time at the chip's peak):
 ``flash_fwd`` 2.43 (29 %), ``flash_bwd_dq`` 1.95 (54 %), ``flash_bwd_dkv``
 2.22 (63 %); 2.72, 2.80 and 3.63 on the full grid with ``[BH, S, 1]``
 vectors.  The rows' layout gave -0.30 and -0.97 ms to the two backward
-calls, the list of pairs -0.32, -0.55 and -0.45 to the three.
+calls, the list of pairs -0.32, -0.55 and -0.45 to the three.  Those are
+readings of the folded form ``[B * H, S, D]``; the in-place form's are in
+``PERF.md`` (section 6, PR 44).
 """
 
 from __future__ import annotations
@@ -192,20 +211,33 @@ def _grid_spec(pairs, batch_heads, in_specs, out_specs, scratch_shapes):
         scratch_shapes=scratch_shapes)
 
 
-# Block index maps of a call over pairs: (head, step, query blocks, key
-# blocks, flags) -> the block of a [BH, S, D] operand that goes with the
-# step's query block or key block, and of a [BH, S // bq, 1, bq] vector of
-# per-row numbers (below).
-def _of_q(b, i, qi_ref, ki_ref, flag_ref):
-    return b, qi_ref[i], 0
+def _block_specs(heads, bq, bk, D):
+    """The one set of block specs of the three calls, ``(by_q, by_k,
+    by_row)``: a query block ``(bq, D)`` and a key block ``(bk, D)`` of an
+    operand ``[B', S, heads * D]`` and a query block's ``(1, bq)`` of a
+    vector of per-row numbers (:func:`_rows`).  The grid's first
+    coordinate ``bh`` counts the ``B' * heads`` heads and its second the
+    steps of :func:`block_pairs`, whose vectors the index maps read in
+    scalar memory: head ``bh`` is row ``bh // heads`` of the operand and
+    the ``D`` lanes from ``(bh % heads) * D``, so a block is ``bq`` rows
+    of ``D`` lanes at a stride of ``heads * D`` and nothing is turned
+    round to make it contiguous.  ``heads`` 1 is the head-major array
+    ``[B * H, S, D]`` that an operand is folded to where ``D`` is not a
+    whole number of lane tiles (:func:`_run_flash`)."""
+    heads = np.int32(heads)
 
+    def of_q(bh, i, qi_ref, ki_ref, flag_ref):
+        return jax.lax.div(bh, heads), qi_ref[i], jax.lax.rem(bh, heads)
 
-def _of_k(b, i, qi_ref, ki_ref, flag_ref):
-    return b, ki_ref[i], 0
+    def of_k(bh, i, qi_ref, ki_ref, flag_ref):
+        return jax.lax.div(bh, heads), ki_ref[i], jax.lax.rem(bh, heads)
 
+    def of_q_row(bh, i, qi_ref, ki_ref, flag_ref):
+        return bh, qi_ref[i], 0, 0
 
-def _of_q_row(b, i, qi_ref, ki_ref, flag_ref):
-    return b, qi_ref[i], 0, 0
+    return (pl.BlockSpec((None, bq, D), of_q),
+            pl.BlockSpec((None, bk, D), of_k),
+            pl.BlockSpec((None, None, 1, bq), of_q_row))
 
 
 def _rows(x, block_q):
@@ -256,29 +288,29 @@ def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref,
         lse_ref[...] = (m_scr[...] + jnp.log(l)).T          # [1, bq]
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32=False):
-    """``(o [BH, S, D], lse [BH, S // bq, 1, bq])``: :func:`_rows`."""
-    BH, S, D = q.shape
+def _flash_fwd(q, k, v, heads, scale, causal, block_q, block_k,
+               out_f32=False):
+    """``(o, lse)`` of ``q``, ``k``, ``v`` ``[B', S, heads * D]``
+    (:func:`_block_specs`): ``o`` as they lie, ``lse``
+    ``[B' * heads, S // bq, 1, bq]`` (:func:`_rows`)."""
+    S = q.shape[1]
+    BH, D = q.shape[0] * heads, q.shape[2] // heads
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
     pairs = block_pairs(S, bq, bk, causal)
+    by_q, by_k, by_row = _block_specs(heads, bq, bk, D)
     return _pallas_call(
         "flash_fwd",
         functools.partial(_fwd_kernel, scale=scale, causal=causal),
         *pairs, q, k, v,
         grid_spec=_grid_spec(
-            pairs, BH,
-            in_specs=[pl.BlockSpec((None, bq, D), _of_q),
-                      pl.BlockSpec((None, bk, D), _of_k),
-                      pl.BlockSpec((None, bk, D), _of_k)],
-            out_specs=[pl.BlockSpec((None, bq, D), _of_q),
-                       pl.BlockSpec((None, None, 1, bq), _of_q_row)],
+            pairs, BH, in_specs=[by_q, by_k, by_k], out_specs=[by_q, by_row],
             scratch_shapes=[_vmem((bq, 1)), _vmem((bq, 1)),
                             _vmem((bq, D))]),
         out_shape=[
             # out_f32: emit fp32 partials (ring composition carries them
             # through the logsumexp combine without per-hop rounding).
-            jax.ShapeDtypeStruct((BH, S, D),
+            jax.ShapeDtypeStruct(q.shape,
                                  jnp.float32 if out_f32 else q.dtype),
             jax.ShapeDtypeStruct((BH, S // bq, 1, bq), jnp.float32)])
 
@@ -349,19 +381,32 @@ def _dkv_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(res, g, scale, causal, block_q, block_k):
+def _delta(do, o, heads):
+    """``delta_i = rowsum(dO_i * O_i)`` over each head's D lanes, [BH, S]
+    float32, of ``do``, ``o`` [B', S, heads * D] — cheap, fused by XLA
+    outside pallas.  A head's lanes are cut out of the row where they lie
+    (a tile-aligned slice where D is a multiple of 128), so each head is
+    one multiply-and-reduce over what it reads: summing a reshape to
+    ``[.., heads, D]`` makes XLA copy the float32 product to another
+    tiling first, 134 MB twice a layer at the training cell's shape."""
+    D = do.shape[2] // heads
+    heads_major = [
+        jnp.sum(do[..., h * D:(h + 1) * D].astype(jnp.float32)
+                * o[..., h * D:(h + 1) * D].astype(jnp.float32), axis=-1)
+        for h in range(heads)]                      # heads x [B', S]
+    return jnp.stack(heads_major, axis=1).reshape(-1, do.shape[1])
+
+
+def _flash_bwd(res, g, heads, scale, causal, block_q, block_k):
     q, k, v, o, lse = res           # lse [BH, S]
     do, dlse = g                    # dlse as the forward gave lse: _rows
-    BH, S, D = q.shape
+    Bp, S, F = q.shape
+    BH, D = Bp * heads, F // heads
     bq = _pick_block(S, block_q)
     bk = _pick_block(S, block_k)
-    # delta_i = rowsum(dO_i * O_i) — cheap, fused by XLA outside pallas.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    operands = (q, k, v, do, _rows(lse, bq), _rows(delta, bq),
+    operands = (q, k, v, do, _rows(lse, bq), _rows(_delta(do, o, heads), bq),
                 dlse.astype(jnp.float32))
-    by_q, by_k, by_row = (pl.BlockSpec((None, bq, D), _of_q),
-                          pl.BlockSpec((None, bk, D), _of_k),
-                          pl.BlockSpec((None, None, 1, bq), _of_q_row))
+    by_q, by_k, by_row = _block_specs(heads, bq, bk, D)
     in_specs = [by_q, by_k, by_k, by_q, by_row, by_row, by_row]
 
     pairs = block_pairs(S, bq, bk, causal)
@@ -372,7 +417,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
         grid_spec=_grid_spec(
             pairs, BH, in_specs=in_specs, out_specs=by_q,
             scratch_shapes=[_vmem((bq, D)), _vmem((3, bq, 1))]),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype))
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype))
 
     pairs = block_pairs(S, bq, bk, causal, by_key=True)
     dk, dv = _pallas_call(
@@ -382,8 +427,8 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
         grid_spec=_grid_spec(
             pairs, BH, in_specs=in_specs, out_specs=[by_k, by_k],
             scratch_shapes=[_vmem((bk, D)), _vmem((bk, D))]),
-        out_shape=[jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, S, D), v.dtype)])
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)])
     return dq, dk, dv
 
 
@@ -399,18 +444,22 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k):
 SAVED_NAMES = ("flash_o", "flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, block_q, block_k, out_f32, named):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, heads, scale, causal, block_q, block_k, out_f32, named):
+    return _flash_fwd(q, k, v, heads, scale, causal, block_q, block_k,
+                      out_f32)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32,
+def _flash_vjp_fwd(q, k, v, heads, scale, causal, block_q, block_k, out_f32,
                    named):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, out_f32)
+    o, lse = _flash_fwd(q, k, v, heads, scale, causal, block_q, block_k,
+                        out_f32)
     # lse is kept as [BH, S], a reshape of the kernel's own output (what
     # tiles a stack of [.., 1, bq] blocks over a scan's layers would get
-    # is XLA's to choose; [L, BH, S] pads nothing).
-    rows = lse.reshape(q.shape[:2])
+    # is XLA's to choose; [L, BH, S] pads nothing).  q, k, v and o are
+    # kept as they lie: where the operands are [B, S, H * D] so are the
+    # residuals, the stacked o a scan saves among them.
+    rows = lse.reshape(lse.shape[0], -1)
     if named:
         # The NAMED o is also the primal output, so that under a policy
         # that saves the names nothing downstream of the kernel asks the
@@ -420,48 +469,82 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, out_f32,
     return (o, lse), (q, k, v, o, rows)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, out_f32, named, res, g):
-    return _flash_bwd(res, g, scale, causal, block_q, block_k)
+def _flash_vjp_bwd(heads, scale, causal, block_q, block_k, out_f32, named,
+                   res, g):
+    return _flash_bwd(res, g, heads, scale, causal, block_q, block_k)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _run_flash(q, k, v, causal, scale, block_q, block_k, out_f32=False,
-               named=False):
-    B, S, H, D = q.shape
+def _run_flash(q, k, v, n_heads, causal, scale, block_q, block_k,
+               out_f32=False, named=False):
+    """The three calls over ``q``, ``k``, ``v`` ``[B, S, H, D]`` or
+    ``[B, S, H * D]`` with ``n_heads`` = H: ``(o, lse)``, ``o`` shaped as
+    ``q`` and ``lse`` ``[B, S, H]``.  Which way the calls read them is
+    read off ``D``: a whole number of lane tiles (``D % 128 == 0``) and a
+    head's block is cut out of ``[B, S, H * D]`` where it lies
+    (:func:`_block_specs`); any other and the heads are folded in front of
+    the SAME calls, ``[B * H, S, D]`` with one head a row, which is a
+    transposition of every operand and result."""
+    if q.ndim == 4:
+        B, S, H, D = q.shape
+        if n_heads not in (None, H):
+            raise ValueError(f"n_heads={n_heads} with operands {q.shape}")
+    elif n_heads is None or q.shape[2] % n_heads:
+        raise ValueError(
+            f"operands {q.shape} take n_heads, a divisor of their width")
+    else:
+        (B, S, F), H = q.shape, n_heads
+        D = F // H
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    in_place = D % 128 == 0
 
     def fold(x):
-        return jnp.moveaxis(x, 2, 1).reshape(B * H, S, D)
+        if in_place:
+            return x.reshape(B, S, H * D)
+        return jnp.moveaxis(x.reshape(B, S, H, D), 2, 1).reshape(B * H, S, D)
 
-    o, lse = _flash(fold(q), fold(k), fold(v), float(scale),
-                    bool(causal), int(block_q), int(block_k),
+    o, lse = _flash(fold(q), fold(k), fold(v), H if in_place else 1,
+                    float(scale), bool(causal), int(block_q), int(block_k),
                     bool(out_f32), bool(named))
-    o = jnp.moveaxis(o.reshape(B, H, S, D), 1, 2)
+    if not in_place:
+        o = jnp.moveaxis(o.reshape(B, H, S, D), 1, 2)
     lse = jnp.moveaxis(lse.reshape(B, H, S), 1, 2)   # [B, S, H]
-    return o, lse
+    return o.reshape(q.shape), lse
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 512):
-    """Blockwise flash attention.  ``q/k/v``: [B, S, H, D].
+                    block_q: int = 512, block_k: int = 512,
+                    n_heads: Optional[int] = None):
+    """Blockwise flash attention.  ``q/k/v``: [B, S, H, D], or
+    [B, S, H * D] (a head's D values side by side, the heads in order: what
+    a 2-D projection ``"bsd,df->bsf"`` writes) with ``n_heads`` = H.
 
-    Returns [B, S, H, D] context.  Differentiable (custom VJP running the
-    flash backward kernels).  Its output and log-sum-exp carry the names
-    :data:`SAVED_NAMES` among the backward kernels' residuals: a
+    Returns the context, shaped as ``q``.  Differentiable (custom VJP
+    running the flash backward kernels).  Its output and log-sum-exp carry
+    the names :data:`SAVED_NAMES` among the backward kernels' residuals: a
     ``jax.checkpoint`` whose policy saves those names runs the forward
     kernel once, not again in its re-forward.
+
+    Where ``D`` is a multiple of 128 the kernels read and write
+    ``[B, S, H * D]`` in place, so operands given in that form are never
+    copied, nor the results, nor the gradients (on a TPU ``[B, S, H, D]``
+    is another tiling of memory and the reshape between the two a copy:
+    the 4-D form pays it on the way in and out).  With any other ``D``
+    every operand and result is transposed to ``[B * H, S, D]`` and back.
     """
-    o, _ = _run_flash(q, k, v, causal, scale, block_q, block_k, named=True)
+    o, _ = _run_flash(q, k, v, n_heads, causal, scale, block_q, block_k,
+                      named=True)
     return o
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
-                        block_q: int = 512, block_k: int = 512):
+                        block_q: int = 512, block_k: int = 512,
+                        n_heads: Optional[int] = None):
     """Like :func:`flash_attention` but also returns the per-query
     logsumexp ``[B, S, H]`` (fp32).  The pair ``(o, lse)`` is what
     blockwise composition needs: partial attentions over disjoint key
@@ -472,5 +555,5 @@ def flash_attention_lse(q, k, v, *, causal: bool = True,
     fp32 (no per-hop rounding when partials are combined).  Its
     residuals carry no name: a checkpointed ring keeps none of its
     ``sp`` hops' partials."""
-    return _run_flash(q, k, v, causal, scale, block_q, block_k,
+    return _run_flash(q, k, v, n_heads, causal, scale, block_q, block_k,
                       out_f32=True)

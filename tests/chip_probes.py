@@ -190,6 +190,51 @@ def flash_grad_calls(seq_len, sharding):
         r"(?:, backend_config=.*)?$", text, re.M)
 
 
+# Result shapes a transposition around the attention kernel's calls would
+# have at the LM training cell's widths: [B, H, S, D] (with [B, S, H, D] in
+# a layout of its own), the folded [B * H, S, D], and [B, S, H * D].
+FLASH_LAYOUT_SHAPES = ("[8,16,2048,128]", "[128,2048,128]", "[8,2048,2048]")
+
+
+def flash_step_layout_copies(device):
+    """The flash LM step at ``olmo-1b_train_s2048``'s widths (two layers;
+    8 rows of 2048 tokens, 16 heads of 128) compiled for one ``v5e`` chip:
+    the names of its Mosaic calls, and every ``copy`` or ``transpose``
+    INSIDE THE TWO SCANS' BODIES (fusions they call included) whose result
+    has one of :data:`FLASH_LAYOUT_SHAPES`, as ``[name, result type]``: what
+    XLA turns round to feed the kernel's calls or to take their results
+    on.  The step's copies of ``[8,2048,2048]`` outside the scans (the
+    embedding's, the last layer's) are not the kernel's."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.parallel import mesh as mesh_mod
+    from horovod_tpu.parallel import train as train_mod
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=50304, d_model=2048, n_layers=2, n_heads=16, d_ff=8192,
+        max_seq_len=2048, attn_impl="flash")
+    mesh = mesh_mod.make_mesh({"dp": 1}, devices=[device])
+    step, init = train_mod.make_transformer_train_step(
+        cfg, mesh, optax.adamw(1e-3, weight_decay=0.01))
+    state = jax.eval_shape(init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((8, 2048), jnp.int32)
+    text = step.lower(state, toks, toks).compile().as_text()
+    comps = computations(text)
+    bodies = set(re.findall(r"\bwhile\(.*?\bbody=%?([\w.\-]+)", text))
+    inside = bodies | {calls for comp in bodies
+                       for _, _, _, op, calls in comps[comp] if calls}
+    turned = [[name, shape] for comp in sorted(inside)
+              for _, name, shape, op, _ in comps.get(comp, ())
+              if op in ("copy", "transpose")
+              and any(dims in shape for dims in FLASH_LAYOUT_SHAPES)]
+    kernels = re.findall(
+        r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", text, re.M)
+    return {"scan_bodies": len(bodies), "kernels": kernels, "turned": turned}
+
+
 def probe_lower_for_tpu(meshes_json):
     """Mosaic custom calls in a small flash LM step lowered, from this CPU
     process, for the compile-only ``v5e:2x2`` topology, and the names of
@@ -281,6 +326,7 @@ def probe_lower_for_tpu(meshes_json):
             * rcfg.state_rows, one_chip)
         flash_alone = [pool.submit(flash_grad_calls, seq_len, one_chip)
                        for seq_len in FLASH_ALONE]
+        flash_layout = pool.submit(flash_step_layout_copies, topo.devices[0])
         found = list(pool.map(mosaic_calls, meshes))
     print("RESULT", json.dumps({
         "device_kind": topo.devices[0].device_kind,
@@ -290,6 +336,7 @@ def probe_lower_for_tpu(meshes_json):
         "serve_sparse": serve_sparse.result(),
         "serve_retention": serve_retention.result(),
         "flash_alone": [calls.result() for calls in flash_alone],
+        "flash_layout": flash_layout.result(),
         "tpu_custom_call": [n for n, _ in found],
         "kernel_names": [names for _, names in found]}))
 
@@ -300,13 +347,10 @@ _INSTR = re.compile(
 _PLUMBING = ("parameter", "get-tuple-element", "tuple", "bitcast", "while")
 
 
-def big_ops(hlo_text, min_elems):
-    """The instructions of a compiled program that produce an array of at
-    least ``min_elems`` elements, as ``[name, opcode]``: those outside
-    fused computations (a fusion's inner instructions never reach memory)
-    and other than plumbing (parameters, tuples, bitcasts, the ``while``
-    itself).  A fusion is named by its root's opcode (``fusion:scatter``)."""
-    comps, body = {}, None      # computation -> (root, name, type, op, calls)
+def computations(hlo_text):
+    """A compiled program's computations by name, each the list of its
+    instructions as ``(root, name, type, opcode, calls)``."""
+    comps, body = {}, None
     for line in hlo_text.splitlines():
         head = re.match(r"\s*(?:ENTRY )?%?([\w.\-]+) \(.*->.*\{\s*$", line)
         instr = _INSTR.match(line)
@@ -314,6 +358,16 @@ def big_ops(hlo_text, min_elems):
             body = comps.setdefault(head.group(1), [])
         elif body is not None and instr:
             body.append(instr.groups())
+    return comps
+
+
+def big_ops(hlo_text, min_elems):
+    """The instructions of a compiled program that produce an array of at
+    least ``min_elems`` elements, as ``[name, opcode]``: those outside
+    fused computations (a fusion's inner instructions never reach memory)
+    and other than plumbing (parameters, tuples, bitcasts, the ``while``
+    itself).  A fusion is named by its root's opcode (``fusion:scatter``)."""
+    comps = computations(hlo_text)
     fused = {calls for instrs in comps.values()
              for _, _, _, op, calls in instrs if op == "fusion"}
     roots = {comp: op for comp, instrs in comps.items()

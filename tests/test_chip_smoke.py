@@ -213,6 +213,34 @@ def test_flash_gradient_alone_compiles_for_the_chip_at_the_cells_blocks(
         assert re.search(r"f32\[2,\d+,1,\d+\]", text), (name, text)
 
 
+def test_flash_lm_step_turns_nothing_round_about_its_kernel(probes):
+    """The flash LM step compiled for ``v5e`` at the training cell's widths
+    carries the attention kernel's three Mosaic calls, the forward once,
+    beside the rotation's (``rope_lanes``: q and k in the forward body,
+    q, k and the two gradients in the backward), and in its two scans'
+    bodies no ``copy`` or ``transpose`` of a q, k, v, context or gradient
+    (``[8,16,2048,128]``, ``[128,2048,128]``, ``[8,2048,2048]``) but ONE:
+    the kept context's way back from the layout XLA gives its stack over
+    the layers (``wo``'s weight-gradient product reads it positions-minor;
+    ``PERF.md`` section 6, PR 44).  The layer hands the kernels
+    ``[B, S, H * D]`` and they read and write it in place; with the
+    model's ``[B, S, H, D]`` and the fold to ``[B * H, S, D]`` there were
+    eight, 67 and 134 MB each."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    found = json.loads(out.split("RESULT", 1)[1])["flash_layout"]
+    assert found["scan_bodies"] == 2, found
+    stems = [n.rsplit(".", 1)[0] for n in found["kernels"]]
+    assert sorted(set(stems)) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                  "flash_fwd", "rope_lanes"], found
+    assert [stems.count(n) for n in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "rope_lanes")] == [
+        1, 1, 1, 6], found
+    assert len(found["turned"]) <= 1, found
+    assert all("bf16[8,2048,2048]" in shape
+               for _, shape in found["turned"]), found
+
+
 @pytest.mark.parametrize("program,update", [
     ("step", "fusion:scatter"), ("install", "fusion:dynamic-update-slice")])
 def test_serving_cache_programs_update_in_place_on_the_chip(

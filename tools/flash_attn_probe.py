@@ -3,12 +3,15 @@
 the LM training cell's shape or a given one.
 
     chiprun -- python3 tools/flash_attn_probe.py [--shape B,H,S,D]
-        [--non-causal] [--lse] [--steps 10]
+        [--non-causal] [--lse] [--flat] [--steps 10]
 
 It runs ``jax.grad`` of ``flash_attention`` (``--lse``:
 ``flash_attention_lse`` with a cotangent on the log-sum-exp, a ring hop's
-call) ``--steps`` times under the profiler and reads the device's own
-clock, as the cell's per-kernel metrics do (``perfbench/readers/
+call; ``--flat``: of operands ``[B, S, H * D]`` with ``n_heads``, which
+the model's flash branch passes and the calls read in place where ``D`` is
+a multiple of 128, not ``[B, S, H, D]``, whose reshape to that form is a
+copy on the chip) ``--steps`` times under the profiler and reads the
+device's own clock, as the cell's per-kernel metrics do (``perfbench/readers/
 kernel_ms.py``: self time of the Mosaic calls by instruction name).  One
 JSON line a call: milliseconds a call and the share of the call's matmul
 roofline (2, 3 and 4 products of S x S x D a head, half of each where
@@ -45,6 +48,7 @@ def main():
                     help="B,H,S,D (default: olmo-1b_train_s2048's)")
     ap.add_argument("--non-causal", action="store_true")
     ap.add_argument("--lse", action="store_true")
+    ap.add_argument("--flat", action="store_true")
     ap.add_argument("--steps", type=int, default=10)
     a = ap.parse_args()
     dev = jax.devices()[0]
@@ -53,14 +57,16 @@ def main():
     peak = device_peaks.peak(dev.device_kind).bf16_flops
     B, H, S, D = map(int, a.shape.split(","))
     causal = not a.non_causal
-    q, k, v, w = (jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
+    shape, heads = ((B, S, H * D), {"n_heads": H}) if a.flat else (
+        (B, S, H, D), {})
+    q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
                   for key in jax.random.split(jax.random.PRNGKey(0), 4))
 
     def loss(q, k, v):
         if a.lse:
-            o, lse = pa.flash_attention_lse(q, k, v, causal=causal)
+            o, lse = pa.flash_attention_lse(q, k, v, causal=causal, **heads)
             return jnp.sum(o * w) + jnp.sum(lse)
-        o = pa.flash_attention(q, k, v, causal=causal)
+        o = pa.flash_attention(q, k, v, causal=causal, **heads)
         return jnp.sum(o.astype(jnp.float32) * w)
 
     grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
@@ -79,7 +85,7 @@ def main():
             and name in n.split("=", 1)[0])
         print(json.dumps({
             "call": name, "shape": [B, H, S, D], "causal": causal,
-            "lse_cotangent": a.lse, "calls": calls,
+            "lse_cotangent": a.lse, "flat": a.flat, "calls": calls,
             "ms_a_call": round(1e3 * seconds / max(calls, 1), 4),
             "matmul_roofline_pct": round(
                 100 * matmuls * needed / peak / (seconds / calls), 2)
