@@ -1,6 +1,7 @@
 """The one table of accelerator peaks, keyed by JAX's ``device_kind``.
 
-``bench.py``, ``chip_smoke.py`` and ``tools/measure_overlap.py`` read it.  A
+``chip_smoke.py`` and ``tools/measure_overlap.py`` read it (the benchmark
+keeps its own copy, ``perfbench/peaks.py``).  A
 device that is not in the table is an error, never a default: a utilization
 computed against a guessed peak is worse than none.
 """

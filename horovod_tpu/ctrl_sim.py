@@ -38,8 +38,7 @@ kernel — and each tree sample is observed into
 ``hvd_ctrl_cycle_seconds{ranks}`` so the metric the real coordinator
 emits gets scale coverage too.
 
-Used by ``bench.py`` (``coordination_cycle_p50_us``) and
-``tests/test_ctrl_tree.py``; runnable standalone::
+Used by ``tests/test_ctrl_tree.py``; runnable standalone::
 
     python -m horovod_tpu.ctrl_sim            # 8/64/256-rank curve
 """
@@ -282,7 +281,7 @@ def run_curve(sizes: Tuple[int, ...] = CURVE_SIZES, cycles: int = 40,
     Returns a flat dict of microsecond p50s keyed
     ``ctrl_cycle_{mode}_p50_us_{size}``, plus the headline
     ``coordination_cycle_p50_us`` — the hierarchical p50 at the largest
-    size (the 256-rank proof point ``bench.py`` regresses on).  Tree
+    size (the 256-rank proof point).  Tree
     samples are observed into ``hvd_ctrl_cycle_seconds{ranks}``.
 
     The two modes are measured in ``repeats`` interleaved passes and

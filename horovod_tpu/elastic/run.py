@@ -120,8 +120,18 @@ class _ElasticContext:
         return int(v) > self._seen_notify if v else False
 
     def consume_updates(self) -> None:
+        """Take the updates published so far as handled -- but for a
+        joiner this gang has room for and has not admitted.  It puts its
+        pending key and THEN bumps the count, possibly while this rank
+        was still starting or re-forming; its bump consumed, nobody would
+        ever admit it.  Left unseen, it interrupts at the next commit."""
         v = self.kv.get(self.key("elastic/notify"))
         self._seen_notify = int(v) if v else 0
+        if len(self.roster) < self.max_np:
+            prefix = self.key("elastic/pending/")
+            if any(k[len(prefix):] not in self.roster
+                   for k in self.kv.list(prefix)):
+                self._seen_notify -= 1
 
     def publish_update(self) -> None:
         v = self.kv.get(self.key("elastic/notify"))

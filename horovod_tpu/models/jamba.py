@@ -36,6 +36,9 @@ chip's (8, 128) tiles hold no padding::
                                  [Lm, d_conv - 1, B, d_inner] compute_dtype}
 
 A request's state (``prefill_request``) is the same pytree with B = 1.
+The module omits what ``serving/decode.py:MODELS`` lets it: no sharding
+of this state is written (recurrent state under tp), and the weights come
+in ``param_dtype``, which is for the caller to choose.
 """
 
 from __future__ import annotations
@@ -48,8 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.transformer import (_dense_ffn, _rmsnorm,
-                                            vocab_projection)
+from horovod_tpu.models.layers import (_at, _dense_ffn, _logits, _put,
+                                       _rmsnorm)
 
 Params = Dict[str, Any]
 State = Dict[str, Tuple[jax.Array, jax.Array]]
@@ -332,13 +335,9 @@ def init_state(cfg: JambaConfig, max_batch: int, cache_len: int) -> State:
                       cfg.compute_dtype))}
 
 
-def _at(stacked, l):
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stacked)
-
-
-def _put(stacked, l, value):
-    return lax.dynamic_update_index_in_dim(stacked, value, l, 0)
+# The axis of each slot-kind leaf that the slots lie along: the convolution
+# state keeps its window's rows ahead of them.
+SLOT_AXES = {"kv": (1, 1), "recurrent": (1, 2)}
 
 
 def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
@@ -388,10 +387,6 @@ def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
     return x, ({"kv": kv, "recurrent": rec} if carries else None)
 
 
-def _logits(params: Params, x):
-    return vocab_projection(_rmsnorm(x, params["ln_f"]), params["embed"])
-
-
 # ---------------------------------------------------------------------------
 # the three entry points
 # ---------------------------------------------------------------------------
@@ -402,7 +397,7 @@ def forward(params: Params, tokens, cfg: JambaConfig):
     the tests' oracle, not a fast path."""
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x, _ = _stack(params, x, cfg, None)
-    return _logits(params, x)
+    return _logits(x, params["ln_f"], params["embed"])
 
 
 def prefill_request(params: Params, prompt, cfg: JambaConfig,
@@ -412,22 +407,7 @@ def prefill_request(params: Params, prompt, cfg: JambaConfig,
     keys and values at rows [0, S0) and zero past them)."""
     x = params["embed"].astype(cfg.compute_dtype)[prompt[None]]
     x, state = _stack(params, x, cfg, init_state(cfg, 1, cache_len))
-    return _logits(params, x[:, -1:])[0, 0], state
-
-
-def install_request(state: State, slot, request: State) -> State:
-    """Write a request's state over slot ``slot``'s: the whole key/value
-    lane and ALL of the slot's recurrent and convolution state, so that
-    nothing of the slot's last tenant is left.  ``state`` donated, the
-    writes are in place."""
-    (ks, vs), (ssm, conv) = state["kv"], state["recurrent"]
-    (k1, v1), (ssm1, conv1) = request["kv"], request["recurrent"]
-    return {
-        "kv": (lax.dynamic_update_slice(ks, k1, (0, slot, 0, 0, 0)),
-               lax.dynamic_update_slice(vs, v1, (0, slot, 0, 0, 0))),
-        "recurrent": (lax.dynamic_update_slice(ssm, ssm1, (0, slot, 0, 0)),
-                      lax.dynamic_update_slice(conv, conv1,
-                                               (0, 0, slot, 0)))}
+    return _logits(x[:, -1:], params["ln_f"], params["embed"])[0, 0], state
 
 
 def decode_step(params: Params, tok, pos, state: State, cfg: JambaConfig):
@@ -438,15 +418,4 @@ def decode_step(params: Params, tok, pos, state: State, cfg: JambaConfig):
     alone."""
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
     x, state = _stack(params, x, cfg, state, pos)
-    return _logits(params, x)[:, 0], state
-
-
-# The state's sharding: none is written (recurrent state under tp), so
-# serving/decode.py refuses a mesh.
-STATE_SPEC = None
-
-
-def serving_params(params: Params, cfg: JambaConfig) -> Params:
-    """``params`` as a serving engine holds them: as given.  The weights
-    come in ``param_dtype``, which is for the caller to choose."""
-    return params
+    return _logits(x, params["ln_f"], params["embed"])[:, 0], state

@@ -93,7 +93,10 @@ State of a served batch (``init_state``)::
 A request's state (``prefill_request``) is the slot kinds with B = 1.
 A slot whose position is 0 is free (``DecodeEngine.clear``): its row is
 kept out of the routing, so a free slot pulls no expert's weights through
-the chip, and out of the counters.
+the chip, and out of the counters.  The module omits what
+``serving/decode.py:MODELS`` lets it: no sharding of this state is written
+(experts under ep, heads of the absorbed form under tp), and the weights
+come in ``param_dtype``, which is for the caller to choose.
 """
 
 from __future__ import annotations
@@ -110,11 +113,9 @@ import numpy as np
 from jax import lax
 
 from horovod_tpu.models import experts
-from horovod_tpu.models.jamba import _at
-from horovod_tpu.models.transformer import (ATTN_COUNTERS, _dense_ffn,
-                                            _rmsnorm, _rope,
-                                            count_attention_reads,
-                                            vocab_projection)
+from horovod_tpu.models.layers import (ATTN_COUNTERS, _at, _dense_ffn,
+                                       _logits, _rmsnorm, _rope,
+                                       add_counters, count_attention_reads)
 from horovod_tpu.ops.pallas_decode_attention import (block_for,
                                                      decode_attention,
                                                      ordered, work_list)
@@ -521,6 +522,10 @@ def init_state(cfg: LatentMoEConfig, max_batch: int, cache_len: int
                                   for name in counter_names(cfg)}}
 
 
+# The axis of each slot-kind leaf that the slots lie along.
+SLOT_AXES = {"kv": (1, 1), "index": 1}
+
+
 def _lanes(state: State) -> Tuple:
     """A state's position-indexed arrays, in ``_attention``'s order."""
     return state["kv"] + ((state["index"],) if "index" in state else ())
@@ -610,11 +615,6 @@ def _stack(params: Params, x, cfg: LatentMoEConfig, kv=None, pos=None):
     return x, (kv if keeps else None), stats
 
 
-def _logits(params: Params, x, cfg: LatentMoEConfig):
-    return vocab_projection(_rmsnorm(x, params["ln_f"], cfg.rms_norm_eps),
-                            params["head"])
-
-
 # ---------------------------------------------------------------------------
 # the three entry points
 # ---------------------------------------------------------------------------
@@ -625,7 +625,7 @@ def forward(params: Params, tokens, cfg: LatentMoEConfig):
     the tests' oracle, not a fast path."""
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x, _, _ = _stack(params, x, cfg)
-    return _logits(params, x, cfg)
+    return _logits(x, params["ln_f"], params["head"], cfg.rms_norm_eps)
 
 
 def prefill_request(params: Params, prompt, cfg: LatentMoEConfig,
@@ -636,17 +636,8 @@ def prefill_request(params: Params, prompt, cfg: LatentMoEConfig,
     rows [0, S0) and zero past them)."""
     x = params["embed"].astype(cfg.compute_dtype)[prompt[None]]
     x, kv, _ = _stack(params, x, cfg, _lanes(init_state(cfg, 1, cache_len)))
-    return _logits(params, x[:, -1:], cfg)[0, 0], _slots(kv)
-
-
-def install_request(state: State, slot, request: State) -> State:
-    """Write a request's lanes over slot ``slot``'s, whole, so that
-    nothing of the slot's last tenant is left.  ``state`` donated, the
-    writes are in place; the counters pass through."""
-    return {**_slots(tuple(
-        lax.dynamic_update_slice(lane, new, (0, slot, 0, 0))
-        for lane, new in zip(_lanes(state), _lanes(request)))),
-            "counters": state["counters"]}
+    return _logits(x[:, -1:], params["ln_f"], params["head"],
+                   cfg.rms_norm_eps)[0, 0], _slots(kv)
 
 
 def decode_step(params: Params, tok, pos, state: State,
@@ -670,22 +661,10 @@ def decode_step(params: Params, tok, pos, state: State,
         add[INDEX_COUNTERS[0]] = L * jnp.sum(written)
         add[INDEX_COUNTERS[1]] = L * jnp.sum(
             jnp.minimum(written, cfg.index_topk))
-    counters = {**state["counters"],
-                **{name: state["counters"][name] + a.astype(jnp.uint32)
-                   for name, a in add.items()}}
     cache_len = kv[0].shape[2]
     counters = count_attention_reads(
-        counters, pos, cache_len, L, block_for(cache_len, shared=True))
-    return _logits(params, x, cfg)[:, 0], {**_slots(kv),
-                                           "counters": counters}
-
-
-# The state's sharding: none is written (experts under ep, heads of the
-# absorbed form under tp), so serving/decode.py refuses a mesh.
-STATE_SPEC = None
-
-
-def serving_params(params: Params, cfg: LatentMoEConfig) -> Params:
-    """``params`` as a serving engine holds them: as given.  The weights
-    come in ``param_dtype``, which is for the caller to choose."""
-    return params
+        add_counters(state["counters"], add), pos, cache_len, L,
+        block_for(cache_len, shared=True))
+    return (_logits(x, params["ln_f"], params["head"],
+                    cfg.rms_norm_eps)[:, 0],
+            {**_slots(kv), "counters": counters})

@@ -1,0 +1,145 @@
+"""What two or more model files use, said once: the norm, the rotation,
+the dense gated feed-forward and the vocabulary projection every decoder
+here is built from, the indexing of a stack of layers, and the part of the
+serving seam (``serving/decode.py``) that is the same statement for every
+model: how a request's state is written over a slot, and how a step moves
+the device counters on.
+
+The model files (``transformer``, ``jamba``, ``latent_moe``,
+``retention``, ``resnet``) import this module and ``experts`` and never
+one another; this module imports none of them.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def _rmsnorm(x, g, eps: float = 1e-6):
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * g).astype(x.dtype)
+
+
+def _rope_angles(theta, half: int, seq_len: int, pos):
+    """The angle [(B,) S, half] that pair i of a head is turned by at each
+    position: see :func:`_rope`."""
+    freqs = theta if hasattr(theta, "shape") else jnp.exp(
+        -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    if pos is None:
+        pos = jnp.arange(seq_len, dtype=jnp.float32)
+    return pos.astype(jnp.float32)[..., None] * freqs
+
+
+def _rope(x, theta, pos=None):
+    """Rotary embedding over head_dim pairs; x: [B, S, H, HD].
+    ``theta``: the base, pair i turning by ``theta^(-i / (HD/2))`` a
+    position, or the table [HD/2] of those frequencies itself (a scaled
+    one: models/latent_moe.py's ``rope_frequencies``).
+    ``pos``: the absolute positions, [S] (shared by the rows) or [B, S]
+    (a row's own: a serving slot rotates its one new token at its own
+    offset); default ``arange(S)``."""
+    B, S, H, HD = x.shape
+    half = HD // 2
+    ang = _rope_angles(theta, half, S, pos)
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
+    return jnp.concatenate(
+        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def _dense_ffn(x, lp, dtype):
+    h = jnp.einsum("bsd,df->bsf", x, lp["w_in"].astype(dtype))
+    g = jnp.einsum("bsd,df->bsf", x, lp["w_gate"].astype(dtype))
+    h = h * jax.nn.silu(g)
+    return jnp.einsum("bsf,fd->bsd", h, lp["w_out"].astype(dtype))
+
+
+def vocab_projection(x, embed):
+    """Final [B,S,D] → [B,S,V] projection: compute-dtype inputs on the
+    MXU, f32 accumulation (an f32xf32 dot here ran at the MXU's
+    multi-pass fp32 rate and was the single hottest op of the step).
+    Shared with the pipelined path (parallel/pipeline.py)."""
+    return jnp.einsum("bsd,vd->bsv", x, embed.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _logits(x, gain, table, eps: float = 1e-6):
+    """The head: the final norm's ``gain`` over x [B, S, D], then the
+    projection on ``table`` [V, D] (the tied embedding, or a head of its
+    own)."""
+    return vocab_projection(_rmsnorm(x, gain, eps), table)
+
+
+def _at(stacked, l):
+    """Layer ``l`` of every leaf of a stack of layers (``l`` traced)."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), stacked)
+
+
+def _put(stacked, l, value):
+    return lax.dynamic_update_index_in_dim(stacked, value, l, 0)
+
+
+# ---------------------------------------------------------------------------
+# the serving seam's shared part
+# ---------------------------------------------------------------------------
+
+
+def install_request(state, slot, request, axes):
+    """Write a request's state over slot ``slot``'s: every leaf of every
+    slot kind, whole, so that nothing of the slot's last tenant is left;
+    whatever else ``state`` holds at the top (the counters, which no slot
+    owns) passes through.  ``axes``: the model's ``SLOT_AXES``, for each
+    slot kind its state may hold the axis of each leaf that the slots lie
+    along (a request's leaf has one slot there).  ``state`` donated, the
+    writes are in place: one ``dynamic_update_slice`` a leaf."""
+    def over(lane, new, axis):
+        at = [0] * lane.ndim
+        at[axis] = slot
+        return lax.dynamic_update_slice(lane, new, at)
+
+    return {**state, **{
+        kind: jax.tree.map(over, state[kind], request[kind], axes[kind])
+        for kind in axes if kind in state}}
+
+
+def add_counters(counters, add):
+    """``counters`` (a state's ``"counters"``: registry names to uint32
+    scalars) moved on by ``add``, name to increment; a name ``add`` does
+    not hold stays as it is.  uint32 and read as differences, so they may
+    wrap between two reads but not twice."""
+    return {**counters, **{name: counters[name] + a.astype(jnp.uint32)
+                           for name, a in add.items()}}
+
+
+# What a decode step that attends lanes of positions adds to
+# ``state["counters"]``, over its layers: positions of the slots' lanes in
+# the blocks its attention fetched, and positions those lanes hold
+# (``max_batch x cache_len`` a layer; 2**32 positions are thousands of
+# turns of the largest table here).
+ATTN_COUNTERS = ("hvd_serve_attn_positions_read_total",
+                 "hvd_serve_attn_positions_held_total")
+
+
+def count_attention_reads(counters, pos, cache_len: int, n_layers: int,
+                          block: Optional[int]):
+    """``counters`` with ATTN_COUNTERS moved on by one decode step of
+    ``n_layers`` layers over slots at ``pos`` [B] in lanes of
+    ``cache_len``: ``block`` is the kernel's (the blocks up to each
+    slot's position are read), or None where the whole lane is."""
+    held = jnp.uint32(n_layers * pos.shape[0] * cache_len)
+    read = held
+    if block is not None:
+        from horovod_tpu.ops.pallas_decode_attention import pairs_run
+
+        read = (pairs_run(pos, block) * (n_layers * block)).astype(jnp.uint32)
+    return add_counters(counters, dict(zip(ATTN_COUNTERS, (read, held))))

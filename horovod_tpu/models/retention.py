@@ -58,7 +58,10 @@ cap (the rotation's) and sizes nothing.  A request's state
 (``prefill_request``) is the ``"recurrent"`` part with B = 1.  A slot
 whose position is 0 is free (``DecodeEngine.clear``): its state is
 stepped too (rows never mix, and a gate under one keeps it bounded) and
-the next install overwrites all of it.
+the next install overwrites all of it.  The module omits what
+``serving/decode.py:MODELS`` lets it: no sharding of this state is written
+(its heads under tp), and the weights come in ``param_dtype``, which is
+for the caller to choose.
 """
 
 from __future__ import annotations
@@ -72,9 +75,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from horovod_tpu.models.jamba import _at
-from horovod_tpu.models.transformer import (_dense_ffn, _rmsnorm, _rope,
-                                            vocab_projection)
+from horovod_tpu.models.layers import (_at, _dense_ffn, _logits, _put,
+                                       _rmsnorm, _rope, add_counters)
 from horovod_tpu.ops.pallas_retention import retention_step
 
 Params = Dict[str, Any]
@@ -215,7 +217,7 @@ def _state_pass(S, z, layer, phi_all, n_q: int, v, decay):
     den = jnp.einsum("bkgd,bkd->bkg", phi_all[:, :, :n_q], z_old,
                      precision=HI)
     z_new = decay[..., None] * z_old + phi_all[:, :, n_q]
-    return S, lax.dynamic_update_index_in_dim(z, z_new, layer, 0), num, den
+    return S, _put(z, layer, z_new), num, den
 
 
 def _retention(x, lp, cfg: RetentionConfig, state=None):
@@ -324,6 +326,10 @@ def init_state(cfg: RetentionConfig, max_batch: int, cache_len: int
         "counters": {name: jnp.zeros((), jnp.uint32) for name in COUNTERS}}
 
 
+# The axis of each slot-kind leaf that the slots lie along.
+SLOT_AXES = {"recurrent": (1, 1)}
+
+
 def _stack(params: Params, x, cfg: RetentionConfig,
            rec: Optional[Tuple] = None, pos=None):
     """x [B, S, D] through every layer.  ``pos`` None: the sequences
@@ -341,8 +347,7 @@ def _stack(params: Params, x, cfg: RetentionConfig,
         if start:
             y, (s_end, z_end) = _retention(y, lp, cfg)
             if keeps:
-                rec = (lax.dynamic_update_index_in_dim(rec[0], s_end, l, 0),
-                       lax.dynamic_update_index_in_dim(rec[1], z_end, l, 0))
+                rec = (_put(rec[0], l, s_end), _put(rec[1], l, z_end))
         else:
             y, rec = _retention(y, lp, cfg, (*rec, l, pos))
         h = h + y
@@ -351,10 +356,6 @@ def _stack(params: Params, x, cfg: RetentionConfig,
     x, rec = lax.fori_loop(0, cfg.num_hidden_layers, layer,
                            (x, rec if keeps else ()))
     return x, (rec if keeps else None)
-
-
-def _logits(params: Params, x):
-    return vocab_projection(_rmsnorm(x, params["ln_f"]), params["head"])
 
 
 # ---------------------------------------------------------------------------
@@ -367,7 +368,7 @@ def forward(params: Params, tokens, cfg: RetentionConfig):
     the tests' oracle, not a fast path."""
     x = params["embed"].astype(cfg.compute_dtype)[tokens]
     x, _ = _stack(params, x, cfg)
-    return _logits(params, x)
+    return _logits(x, params["ln_f"], params["head"])
 
 
 def prefill_request(params: Params, prompt, cfg: RetentionConfig,
@@ -378,19 +379,8 @@ def prefill_request(params: Params, prompt, cfg: RetentionConfig,
     x = params["embed"].astype(cfg.compute_dtype)[prompt[None]]
     x, rec = _stack(params, x, cfg,
                     init_state(cfg, 1, cache_len)["recurrent"])
-    return _logits(params, x[:, -1:])[0, 0], {"recurrent": rec}
-
-
-def install_request(state: State, slot, request: State) -> State:
-    """Write a request's state over slot ``slot``'s: ALL of its ``S`` and
-    ``z`` in every layer, so that nothing of the slot's last tenant is
-    left.  ``state`` donated, the writes are in place; the counters pass
-    through."""
-    (Ss, zs), (s1, z1) = state["recurrent"], request["recurrent"]
-    return {"recurrent": (
-        lax.dynamic_update_slice(Ss, s1, (0, slot, 0, 0, 0)),
-        lax.dynamic_update_slice(zs, z1, (0, slot, 0, 0))),
-        "counters": state["counters"]}
+    return (_logits(x[:, -1:], params["ln_f"], params["head"])[0, 0],
+            {"recurrent": rec})
 
 
 def decode_step(params: Params, tok, pos, state: State,
@@ -403,20 +393,7 @@ def decode_step(params: Params, tok, pos, state: State,
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
     x, rec = _stack(params, x, cfg, state["recurrent"], pos)
     L = cfg.num_hidden_layers
-    add = ((jnp.sum(pos > 0) * L).astype(jnp.uint32),
-           jnp.uint32(L * pos.shape[0]))
-    counters = {**state["counters"],
-                **{name: state["counters"][name] + a
-                   for name, a in zip(COUNTERS, add)}}
-    return _logits(params, x)[:, 0], {"recurrent": rec, "counters": counters}
-
-
-# The state's sharding: none is written (heads of the recurrent state
-# under tp), so serving/decode.py refuses a mesh.
-STATE_SPEC = None
-
-
-def serving_params(params: Params, cfg: RetentionConfig) -> Params:
-    """``params`` as a serving engine holds them: as given.  The weights
-    come in ``param_dtype``, which is for the caller to choose."""
-    return params
+    counters = add_counters(state["counters"], dict(zip(COUNTERS, (
+        jnp.sum(pos > 0) * L, jnp.uint32(L * pos.shape[0])))))
+    return (_logits(x, params["ln_f"], params["head"])[:, 0],
+            {"recurrent": rec, "counters": counters})

@@ -20,10 +20,12 @@ experts (ep).  This model is built so that every one of those axes is a
 * bf16 compute, fp32 params/norms, RoPE positions, pre-RMSNorm blocks,
   causal masking via static ``lax`` ops only — no dynamic shapes anywhere.
 * One attention (``_attention``: projections, rotation, output; with a
-  cache or without), one rotation (``_rope``), one softmax core and one
-  layer body (``_layer``).  Training (``apply``), the prefill and the
-  decode step are that layer under three loops, so a change to what
-  attention reads or how heads are grouped is written once.  The one
+  cache or without), one rotation (``_rope``, with the norm, the dense
+  feed-forward and the head in ``models/layers.py``, which every served
+  model shares), one softmax core and one layer body (``_layer``).
+  Training (``apply``), the prefill and the decode step are that layer
+  under three loops, so a change to what attention reads or how heads
+  are grouped is written once.  The one
   branch that holds its arrays otherwise is the training kernel's
   (``_flash_attention``: ``attn_impl="flash"``, no cache): q, k, v and
   the context stay [B, S, H * HD] from the projections (one 2-D product
@@ -31,12 +33,12 @@ experts (ep).  This model is built so that every one of those axes is a
   the lanes) to ``wo``, because the kernel reads that array in place and
   on a TPU [B, S, H, HD] is a copy away from it.  The parameters keep
   their shapes; the other branches' programs do not change.
-* Cached decode is a seam, not a second model: ``init_state``,
-  ``prefill_request``, ``install_request``, ``decode_step``,
-  ``STATE_SPEC`` and ``serving_params``, the names and signatures
-  ``models/jamba.py`` gives its own.  ``serving/decode.py`` builds its
-  engine from them; ``generate()`` is ``decode_step`` with one position
-  for all rows, and the serving tests' oracle.
+* Cached decode is a seam, not a second model: ``init_state`` with
+  ``SLOT_AXES``, ``prefill_request``, ``decode_step``, ``STATE_SPEC`` and
+  ``serving_params``, the names and signatures every served module gives
+  its own.  ``serving/decode.py`` builds its engine from them;
+  ``generate()`` is ``decode_step`` with one position for all rows, and
+  the serving tests' oracle.
 """
 
 from __future__ import annotations
@@ -50,6 +52,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.models.layers import (ATTN_COUNTERS, _dense_ffn, _logits,
+                                       _rmsnorm, _rope, _rope_angles,
+                                       count_attention_reads,
+                                       vocab_projection)
 
 Params = Dict[str, Any]
 
@@ -183,42 +190,6 @@ def _constrain(x, spec: Optional[P], mesh):
         return x
     return lax.with_sharding_constraint(
         x, jax.sharding.NamedSharding(mesh, fixed))
-
-
-def _rmsnorm(x, g, eps: float = 1e-6):
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * g).astype(x.dtype)
-
-
-def _rope_angles(theta, half: int, seq_len: int, pos):
-    """The angle [(B,) S, half] that pair i of a head is turned by at each
-    position: see :func:`_rope`."""
-    freqs = theta if hasattr(theta, "shape") else jnp.exp(
-        -math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
-    if pos is None:
-        pos = jnp.arange(seq_len, dtype=jnp.float32)
-    return pos.astype(jnp.float32)[..., None] * freqs
-
-
-def _rope(x, theta, pos=None):
-    """Rotary embedding over head_dim pairs; x: [B, S, H, HD].
-    ``theta``: the base, pair i turning by ``theta^(-i / (HD/2))`` a
-    position, or the table [HD/2] of those frequencies itself (a scaled
-    one: models/latent_moe.py's ``rope_frequencies``).
-    ``pos``: the absolute positions, [S] (shared by the rows) or [B, S]
-    (a row's own: a serving slot rotates its one new token at its own
-    offset); default ``arange(S)``."""
-    B, S, H, HD = x.shape
-    half = HD // 2
-    ang = _rope_angles(theta, half, S, pos)
-    cos = jnp.cos(ang)[..., None, :]
-    sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1
-    ).astype(x.dtype)
 
 
 def _rope_flat(x, n_heads: int, theta):
@@ -423,13 +394,6 @@ def lane_reader(cfg: TransformerConfig, mesh, pos, cache_len: int):
     return lambda q, ks, vs, layer: read(q, ks, vs, layer, pos, work)
 
 
-def _dense_ffn(x, lp, dtype):
-    h = jnp.einsum("bsd,df->bsf", x, lp["w_in"].astype(dtype))
-    g = jnp.einsum("bsd,df->bsf", x, lp["w_gate"].astype(dtype))
-    h = h * jax.nn.silu(g)
-    return jnp.einsum("bsf,fd->bsd", h, lp["w_out"].astype(dtype))
-
-
 def _moe_ffn(x, lp, cfg: TransformerConfig):
     """Switch-style top-1 MoE with static capacity.
 
@@ -522,17 +486,7 @@ def apply(params: Params, tokens, cfg: TransformerConfig,
 
     (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                            params["layers"])
-    x = _rmsnorm(x, params["ln_f"])
-    return vocab_projection(x, params["embed"]), aux
-
-
-def vocab_projection(x, embed):
-    """Final [B,S,D] → [B,S,V] projection: compute-dtype inputs on the
-    MXU, f32 accumulation (an f32xf32 dot here ran at the MXU's
-    multi-pass fp32 rate and was the single hottest op of the step).
-    Shared with the pipelined path (parallel/pipeline.py)."""
-    return jnp.einsum("bsd,vd->bsv", x, embed.astype(x.dtype),
-                      preferred_element_type=jnp.float32)
+    return _logits(x, params["ln_f"], params["embed"]), aux
 
 
 def softmax_xent(logits, targets):
@@ -564,42 +518,22 @@ def loss_fn(params, tokens, targets, cfg: TransformerConfig,
 # exist for training; under a mesh the cache shards its heads over tp).
 #
 # The cache is a STATE, ``{"kv": (ks, vs)}``, each [L, B, Smax, H, HD],
-# and the four functions that make, fill, install and step it are the
-# seam serving/decode.py builds a DecodeEngine from, under the names and
-# signatures models/jamba.py gives its own.  A serving batch is ragged
-# (each slot joined at a different step and sits at its own offset), so
-# decode_step takes a position per row; generate() is the same step with
-# one position for all rows.  Rows never mix, which is what keeps a
-# continuously batched decode bit-identical to a request decoded alone.
+# and the three functions that make, fill and step it are the seam
+# serving/decode.py builds a DecodeEngine from, under the names and
+# signatures every served module gives its own (the install is the shared
+# one, models/layers.py, told where the slots lie: SLOT_AXES).  A serving
+# batch is ragged (each slot joined at a different step and sits at its
+# own offset), so decode_step takes a position per row; generate() is the
+# same step with one position for all rows.  Rows never mix, which is what
+# keeps a continuously batched decode bit-identical to a request decoded
+# alone.
 
 
 KV_CACHE_SPEC = P(None, None, None, "tp", None)  # [L, B, Smax, H, HD]
-
-# What a decode step adds to ``state["counters"]``, over its layers:
-# positions of the slots' lanes in the blocks its attention fetched, and
-# positions those lanes hold (``max_batch x cache_len`` a layer).  uint32
-# and read as differences, so they may wrap between two reads but not
-# twice (2**32 positions: thousands of turns of the largest table here).
-ATTN_COUNTERS = ("hvd_serve_attn_positions_read_total",
-                 "hvd_serve_attn_positions_held_total")
 STATE_SPEC = {"kv": (KV_CACHE_SPEC, KV_CACHE_SPEC),
               "counters": {name: P() for name in ATTN_COUNTERS}}
-
-
-def count_attention_reads(counters, pos, cache_len: int, n_layers: int,
-                          block: Optional[int]):
-    """``counters`` with ATTN_COUNTERS moved on by one decode step of
-    ``n_layers`` layers over slots at ``pos`` [B] in lanes of
-    ``cache_len``: ``block`` is the kernel's (the blocks up to each
-    slot's position are read), or None where the whole lane is."""
-    held = jnp.uint32(n_layers * pos.shape[0] * cache_len)
-    read = held
-    if block is not None:
-        from horovod_tpu.ops.pallas_decode_attention import pairs_run
-
-        read = (pairs_run(pos, block) * (n_layers * block)).astype(jnp.uint32)
-    return {**counters, ATTN_COUNTERS[0]: counters[ATTN_COUNTERS[0]] + read,
-            ATTN_COUNTERS[1]: counters[ATTN_COUNTERS[1]] + held}
+# The axis of each slot-kind leaf that the slots lie along.
+SLOT_AXES = {"kv": (1, 1)}
 
 
 def _refuse_experts(cfg: TransformerConfig):
@@ -652,15 +586,6 @@ def prefill_request(params, prompt, cfg: TransformerConfig, cache_len: int):
     lane [L, 1, cache_len, H, HD] filled through the prompt)."""
     logits, state = _prefill(params, prompt[None], cfg, cache_len)
     return logits[0], state
-
-
-def install_request(state, slot, request):
-    """Write a request's lane over slot ``slot``'s, all of it.  ``state``
-    donated, the writes are in place; the counters pass through."""
-    (ks, vs), (k1, v1) = state["kv"], request["kv"]
-    at = (0, slot, 0, 0, 0)
-    return {**state, "kv": (lax.dynamic_update_slice(ks, k1, at),
-                            lax.dynamic_update_slice(vs, v1, at))}
 
 
 def decode_step(params, tok, pos, state, cfg: TransformerConfig, mesh=None):

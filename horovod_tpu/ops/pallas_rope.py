@@ -10,7 +10,7 @@ head is its lanes i and i + HD/2, so the rotation needs each lane's partner
 it moves lanes through memory (two slices of 64 lanes, padded to 128, and a
 concatenate: three passes, one of them float32, where the rotation is one),
 or it picks another tiling for the array and copies it there and back,
-which is what ``models/transformer.py:_rope`` on ``[B, S, H, HD]`` cost
+which is what ``models/layers.py:_rope`` on ``[B, S, H, HD]`` cost
 around the attention kernel.  In a kernel it is one ``pltpu.roll`` of a
 ``[rows, HD]`` tile in vector registers: the array is read once and written
 once, in the type it has, float32 inside.
@@ -63,7 +63,7 @@ def rotate(x, cos, sin, n_heads):
     """``x`` [B, S, H * HD] with every head's pairs (i, i + HD/2) turned by
     the angles whose cosines and sines are ``cos``, ``sin`` [S, HD/2]
     (float32, the rows share their positions): the products and sums of
-    ``models/transformer.py:_rope`` on ``x`` as [B, S, H, HD], in ``x``'s
+    ``models/layers.py:_rope`` on ``x`` as [B, S, H, HD], in ``x``'s
     type and shape.  ``HD`` a multiple of 128."""
     # Lane j of a head takes x[j] cos + x[partner] (-sin | +sin): the
     # first half's partner carries a minus, the second half's a plus.

@@ -1298,8 +1298,8 @@ class PyEngine(_EngineBase):
                 _tmx.observe("hvd_cycle_duration_seconds", dt)
                 if self.rank == 0:
                     # Root coordination cost, keyed by gang size — the
-                    # curve bench.py's ctrl_sim sweep reports (and the
-                    # number the hierarchical tree exists to flatten).
+                    # curve ctrl_sim's sweep reports (and the number
+                    # the hierarchical tree exists to flatten).
                     _tmx.observe("hvd_ctrl_cycle_seconds", dt,
                                  labels=(str(self.size),))
                 if dt < self.cycle_time:

@@ -3,41 +3,39 @@
 One :class:`DecodeEngine` lives on every rank of the serving gang and
 holds the state of ``max_batch`` slots and the per-slot current token and
 position vectors.  What that state IS belongs to the model: one pytree
-that the model's module makes, fills from a prompt, installs into a slot
-and steps.  This file has no code per model: every served module presents
-the same six names (see ``MODELS``), and :func:`slot_model` binds them to
-a config through one builder.  The state's top-level keys are the kinds
-of state a slot holds:
+that the model's module makes, fills from a prompt and steps.  This file
+has no code per model: every served module presents the same names (see
+``MODELS``), and :func:`slot_model` binds them to a config through one
+builder.  The state's top-level keys are the kinds of state a slot holds:
 
 * ``"kv"``: position-indexed keys and values.  A step attends a slot's
   lane as far as the slot's position and no further, so a retired lane
   is never read; the next install overwrites it.
 * ``"recurrent"``: fixed-size state with no mask (a state-space layer's).
   The install overwrites ALL of a slot's, so nothing of its last tenant
-  reaches the next (pinned by tests/test_jamba.py).
+  reaches the next (pinned by tests/test_serving_cache.py for every
+  served form, and by tests/test_jamba.py on the served tokens).
 * ``"index"``: position-indexed keys of a learned indexer, one a position
-  a layer beside the ``"kv"`` lane it selects from (models/latent_moe.py
-  with ``index_topk``); read as far as the slot's position, like a lane.
+  a layer beside the ``"kv"`` lane it selects from; read as far as the
+  slot's position, like a lane.
 * ``"counters"``: a dict of the registry's counter names to uint32
   scalars that the model's step adds to ON THE DEVICE (what it really
-  routed; what its attention read of the lanes).  No slot owns them, an install passes them through, and
-  a turn never reads them: the engine brings them to the host beside the
-  read an admission makes anyway (the prefill's first token) and adds
-  what they grew by to the registry, when the registry is on.
+  routed; what its attention read of the lanes).  No slot owns them, an
+  install passes them through, and a turn never reads them: the engine
+  brings them to the host beside the read an admission makes anyway (the
+  prefill's first token) and adds what they grew by to the registry, when
+  the registry is on.  Which two of them make a share on ``GET /stats``
+  is declared with them (``telemetry/registry.py``), not here.
 
-The dense decoder (models/transformer.py) holds ``{"kv": (ks, vs)}``, each
-``[L, max_batch, cache_len, H, HD]``, beside two counters of what its
-steps' attention read and held; models/jamba.py holds both kinds of slot
-state;
-models/latent_moe.py holds as ``"kv"`` a latent and one rotary key a
-position, ``[L, max_batch, cache_len, 512]`` and ``[..., 64]`` where a
-full-width cache would be 20 heads x (256 + 256), beside its routing's
-counters, and with an indexer a third array ``"index"`` ``[..., 128]``.
-The state is ONE resident set of buffers: the two programs that write it,
-the jit-ed step (B new rows, or one recurrent update, a layer) and the
-install that ends a prefill (one slot's share), take it donated and
-update it in place, so neither a turn nor an admission copies it or
-holds a second one (pinned on the compiled programs by
+The install is ONE function for every model (``models/layers.py``:
+every slot-kind leaf of the request over the slot's, whole), told by the
+model's ``SLOT_AXES`` along which axis of each leaf the slots lie.
+
+Donation.  The state is ONE resident set of buffers: the two programs
+that write it, the jit-ed step (B new rows, or one recurrent update, a
+layer) and the install that ends a prefill (one slot's share), take it
+donated and update it in place, so neither a turn nor an admission copies
+it or holds a second one (pinned on the compiled programs by
 tests/test_serving_cache.py and tests/test_jamba.py, and on the chip by
 the benchmark's ``peak_hbm_gb.serve`` and op breakdown).  The per-slot
 math is that of the model's single-request path, so a slot's output never
@@ -45,61 +43,44 @@ depends on what its neighbors are decoding (pinned by
 tests/test_serving.py and tests/test_jamba.py oracles).
 
 The parameters an engine holds are the model's ``serving_params``, made
-once when it is built: every leaf that the model's forward casts to the
-compute type at its use, in that type (models/transformer.py's
-``serving_params``; the norm gains, used in float32, stay float32).  The
-cast at the use is then a no-op and every matmul takes the rounding of
-its weight that it took before: the programs' results are equal to the
-bit (pinned on the CPU by tests/test_serving_cache.py; the chip's
-compiler fuses the two programs differently and they agree as two
-fusings of one bfloat16 program do).  Given float32 weights and left to
-cast at the use, the decode step's layer scan has the casts of ALL
-layers' weights hoisted out of the loop by XLA and run whole on every
-turn, and every prefill once more: for the benchmark's 8-layer OLMo-1B
-2.56 GB read and 1.28 GB written before the first matmul, a step of
-12.5 ms on a TPU v5e where it then took 6.7.  A leaf already in its
-use's type is held as the same buffer (models/jamba.py, whose weights
-come in ``param_dtype``, holds what it is given), a sharded leaf keeps
-its sharding, and the engine keeps no reference to a leaf it replaced:
-the float32 original lives as long as the caller's own reference
-(``ServingLoop.params``, for the engine of a re-formed gang).
-``hvd_serve_param_bytes{dtype}`` says what is held.
-
-The form of a leaf is the model's to choose as well as its type.  The
-dense decoder holds its attention's three input projections ``wq``,
-``wk``, ``wv`` ``[L, D, H, HD]`` as ONE leaf ``wqkv`` ``[L, D, 3 H HD]``
-(joined once, beside the cast; the same bytes), and its attention makes
-one product where it finds that key and three where it finds the three
-(training and ``generate`` on ``init``'s parameters).  The reason is the
-chip's compiler: ``"bsd,dhk->bshk"`` lowers as a convolution with a
-window over the heads, into which XLA does not fold the layer scan's
-slice of the stack, so each layer's 8.4 MB ``wq``, ``wk`` and ``wv`` were
-staged in fast memory by an op of their own before their products (three
-``constant_dynamic-slice_fusion`` of ``bf16[1,2048,16,128]``, 12.5 us
-each: 0.30 ms of a 2.1 ms step in the benchmark's OLMo-1B cell); three
-2-D leaves ``[L, D, H HD]`` compile to the same (the reshape folds back
-into the product), a 5-D ``[L, D, 3, H, HD]`` too; the plain product
-``"bsd,df->bsf"`` takes the whole stack and the layer's index, as
-``wo``'s and the feed-forward's do (pinned on the step and the prefill
-compiled for the chip by tests/test_chip_smoke.py).  Where the given
-``wq`` lies split over its heads (``tp`` > 1) joined columns would not
-split by heads and the three are held as given.
+once when it is built, or the given ones where the module has none.  The
+dense decoder's: every leaf that its forward casts to the compute type at
+its use, in that type (the norm gains, used in float32, stay float32), so
+that the cast at the use is a no-op and every matmul takes the rounding
+of its weight that it took before: the programs' results are equal to the
+bit (pinned on the CPU by tests/test_serving_cache.py).  Given float32
+weights and left to cast at the use, the step's layer scan has the casts
+of ALL layers' weights hoisted out of the loop by XLA and run whole on
+every turn.  And why ``wqkv``: its attention's three input projections
+``[L, D, H, HD]`` are held as ONE leaf ``[L, D, 3 H HD]`` (the same
+bytes), because ``"bsd,dhk->bshk"`` lowers on the chip as a convolution
+with a window over the heads, into which XLA does not fold the layer
+scan's slice of the stack: each layer's three weights were staged in fast
+memory by an op of their own, while the plain product ``"bsd,df->bsf"``
+reads its layer out of the stack in place (pinned on the step and the
+prefill compiled for the chip by tests/test_chip_smoke.py; PERF.md
+sections 5 and 6 hold the readings).  Where the given ``wq`` lies split
+over its heads (``tp`` > 1) joined columns would not split by heads and
+the three are held as given.  A leaf already in its use's type is held as
+the same buffer, a sharded leaf keeps its sharding, and the engine keeps
+no reference to a leaf it replaced.  ``hvd_serve_param_bytes{dtype}``
+says what is held.
 
 Under a mesh the state shards by the model's ``STATE_SPEC`` (the dense
 decoder's: KV_CACHE_SPEC, heads over ``tp``), applied with ``filter_spec``
-so a spec axis missing from the mesh degrades to replication; a model
-whose spec is None refuses a mesh.
+so a spec axis missing from the mesh degrades to replication; a module
+that has none refuses a mesh.
 
-A step has two halves.  ``dispatch()`` queues the jit-ed step and the
-three lazy ops after it (the greedy ``argmax``, the token and the position
-advance) and waits for nothing: the next step's inputs are device arrays.
-``read()`` brings the oldest unread token vector to the host, and waits
-for that step only.  ``ServingLoop`` dispatches step k before it reads
-step k-1, so the chip is never idle for the host's part of a turn;
-``step()`` is the two in order.  A slot cleared between a step's dispatch
-and its read has had one more row computed: the row stays in bounds (the
-position clamp), reaches no other row, and the next install overwrites
-what it wrote.
+Dispatch and read.  A step has two halves.  ``dispatch()`` queues the
+jit-ed step and the three lazy ops after it (the greedy ``argmax``, the
+token and the position advance) and waits for nothing: the next step's
+inputs are device arrays.  ``read()`` brings the oldest unread token
+vector to the host, and waits for that step only.  ``ServingLoop``
+dispatches step k before it reads step k-1, so the chip is never idle for
+the host's part of a turn; ``step()`` is the two in order.  A slot cleared
+between a step's dispatch and its read has had one more row computed: the
+row stays in bounds (the position clamp), reaches no other row, and the
+next install overwrites what it wrote.
 
 Prefill compiles once per distinct prompt length (the serving analogue
 of generate()'s per-shape compile); the install takes the slot as a
@@ -129,6 +110,7 @@ from horovod_tpu.models import jamba as J
 from horovod_tpu.models import latent_moe as X
 from horovod_tpu.models import retention as R
 from horovod_tpu.models import transformer as T
+from horovod_tpu.models.layers import install_request
 from horovod_tpu.telemetry import registry as _tmx
 
 STATE_KINDS = ("kv", "recurrent", "index")
@@ -176,12 +158,16 @@ class SlotModel(NamedTuple):
     held: Callable
 
 
-# Config type -> the module that serves it.  A module's side of the seam is
-# ``init_state(cfg, max_batch, cache_len)``, ``prefill_request(params,
-# prompt, cfg, cache_len)``, ``install_request(state, slot, request)``,
-# ``decode_step(params, tok, pos, state, cfg)``, ``STATE_SPEC`` and
-# ``serving_params(params, cfg)``: what a slot's state is and how it is
-# installed is the model's to say, and a further model is a line here.
+# Config type -> the module that serves it; a further model is its module
+# and a line here.  A module MUST present ``init_state(cfg, max_batch,
+# cache_len)`` with ``SLOT_AXES`` beside it (for each slot kind of the
+# state, the axis of each leaf that the slots lie along),
+# ``prefill_request(params, prompt, cfg, cache_len)`` and
+# ``decode_step(params, tok, pos, state, cfg)``.  It MAY omit
+# ``STATE_SPEC`` (the state's sharding: without one a mesh is refused, and
+# with one ``decode_step`` takes ``mesh=``) and ``serving_params(params,
+# cfg)`` (the form the engine holds the parameters in: without it, as
+# given).  Its device counters are declared in ``telemetry/registry.py``.
 MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X,
           R.RetentionConfig: R}
 
@@ -193,12 +179,15 @@ def slot_model(cfg, cache_len: int, mesh=None) -> SlotModel:
     module = MODELS.get(type(cfg))
     if module is None:
         raise TypeError(f"no serving path for a {type(cfg).__name__}")
-    under = {} if mesh is None or module.STATE_SPEC is None else {"mesh": mesh}
+    spec = getattr(module, "STATE_SPEC", None)
+    held = getattr(module, "serving_params", None)
+    under = {} if mesh is None or spec is None else {"mesh": mesh}
     return SlotModel(
         partial(module.init_state, cfg, cache_len=cache_len),
         partial(module.prefill_request, cfg=cfg, cache_len=cache_len),
-        module.install_request, partial(module.decode_step, cfg=cfg, **under),
-        module.STATE_SPEC, partial(module.serving_params, cfg=cfg))
+        partial(install_request, axes=module.SLOT_AXES),
+        partial(module.decode_step, cfg=cfg, **under), spec,
+        (lambda params: params) if held is None else partial(held, cfg=cfg))
 
 
 def install(model: SlotModel, state, tok, pos, slot, logits, request,

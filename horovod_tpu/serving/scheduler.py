@@ -309,25 +309,10 @@ class Scheduler:
                 out["chip_wait_share"] = round(hists.get(
                     "hvd_serve_read_wait_seconds",
                     {}).get("sum", 0.0) / turn["sum"], 4)
-            held = counters.get("hvd_serve_attn_positions_held_total")
-            if held:
-                # Share of the slots' lanes the decode steps' attention
-                # read: 1.0 is the whole cache every step (a full table
-                # of full lanes, or the masked read under tp / sp).
-                out["attn_read_share"] = round(counters.get(
-                    "hvd_serve_attn_positions_read_total", 0.0) / held, 4)
-            scored = counters.get("hvd_serve_index_positions_scored_total")
-            if scored:
-                # Share of the positions the live slots have written that
-                # the attention saw after the indexer's selection
-                # (models/latent_moe.py with index_topk).
-                out["attn_selected_share"] = round(counters.get(
-                    "hvd_serve_attn_positions_selected_total",
-                    0.0) / scored, 4)
-            held = counters.get("hvd_serve_state_rows_held_total")
-            if held:
-                # Share of the slots whose recurrent state the decode
-                # steps stepped that held a request (models/retention.py).
-                out["state_live_share"] = round(counters.get(
-                    "hvd_serve_state_rows_live_total", 0.0) / held, 4)
+            # The shares of two device counters a model's step sums,
+            # as the registry declares them beside the counters.
+            for key, (part, whole) in _tmx.stats_shares().items():
+                if counters.get(whole):
+                    out[key] = round(
+                        counters.get(part, 0.0) / counters[whole], 4)
         return out

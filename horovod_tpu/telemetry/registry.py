@@ -39,8 +39,12 @@ def log2_buckets(lo: float, n: int) -> Tuple[float, ...]:
     return tuple(lo * (2.0 ** i) for i in range(n))
 
 
-def _counter(help_: str, labels: Tuple[str, ...] = ()) -> dict:
-    return {"kind": "counter", "help": help_, "labels": labels}
+def _counter(help_: str, labels: Tuple[str, ...] = (),
+             share: Optional[Tuple[str, str]] = None) -> dict:
+    """``share`` = (key, whole): this counter over the counter ``whole``
+    is ``key`` on ``GET /stats`` (:func:`stats_shares`)."""
+    spec = {"kind": "counter", "help": help_, "labels": labels}
+    return spec if share is None else {**spec, "share": share}
 
 
 def _gauge(help_: str, labels: Tuple[str, ...] = ()) -> dict:
@@ -145,7 +149,8 @@ KNOWN_METRICS: Dict[str, dict] = {
     "hvd_ctrl_cycle_seconds": _hist(
         "Wall time of one root coordination cycle, labeled by gang "
         "size — the coordination-cycle-latency-vs-ranks curve the "
-        "control-plane scale simulation (bench.py) exports.", *_SECONDS,
+        "control-plane scale simulation (horovod_tpu/ctrl_sim.py) "
+        "exports.", *_SECONDS,
         labels=("ranks",)),
     "hvd_subcoord_reparents_total": _counter(
         "Children of a dead per-host sub-coordinator re-attached "
@@ -230,12 +235,14 @@ KNOWN_METRICS: Dict[str, dict] = {
         "is read as far as the slot has written it "
         "(ops/pallas_decode_attention.py).  Summed on the device from the "
         "positions, like the hvd_moe_* counters, and read beside an "
-        "admission's own read, never on a turn."),
+        "admission's own read, never on a turn.",
+        share=("attn_read_share", "hvd_serve_attn_positions_held_total")),
     "hvd_serve_attn_positions_held_total": _counter(
         "Positions the slots' lanes hold (max_batch x cache_len), summed "
         "over layers and steps: what a masked read of the whole cache "
         "reads.  hvd_serve_attn_positions_read_total over this is "
-        "attn_read_share on GET /stats."),
+        "attn_read_share on GET /stats: 1.0 is the whole cache every step "
+        "(a full table of full lanes, or the masked read under tp / sp)."),
     "hvd_serve_index_positions_scored_total": _counter(
         "Positions a learned indexer scored (models/latent_moe.py with "
         "index_topk: what the live slots have written, position + 1 a "
@@ -245,13 +252,17 @@ KNOWN_METRICS: Dict[str, dict] = {
         "Positions the decode steps' attention saw after the indexer's "
         "selection (min(index_topk, position + 1) a live slot), summed "
         "the same way.  Over hvd_serve_index_positions_scored_total it is "
-        "attn_selected_share on GET /stats."),
+        "attn_selected_share on GET /stats: the share of the positions the "
+        "live slots have written that the attention saw.",
+        share=("attn_selected_share",
+               "hvd_serve_index_positions_scored_total")),
     "hvd_serve_state_rows_live_total": _counter(
         "Slots with a request in them (position > 0) whose recurrent state "
         "a decode step read and wrote, summed over layers and steps "
         "(models/retention.py).  Summed on the device from the positions, "
         "like the hvd_moe_* counters, and read beside an admission's own "
-        "read, never on a turn."),
+        "read, never on a turn.",
+        share=("state_live_share", "hvd_serve_state_rows_held_total")),
     "hvd_serve_state_rows_held_total": _counter(
         "Slots whose recurrent state a decode step read and wrote "
         "(max_batch: a free slot's is stepped too), summed over layers and "
@@ -423,14 +434,13 @@ class Registry:
         return "\n".join(lines) + "\n"
 
 
-# -- quantile math (shared by aggregate.py, serving /stats, bench.py) ----
+# -- quantile math (shared by aggregate.py and serving /stats) ----------
 
 
 def quantile(samples, q: float) -> float:
     """The ``q``-quantile (``0 <= q <= 1``) of raw samples with linear
     interpolation between order statistics — numerically identical to
-    ``np.percentile(samples, 100 * q)`` so bench.py's gated numbers do
-    not move when it switches over.  Empty input -> 0.0."""
+    ``np.percentile(samples, 100 * q)``.  Empty input -> 0.0."""
     xs = sorted(float(x) for x in samples)
     if not xs:
         return 0.0
@@ -546,3 +556,12 @@ def render_prometheus() -> str:
 def known_metrics() -> Dict[str, dict]:
     """Registry accessor for tools/check_metric_docs.py."""
     return dict(KNOWN_METRICS)
+
+
+def stats_shares() -> Dict[str, Tuple[str, str]]:
+    """The shares of two device counters that ``GET /stats`` prints, as
+    their counters declare them: key -> (part, whole).  The scheduler
+    prints ``part / whole`` under ``key`` where ``whole`` has moved; a
+    model that counts something new declares it here and nowhere else."""
+    return {spec["share"][0]: (name, spec["share"][1])
+            for name, spec in KNOWN_METRICS.items() if "share" in spec}

@@ -21,10 +21,9 @@ Subcommands:
   runs (directories of rank files, or two ``analyze --json`` outputs)
   to specific phases: prints the top phase deltas.
 
-Importable: bench.py uses :func:`analyze_dir` to embed a
-``phase_breakdown`` block into its snapshots, and
-tools/check_bench_regression.py uses :func:`top_deltas` to name the
-phase that moved when its throughput gate trips.
+Importable: :func:`analyze_dir` gives a traced run's ``phase_breakdown``
+block and :func:`top_deltas` names the phases that moved between two of
+them (what ``diff`` prints).
 """
 
 from __future__ import annotations
@@ -179,7 +178,7 @@ def analyze(files: List[dict]) -> dict:
     — the data-plane step the fused op could not finish before; hop
     spans are refined to hop.recv / hop.reduce / hop.send_wait by their
     largest sub-timing.  ``phase_breakdown_ms`` is mean milliseconds
-    per collective per rank, the block bench.py embeds in snapshots."""
+    per collective per rank."""
     offsets = rank_offsets(files)
     groups: Dict[int, list] = {}
     names: Dict[int, dict] = {}
@@ -248,7 +247,7 @@ def analyze(files: List[dict]) -> dict:
 
 def analyze_dir(d: str) -> Optional[dict]:
     """:func:`analyze` over every rank file in a trace dir (None when
-    the dir holds no trace files) — the bench.py entry point."""
+    the dir holds no trace files)."""
     paths = trace_files(d)
     if not paths:
         return None
@@ -274,7 +273,7 @@ def top_deltas(old: Dict[str, float], new: Dict[str, float],
 
 def _load_breakdown(path: str) -> Dict[str, float]:
     """A diff operand: a trace dir, a rank file, or an ``analyze
-    --json`` / bench-snapshot JSON carrying ``phase_breakdown_ms``."""
+    --json`` output carrying ``phase_breakdown_ms``."""
     if os.path.isdir(path):
         rep = analyze_dir(path)
         if rep is None:

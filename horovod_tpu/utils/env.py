@@ -255,9 +255,8 @@ def shm_sleep_us() -> int:
     """Escalating-microsleep ceiling for shm waits, in microseconds
     (floor 10).  Default 200 us: long enough to stop a yield storm from
     starving the producer, short enough that a ring hop's wake-up
-    latency stays well under the kernel's socket wake path (the old
-    single-core 1 ms ceiling is what lost BENCH_r08's shm-vs-TCP
-    shoot-out)."""
+    latency stays well under the kernel's socket wake path (under the
+    old single-core 1 ms ceiling shm lost to TCP on one host)."""
     return max(10, get_int(SHM_SLEEP_US, 200))
 
 

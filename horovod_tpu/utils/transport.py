@@ -195,7 +195,7 @@ _SHM_PREFIX = "hvd-shm-"
 # core.  The escalating microsleep is capped at HVD_SHM_SLEEP_US on
 # every host: the old single-core 1 ms ceiling meant ~0.5 ms average
 # wake-up latency per slot while the TCP path got kernel-event wakeups,
-# which is how shm lost its own shoot-out in BENCH_r08.  On one core the
+# which is how shm once lost to TCP on one host.  On one core the
 # yield phase is what hands the quantum to the producer; the sleep only
 # exists so a yield storm cannot starve it.
 _CPUS = os.cpu_count() or 1

@@ -417,6 +417,7 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
 
     from horovod_tpu.models import jamba, latent_moe, retention
     from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving.decode import slot_model
 
     rc, out, err = probes.result("lower_for_tpu")
     assert rc == 0, err[-3000:]
@@ -434,7 +435,7 @@ def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):
                             chip_probes.RETENTION_CAST_LEAVES)}[probe]
     cfg = config(**{k: v for k, v in sizes.items() if k != "slots"})
     given = jax.eval_shape(lambda k: model.init(k, cfg), jax.random.PRNGKey(0))
-    held = jax.eval_shape(lambda p: model.serving_params(p, cfg), given)
+    held = jax.eval_shape(slot_model(cfg, cfg.max_seq_len).held, given)
     weights = (chip_probes.weight_dims(given, cast)
                | chip_probes.weight_dims(held, cast))
     assert converts, "the step rounds its activations at least"

@@ -5,7 +5,7 @@ quorum"):
 * tree planning units — per-host sub-coordinators from the block
   topology, fan-out caps, the single-host byte-identical-to-seed pin,
   and the HVD_CTRL_TREE kill-switch;
-* the ctrl_sim scale harness (the 256-rank proof bench.py snapshots);
+* the ctrl_sim scale harness (the 256-rank proof);
 * sub-coordinator SIGKILL on a 3-host/9-rank gang — children re-parent
   to the root, only the dead rank is evicted, SUBCOORD_REPARENT lands
   on the timeline and in the blackbox ring;
@@ -156,9 +156,8 @@ def test_ctrl_sim_curve_exports_headline_and_observes_metric():
 @pytest.mark.slow
 def test_ctrl_sim_256_rank_tree_beats_star():
     """The acceptance proof at full scale: 256 in-process ranks, the
-    hierarchical tree's p50 under the flat star's.  bench.py snapshots
-    the same comparison into BENCH_r*.json; this keeps it reproducible
-    as a test.  (Median of three runs per mode to shrug off scheduler
+    hierarchical tree's p50 under the flat star's, reproducible as a
+    test.  (Median of three runs per mode to shrug off scheduler
     noise on shared CI hosts.)"""
     import statistics
 

@@ -157,6 +157,41 @@ def test_kv_list_prefix(monkeypatch):
         server.stop()
 
 
+@pytest.mark.parametrize("pending, max_np, seen", [
+    (["uid-joiner"], 3, True),      # announced while the gang was starting
+    ([], 3, False),                 # an update with nobody waiting: handled
+    (["uid-joiner"], 2, False),     # no room: deferred, not re-armed
+    (["uid-1"], 3, False),          # a member's stale key is not a joiner
+], ids=["joiner_waiting", "nobody_waiting", "no_room", "member_key"])
+def test_consume_updates_leaves_a_waiting_joiner_to_the_next_commit(
+        monkeypatch, pending, max_np, seen):
+    """A joiner puts its pending key, then bumps the update count.  A
+    rank that is still starting (or re-forming) when that happens takes
+    the count as handled; it must not take the joiner's bump with it, or
+    nobody ever admits the joiner (the hang of
+    test_elastic_joiner_grows_gang on a loaded machine)."""
+    monkeypatch.delenv("HVD_SECRET_KEY", raising=False)
+    from horovod_tpu.elastic.run import _ElasticContext
+
+    server = RendezvousServer("127.0.0.1")
+    monkeypatch.setenv("HVD_RENDEZVOUS_ADDR", "127.0.0.1")
+    monkeypatch.setenv("HVD_RENDEZVOUS_PORT", str(server.start()))
+    monkeypatch.setenv("HVD_ELASTIC_UID", "uid-0")
+    monkeypatch.setenv("HVD_ELASTIC_MAX_NP", str(max_np))
+    try:
+        ctx = _ElasticContext()
+        ctx.roster = ["uid-0", "uid-1"]
+        for uid in pending:
+            ctx.kv.put(ctx.key(f"elastic/pending/{uid}"), "1")
+        ctx.publish_update()
+        ctx.consume_updates()
+        assert ctx.has_pending_update() is seen
+        ctx.publish_update()        # a later update is seen either way
+        assert ctx.has_pending_update()
+    finally:
+        server.stop()
+
+
 # ---------------------------------------------------------------------------
 # multi-process elastic scenarios
 # ---------------------------------------------------------------------------

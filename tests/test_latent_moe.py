@@ -36,7 +36,8 @@ import pytest
 from chip_probes import serve_cache_programs
 from horovod_tpu.models import experts, latent_moe
 from horovod_tpu.ops import pallas_decode_attention as pda
-from horovod_tpu.serving import DecodeEngine, LatentMoEConfig, ServingLoop
+from horovod_tpu.serving import (DecodeEngine, LatentMoEConfig, ServingLoop,
+                                 decode)
 from horovod_tpu.telemetry import registry as tmx
 from perfbench.reference import moe_lm as ref
 from test_pallas_decode_attention import masked_read, uneven_steps
@@ -150,7 +151,7 @@ def test_prefill_then_absorbed_decode_equals_forward(params, prompt_len):
     c, k_r = request["kv"]
     assert c.shape == (3, 1, CACHE_LEN, 8) and k_r.shape == (3, 1, CACHE_LEN, 4)
     assert float(jnp.abs(c[:, :, prompt_len:]).max()) == 0.0
-    state = latent_moe.install_request(
+    state = decode.slot_model(CFG, CACHE_LEN).install(
         latent_moe.init_state(CFG, 3, CACHE_LEN), 1, request)
     for t in range(prompt_len, len(seq)):
         logits, state = STEP(params, jnp.asarray([0, seq[t], 0], jnp.int32),
@@ -476,7 +477,7 @@ def test_step_with_the_kernel_equals_the_masked_read_of_the_whole_lane(
         return uneven_steps(
             jax.jit(lambda p: latent_moe.prefill_request(params, p, PIN,
                                                          S_PIN)),
-            latent_moe.install_request,
+            decode.slot_model(PIN, S_PIN).install,
             jax.jit(lambda tok, pos, state: latent_moe.decode_step(
                 params, tok, pos, state, PIN)),
             latent_moe.init_state(PIN, B_PIN, S_PIN), lengths,

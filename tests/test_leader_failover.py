@@ -96,6 +96,14 @@ def test_rank0_sigkill_mid_serving_promotes_survivor(tmp_path):
                 env["SERVE_PORT_FILE"] = port_files[rank]
             if rank == 0:
                 env["SERVE_EXPECT"] = "0"   # dies before stopping
+                # The victim's turns take 0.1 s each, so "mid-decode" is
+                # a window of seconds: at full speed the 24 tokens are
+                # out ~0.1 s after the fourth admission, and on a loaded
+                # machine a request could complete between the poll that
+                # saw four slots busy and the SIGKILL (attempts == 1).
+                env[fi.ENV_VAR] = json.dumps({"faults": [
+                    {"site": "serve.step", "kind": "delay",
+                     "delay_s": 0.1}]})
             else:
                 env["SERVE_EXPECT"] = str(len(reqs))
             if rank == 1:
@@ -137,6 +145,11 @@ def test_rank0_sigkill_mid_serving_promotes_survivor(tmp_path):
         else:
             raise AssertionError("four slots never filled")
 
+        # A few of the victim's 0.1 s turns on: the frame that admitted
+        # the fourth request has reached the followers' shadows (the
+        # scheduler shows a slot busy before that frame is sent), and
+        # twenty turns of every request are still to come.
+        time.sleep(0.3)
         procs[0].kill()  # SIGKILL, mid-decode
 
         # Phase 2: the clients re-POST the same ids to rank 1's door.

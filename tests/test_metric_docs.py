@@ -46,6 +46,12 @@ def test_every_registered_metric_is_documented():
         "docs/metrics.md")
 
 
+def test_every_declared_share_is_of_two_counters_and_documented(tmp_path):
+    assert check_metric_docs.bad_shares() == []
+    assert check_metric_docs.bad_shares(tmp_path / "n.md") == [
+        "attn_read_share", "attn_selected_share", "state_live_share"]
+
+
 def test_every_alert_rule_is_documented():
     rules = check_metric_docs.alert_rules()
     assert "throughput_collapse" in rules

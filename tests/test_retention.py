@@ -33,7 +33,7 @@ import numpy as np
 import pytest
 
 from chip_probes import serve_cache_programs
-from horovod_tpu.models import jamba, latent_moe, retention
+from horovod_tpu.models import jamba, latent_moe, layers, retention
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.models.retention import RetentionConfig
 from horovod_tpu.ops.pallas_retention import block_for, retention_step
@@ -106,10 +106,10 @@ def test_the_published_state_is_a_matrix_a_layer_and_no_lane():
 
 
 def test_the_module_shares_the_rotation_the_norm_and_the_feed_forward():
-    for name in ("_rope", "_rmsnorm", "_dense_ffn", "vocab_projection"):
-        assert getattr(retention, name) is getattr(tfm, name)
-        assert getattr(retention, name) is getattr(latent_moe, name)
-    assert retention._at is jamba._at
+    for name in ("_rope", "_rmsnorm", "_dense_ffn", "_logits", "_at"):
+        assert getattr(retention, name) is getattr(layers, name)
+        assert getattr(latent_moe, name) is getattr(layers, name)
+    assert tfm._rope is layers._rope and jamba._at is layers._at
 
 
 # -- (b) the kernel -----------------------------------------------------------

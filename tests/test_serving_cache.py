@@ -45,9 +45,23 @@ And the names of the three programs an engine compiles (``serve_step``,
 ``serve_prefill_s<N>``, ``serve_install``: what a profile's ``XLA Modules``
 line shows), with the programs otherwise what the unnamed functions lower
 to.
+
+And the seam itself, over one small config of each of the five served
+forms (``FORMS``): the lowered text of four forms' step and prefill as
+sha256 digests taken before the install, the counter merge and the shared
+layer code moved to ``models/layers.py``; the ONE install's contract (the
+slot whole, every other slot and the counters untouched, nothing left of
+the last tenant); the device counters' names against the registry and its
+declared ``/stats`` shares; that no model file imports another and the
+server names no counter; what ``slot_model`` defaults for a module that
+omits ``STATE_SPEC`` and ``serving_params``; and ``stats()`` printing each
+declared share.
 """
 
+import dataclasses
+import hashlib
 import inspect
+import types
 from functools import partial
 
 import jax
@@ -57,7 +71,7 @@ import pytest
 
 from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
                          dims_key, serve_cache_programs, weight_dims)
-from horovod_tpu.models import jamba
+from horovod_tpu.models import jamba, latent_moe, layers, retention
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import pallas_decode_attention as pda
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
@@ -147,7 +161,7 @@ def test_step_with_the_kernel_equals_the_masked_read_of_the_whole_lane(
     def logits():
         return uneven_steps(
             jax.jit(lambda p: tfm.prefill_request(params, p, cfg, S)),
-            tfm.install_request,
+            decode.slot_model(cfg, S).install,
             jax.jit(lambda tok, pos, state: tfm.decode_step(
                 params, tok, pos, state, cfg)),
             tfm.init_state(cfg, B, S), lengths, V)[0]
@@ -484,9 +498,8 @@ def test_init_parameters_lower_to_three_products_and_no_joined_one(program):
 
 SEAM = {"init_state": ["cfg", "max_batch", "cache_len"],
         "prefill_request": ["params", "prompt", "cfg", "cache_len"],
-        "install_request": ["state", "slot", "request"],
-        "decode_step": ["params", "tok", "pos", "state", "cfg"],
-        "serving_params": ["params", "cfg"]}
+        "decode_step": ["params", "tok", "pos", "state", "cfg"]}
+MAY_OMIT = {"serving_params": ["params", "cfg"]}
 
 
 @pytest.mark.parametrize("make", [_dense_bf16, _jamba_bf16],
@@ -494,7 +507,9 @@ SEAM = {"init_state": ["cfg", "max_batch", "cache_len"],
 def test_model_module_presents_the_seam_the_one_builder_takes(make):
     cfg, given, _ = make()
     module = decode.MODELS[type(cfg)]
-    for name, takes in SEAM.items():
+    for name, takes in {**SEAM, **MAY_OMIT}.items():
+        if name in MAY_OMIT and not hasattr(module, name):
+            continue
         has = inspect.signature(getattr(module, name)).parameters
         assert list(has)[:len(takes)] == takes, name
         # what a module takes besides (the dense decoder's step: the
@@ -504,11 +519,16 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
     model = decode.slot_model(cfg, S)
     for part, name in [(model.init_state, "init_state"),
                        (model.prefill, "prefill_request"),
-                       (model.step, "decode_step"),
-                       (model.held, "serving_params")]:
+                       (model.step, "decode_step")]:
         assert part.func is getattr(module, name), name
-    assert model.install is module.install_request
-    assert model.spec is module.STATE_SPEC
+    # the install is the shared one, told where the module's slots lie
+    assert model.install.func is layers.install_request
+    assert model.install.keywords == {"axes": module.SLOT_AXES}
+    assert model.spec is getattr(module, "STATE_SPEC", None)
+    if hasattr(module, "serving_params"):
+        assert model.held.func is module.serving_params
+    else:
+        assert model.held(given) is given
     # and the parts fit: a request's state installs into a batch's, which
     # a step takes and gives back in the shapes it came in
     params = model.held(given)
@@ -527,3 +547,265 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
     assert logits.shape == (2, V)
     assert (jax.tree.map(lambda a: (a.shape, a.dtype), after)
             == jax.tree.map(lambda a: (a.shape, a.dtype), state))
+
+
+# -- the seam over the five served forms ----------------------------------------
+#
+# One small config a served form, the fixtures' widths of tests/test_jamba.py,
+# test_latent_moe.py, test_sparse_latent_moe.py and test_retention.py in the
+# published types: each takes every branch of its form (attention and Mamba
+# runs; dense and expert layers; the indexer, a share of the experts, grouped
+# routing and YaRN; the retention's prompt blocks).
+
+YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
+        "original_max_position_embeddings": 16, "mscale": 1.0,
+        "mscale_all_dim": 1.0}
+LATENT = dict(vocab_size=96, hidden_size=32, intermediate_size=64,
+              moe_intermediate_size=16, num_hidden_layers=3,
+              first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=12,
+              kv_lora_rank=8, qk_nope_head_dim=6, qk_rope_head_dim=4,
+              v_head_dim=8, n_shared_experts=1, max_seq_len=64, attn_block=8)
+FORMS = {
+    "olmo-1b": tfm.TransformerConfig(
+        vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=64,
+        max_seq_len=64),
+    "jamba2-3b": jamba.JambaConfig(
+        vocab_size=96, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=1,
+        attn_layer_period=4, attn_layer_offset=1, mamba_d_state=4,
+        mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=6, max_seq_len=64,
+        scan_chunk=4),
+    "glm-4.7-flash": latent_moe.LatentMoEConfig(
+        n_routed_experts=8, num_experts_per_tok=2, routed_scaling_factor=1.8,
+        rms_norm_eps=1e-5, rope_theta=1e6, **LATENT),
+    "deepseek-v3.2": latent_moe.LatentMoEConfig(
+        n_routed_experts=16, num_experts_per_tok=3, routed_scaling_factor=2.5,
+        rms_norm_eps=1e-6, rope_theta=10000.0, n_group=4, topk_group=2,
+        index_n_heads=4, index_head_dim=8, index_topk=8, rope_scaling=YARN,
+        experts_held=4, expert_first=4, **LATENT),
+    "brumby-14b": retention.RetentionConfig(
+        vocab_size=96, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, rope_theta=10000.0, max_seq_len=64)}
+SLOTS, CACHE_LEN, PROMPT = 4, 64, 24
+
+# sha256 of the lowered text (StableHLO, no locations; jax 0.9.0) of the
+# engine's step and prefill (PROMPT tokens) for four of FORMS through
+# ``decode.slot_model``, as tests/test_pallas_attention.py pins the dense
+# decoder's four, taken on the commit before the install, the counter merge,
+# ``_logits`` and the shared layer code moved to models/layers.py
+# (``retention.PROMPT_BLOCK`` 8).  A change that alters what a served form
+# computes, or the order it computes it in, lands here: change a digest only
+# with that form's cells' numbers in hand.
+LOWERED_BEFORE = {
+    "jamba2-3b:serve_step":
+        "37725ddcebea758b7805f597b1f410711e05bd7c0766498f0a056910327f6cee",
+    "jamba2-3b:serve_prefill":
+        "9864fa04f07c36dbfae741723850d462e76e301c1abd5422a8d0617a7aaba7ca",
+    "glm-4.7-flash:serve_step":
+        "baa03dc05cd0a0fb075080aacf98b0a005a34c46e1410cf0264f7639371529d0",
+    "glm-4.7-flash:serve_prefill":
+        "96cdada0cc3f5fda188834de5142835135349f0cd6079206f6c2814a77cc683c",
+    "deepseek-v3.2:serve_step":
+        "b849e0d8f5a5ea6cd17bd6fa24b037cf596c663a6d295bdbc3913911595b21f4",
+    "deepseek-v3.2:serve_prefill":
+        "6da620e95f68d495fa8f3a76352267d8bb825fc361dd5d29367a260ce5b2b957",
+    "brumby-14b:serve_step":
+        "045e7d287c5889bb2ef8b6e04e8d45e04fbb2a6d108a3e51d6f8bd7a90c917c5",
+    "brumby-14b:serve_prefill":
+        "2fdbfa717b5e079a1127aa9028da2babdb410e8a33da44054df4847d285524e1"}
+
+
+@pytest.mark.parametrize("which", list(LOWERED_BEFORE))
+def test_served_forms_lower_as_before_the_shared_seam(which, monkeypatch):
+    monkeypatch.setattr(retention, "PROMPT_BLOCK", 8)
+    form, program = which.split(":")
+    cfg = FORMS[form]
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def specs(tree):
+        return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
+
+    model = decode.slot_model(cfg, CACHE_LEN)
+    held = specs(jax.eval_shape(
+        lambda k: model.held(decode.MODELS[type(cfg)].init(k, cfg)),
+        jax.random.PRNGKey(0)))
+    if program == decode.STEP_PROGRAM:
+        lowered = jax.jit(decode.named(decode.STEP_PROGRAM, model.step),
+                          donate_argnums=(3,)).lower(
+            held, spec((SLOTS,)), spec((SLOTS,)),
+            specs(jax.eval_shape(lambda: model.init_state(SLOTS))))
+    else:
+        lowered = jax.jit(decode.named(decode.PREFILL_PROGRAM,
+                                       model.prefill)).lower(
+            held, spec((PROMPT,)))
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() \
+        == LOWERED_BEFORE[which]
+
+
+def _slot_leaves(model_axes, state):
+    """[(kind, leaf on the host, the axis its slots lie along)] of a
+    state."""
+    return [(kind, np.asarray(leaf), axis)
+            for kind, axes in model_axes.items() if kind in state
+            for leaf, axis in zip(jax.tree.leaves(state[kind]),
+                                  jax.tree.leaves(axes))]
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_one_install_writes_a_slot_whole_and_nothing_else(form):
+    """After the shared install every slot-kind leaf of the slot is the
+    request's, every other slot's bytes and the counters are as they were,
+    and an install of zeros leaves nothing of the last tenant."""
+    cfg = FORMS[form]
+    module = decode.MODELS[type(cfg)]
+    model = decode.slot_model(cfg, CACHE_LEN)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
+
+    def seeded(tree):
+        return jax.tree.map(lambda a: jax.random.normal(
+            next(keys), a.shape).astype(a.dtype), tree)
+
+    zeros = model.init_state(SLOTS)
+    # every kind the state holds but the counters lies along a declared axis
+    assert set(zeros) - {"counters"} <= set(module.SLOT_AXES)
+    before = seeded({k: v for k, v in zeros.items() if k != "counters"})
+    if "counters" in zeros:
+        before["counters"] = {name: jnp.uint32(7 + i) for i, name
+                              in enumerate(zeros["counters"])}
+    # what the form's prefill hands over, in shape; the values seeded
+    request = seeded(jax.eval_shape(
+        lambda k: model.prefill(model.held(module.init(k, cfg)),
+                                jnp.arange(1, 10))[1], jax.random.PRNGKey(0)))
+    install = jax.jit(model.install)        # the slot traced, as the engine's
+    after = install(before, 2, request)
+    assert jax.tree.structure(after) == jax.tree.structure(before)
+    np.testing.assert_equal(jax.device_get(after.get("counters")),
+                            jax.device_get(before.get("counters")))
+    wrote = 0
+    for (kind, got, axis), (_, was, _), (_, new, _) in zip(
+            _slot_leaves(module.SLOT_AXES, after),
+            _slot_leaves(module.SLOT_AXES, before),
+            _slot_leaves(module.SLOT_AXES, request)):
+        assert new.shape[axis] == 1 and got.shape[axis] == SLOTS, kind
+        np.testing.assert_array_equal(np.take(got, [2], axis), new, kind)
+        np.testing.assert_array_equal(np.delete(got, 2, axis),
+                                      np.delete(was, 2, axis), kind)
+        wrote += 1
+    assert wrote == len(jax.tree.leaves(request)) > 0
+    emptied = install(after, 2, jax.tree.map(jnp.zeros_like, request))
+    for kind, got, axis in _slot_leaves(module.SLOT_AXES, emptied):
+        assert not np.take(got, 2, axis).any(), kind
+
+
+def _device_counters(form):
+    state = jax.eval_shape(
+        lambda: decode.slot_model(FORMS[form], CACHE_LEN).init_state(SLOTS))
+    return set(state.get("counters", {}))
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_a_form_counts_under_registered_names_and_shares_are_whole(form):
+    """Every name a form's state counts under is a counter of the registry,
+    a form that holds one counter of a declared share holds the other, and
+    no share is declared of counters that no form holds."""
+    known = tmx.known_metrics()
+    names = _device_counters(form)
+    assert all(known[name]["kind"] == "counter" for name in names)
+    every = set().union(*(_device_counters(f) for f in FORMS))
+    for key, (part, whole) in tmx.stats_shares().items():
+        assert (part in names) == (whole in names), key
+        assert {part, whole} <= every, key
+
+
+def test_model_files_import_no_other_and_the_server_names_no_counter():
+    """The arrows point one way: ops/ <- layers, experts <- the model files
+    <- serving/decode.py; and what a model counts is named by the model and
+    the registry, never by the scheduler or the engine."""
+    import ast
+    from pathlib import Path
+
+    pkg = Path(decode.__file__).resolve().parent.parent
+    models = ("transformer", "jamba", "latent_moe", "retention", "resnet")
+    for name in models + ("layers", "experts"):
+        tree = ast.parse((pkg / "models" / f"{name}.py").read_text())
+        imported = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and node.module:
+                imported.add(node.module)
+                imported |= {f"{node.module}.{a.name}" for a in node.names}
+            elif isinstance(node, ast.Import):
+                imported |= {a.name for a in node.names}
+        others = {f"horovod_tpu.models.{m}" for m in models if m != name}
+        assert not imported & others, (name, imported & others)
+    declared = set().union(*(_device_counters(f) for f in FORMS))
+    declared |= {c for pair in tmx.stats_shares().values() for c in pair}
+    assert declared >= set(layers.ATTN_COUNTERS)
+    for path in ("serving/scheduler.py", "serving/decode.py"):
+        text = (pkg / path).read_text()
+        assert [name for name in sorted(declared) if name in text] == []
+
+
+@dataclasses.dataclass(frozen=True)
+class BareConfig:
+    max_seq_len: int = 8
+
+
+# A module that presents what it must and nothing it may omit: its slots lie
+# along axis 0, its state is one row a slot, and its step adds the token.
+BARE = types.SimpleNamespace(
+    SLOT_AXES={"recurrent": (0,)},
+    init_state=lambda cfg, max_batch, cache_len: {
+        "recurrent": (jnp.zeros((max_batch, 3), jnp.float32),)},
+    prefill_request=lambda params, prompt, cfg, cache_len: (
+        params["w"] * jnp.sum(prompt),
+        {"recurrent": (jnp.sum(prompt)[None, None] * params["w"][None, :3],)}),
+    decode_step=lambda params, tok, pos, state, cfg: (
+        state["recurrent"][0] @ params["w"][:3, None] * params["w"][None],
+        {"recurrent": (state["recurrent"][0] + tok[:, None],)}))
+
+
+@pytest.mark.parametrize("mesh", [False, True],
+                         ids=["held_as_given", "refuses_a_mesh"])
+def test_a_module_that_omits_what_it_may_is_held_as_given_and_unsharded(
+        monkeypatch, mesh):
+    monkeypatch.setitem(decode.MODELS, BareConfig, BARE)
+    params = {"w": jnp.arange(1.0, 6.0)}
+    if mesh:
+        with pytest.raises(NotImplementedError,
+                           match="serving a BareConfig under a mesh: its "
+                                 "state has no sharding spec"):
+            DecodeEngine(params, BareConfig(), max_batch=2, mesh=make_mesh(
+                {"dp": 2}, devices=jax.devices()[:2]))
+        return
+    engine = DecodeEngine(params, BareConfig(), max_batch=2)
+    assert engine.params is params and engine.model.spec is None
+    assert engine.prefill(1, [2, 3]) == 4       # argmax of w * 5
+    np.testing.assert_array_equal(engine.state["recurrent"][0],
+                                  [[0, 0, 0], [5, 10, 15]])
+    engine.step()
+    np.testing.assert_array_equal(engine.state["recurrent"][0],
+                                  [[0, 0, 0], [9, 14, 19]])
+    assert engine.counters() == {}
+
+
+def test_stats_prints_each_declared_share_and_omits_it_at_a_zero_whole():
+    shares = tmx.stats_shares()
+    assert set(shares) >= {"attn_read_share", "attn_selected_share",
+                           "state_live_share"}
+    sched = Scheduler(max_batch=B, max_queue=4, cache_len=S)
+    tmx.configure(True)
+    try:
+        for part, _ in shares.values():     # a part alone makes no share
+            tmx.inc_counter(part, 2)
+        assert not set(shares) & set(sched.stats())
+        for i, (_, whole) in enumerate(shares.values()):
+            tmx.inc_counter(whole, 3 + i)
+        stats = sched.stats()
+        assert ({key: stats[key] for key in shares}
+                == {key: round(2 / (3 + i), 4) for i, key in enumerate(shares)})
+    finally:
+        tmx.configure(False)
+    assert not set(shares) & set(sched.stats())

@@ -134,7 +134,7 @@ def test_prefill_then_sparse_steps_equal_the_full_pass(
     logits, request = PREFILL(params, tokens[:12])
     np.testing.assert_allclose(logits, want[11], rtol=2e-4, atol=2e-6)
     assert set(request) == {"kv", "index"}
-    state = latent_moe.install_request(
+    state = decode.slot_model(CFG, CACHE_LEN).install(
         latent_moe.init_state(CFG, 2, CACHE_LEN), 1, request)
     for t in range(12, 40):
         logits, state = STEP(params, jnp.asarray([0, tokens[t]], jnp.int32),
@@ -358,7 +358,7 @@ def test_step_with_the_kernels_equals_a_masked_read_under_the_selection(
 
     def logits():       # the step traced anew: it reads the attention then
         return uneven_steps(
-            prefill, latent_moe.install_request,
+            prefill, decode.slot_model(PIN, S_PIN).install,
             jax.jit(lambda tok, pos, state: latent_moe.decode_step(
                 params, tok, pos, state, PIN)),
             latent_moe.init_state(PIN, B_PIN, S_PIN), lengths,

@@ -14,7 +14,10 @@ mirroring tools/check_fault_sites.py:
    registry IS the user-facing scrape surface;
 3. the registry may declare metrics with no literal in-package call
    site (names built at runtime would be invisible to the AST scan),
-   but never the reverse.
+   but never the reverse;
+4. every share of two counters that the registry declares for
+   ``GET /stats`` (``stats_shares()``) names two registered counters and
+   appears in docs/metrics.md under its key.
 
 Usage: ``python tools/check_metric_docs.py`` (exit 1 on violations).
 """
@@ -66,13 +69,17 @@ def used_literals(pkg_dir: Path = PKG_DIR) -> dict:
     return out
 
 
-def registry() -> dict:
+def _registry_module():
     sys.path.insert(0, str(REPO_ROOT))
     try:
         from horovod_tpu.telemetry import registry as reg
     finally:
         sys.path.pop(0)
-    return reg.known_metrics()
+    return reg
+
+
+def registry() -> dict:
+    return _registry_module().known_metrics()
 
 
 def undeclared_metrics(pkg_dir: Path = PKG_DIR) -> dict:
@@ -90,6 +97,17 @@ def undocumented_metrics(doc_file: Path = DOC_FILE) -> list:
     # words).
     return [m for m in sorted(registry())
             if not re.search(rf"\b{re.escape(m)}\b", text)]
+
+
+def bad_shares(doc_file: Path = DOC_FILE) -> list:
+    """Declared ``GET /stats`` shares whose key docs/metrics.md does not
+    name, or whose two counters are not both registered counters."""
+    reg = _registry_module()
+    known = reg.known_metrics()
+    text = doc_file.read_text(encoding="utf-8") if doc_file.is_file() else ""
+    return [key for key, pair in sorted(reg.stats_shares().items())
+            if not re.search(rf"\b{re.escape(key)}\b", text)
+            or any(known.get(m, {}).get("kind") != "counter" for m in pair)]
 
 
 def alert_rules() -> tuple:
@@ -129,6 +147,13 @@ def main() -> int:
               "table:", file=sys.stderr)
         for m in undoc:
             print(f"  {m!r}", file=sys.stderr)
+    shares = bad_shares()
+    if shares:
+        bad = True
+        print("GET /stats shares missing from docs/metrics.md, or not of "
+              "two registered counters:", file=sys.stderr)
+        for key in shares:
+            print(f"  {key!r}", file=sys.stderr)
     undoc_rules = undocumented_alert_rules()
     if undoc_rules:
         bad = True

@@ -2,7 +2,7 @@
 """Shim: the implementation moved to horovod_tpu/tools/hvd_trace.py so
 it installs with the package (``hvd-trace`` console script).  Importing
 this module yields the real one — existing ``import hvd_trace`` users
-(bench.py, tests) see the full surface, private names included."""
+(tests) see the full surface, private names included."""
 
 import os
 import sys
