@@ -12,9 +12,11 @@ for a serving turn's few dozen:
    size, a group's score is the sum of its two largest ``s + b``, and
    only the ``topk_group`` best groups' experts stand for the top-k;
 2. the ``rows x k`` (row, expert) pairs are sorted by expert, the rows
-   gathered in that order, and three ``jax.lax.ragged_dot`` products (up,
-   gate, down) run over the groups: an expert with no row costs nothing,
-   one with many rows gets them all.  **No capacity, no dropped row, no
+   gathered in that order, and ``jax.lax.ragged_dot`` products run over
+   the groups, in the expert's own form: with a ``w_gate`` stack three
+   (``w_out (w_in x * silu(w_gate x))``), without one two
+   (``w_out relu(w_in x)^2``).  An expert with no row costs nothing, one
+   with many rows gets them all.  **No capacity, no dropped row, no
    auxiliary loss**, so a row's output depends on that row alone: what a
    served slot returns never depends on its neighbours;
 3. the results go back to their rows with their weights.
@@ -58,6 +60,29 @@ import jax.numpy as jnp
 from jax import lax
 
 
+# What a model's decode step adds to its state's ``"counters"`` from
+# ``routed_ffn``'s stats, over its expert layers: (row, expert) pairs
+# routed, experts with at least one row, the fullest expert's rows, and the
+# expert layers stepped; with a share of the experts, also the live pairs
+# whose expert lies on another chip.
+MOE_COUNTERS = ("hvd_moe_rows_routed_total", "hvd_moe_experts_touched_total",
+                "hvd_moe_max_expert_rows_total", "hvd_moe_layer_turns_total")
+ABSENT_COUNTER = "hvd_moe_rows_absent_total"
+
+
+# The grouped product's tiles: a stack axis that is not whole tiles makes
+# it walk the stack in pieces of 128 or 384 (on the chip, 576 rows against
+# 63 experts, bytes of the touched experts a second: [2688, 1856] 118 GB/s,
+# [3072, 2048] 561; 6144 rows: 179 and 269; PR 46, TPU v5 lite).
+TILE = 512
+
+
+def padded_width(n: int) -> int:
+    """The width a routed expert's stack is held at for a published width
+    ``n``: whole tiles, where ``n`` is more than one."""
+    return -(-n // TILE) * TILE if n > TILE else n
+
+
 def route(x, router, bias, top_k: int, scale: float, n_group: int = 1,
           topk_group: int = 1) -> Tuple[jax.Array, jax.Array]:
     """x: [T, D] -> (chosen [T, k] int32, weights [T, k] float32).
@@ -85,11 +110,13 @@ def route(x, router, bias, top_k: int, scale: float, n_group: int = 1,
 def routed_ffn(x, experts, layer, chosen, weights, dtype,
                live: Optional[jax.Array] = None,
                first: Optional[int] = None):
-    """x: [T, D]; ``experts``: ``w_in``, ``w_gate`` [L, E, D, F] and
-    ``w_out`` [L, E, F, D]; ``layer``: which of the L (may be traced);
-    ``chosen``, ``weights``: [T, k] from :func:`route`; ``live``: [T]
-    bool or None (all); ``first``: None where the stack holds every
-    expert the router chooses among, else the router's output that the
+    """x: [T, D]; ``experts``: ``w_in`` [L, E, D, F], ``w_out`` [L, E, F,
+    D] and, for gated experts, ``w_gate`` [L, E, D, F] (without it an
+    expert is ``w_out relu(w_in x)^2``), ``D`` and ``F`` as published or
+    padded with zeros (:func:`padded_width`); ``layer``: which of the L
+    (may be traced); ``chosen``, ``weights``: [T, k] from :func:`route`;
+    ``live``: [T] bool or None (all); ``first``: None where the stack holds
+    every expert the router chooses among, else the router's output that the
     stack's expert 0 answers to (a share: ``first`` to ``first + E``).
     Returns (y [T, D] in ``dtype``, stats [3])."""
     T, k = chosen.shape
@@ -105,14 +132,26 @@ def routed_ffn(x, experts, layer, chosen, weights, dtype,
     groups = lax.dynamic_update_slice(
         jnp.zeros((L * E,), jnp.int32), counts, (layer * E,))
     xs = x.astype(dtype)[order // k]                       # [T k, D]
+    # A stack may be held with zero rows and columns up to whole tiles of
+    # the grouped product (``padded_width``): the rows take zero columns
+    # to match and lose them again, and a zero column of ``w_in`` is a
+    # zero of ``h`` against a zero row of ``w_out``.
+    D, held = x.shape[1], experts["w_in"].shape[2]
+    if held != D:
+        xs = jnp.pad(xs, ((0, 0), (0, held - D)))
 
     def grouped(rows, w):
         return lax.ragged_dot(
             rows, w.reshape((L * E,) + w.shape[2:]).astype(dtype), groups)
 
-    h = grouped(xs, experts["w_in"]) * jax.nn.silu(
-        grouped(xs, experts["w_gate"]))
+    if "w_gate" in experts:
+        h = grouped(xs, experts["w_in"]) * jax.nn.silu(
+            grouped(xs, experts["w_gate"]))
+    else:
+        h = jnp.square(jax.nn.relu(grouped(xs, experts["w_in"])))
     ys = grouped(h, experts["w_out"])
+    if held != D:
+        ys = ys[:, :D]
     # Back to (row, choice) order.  Pairs behind the last group were in
     # no product: what the rows hold there is not a number to weigh.
     in_a_group = jnp.arange(T * k) < jnp.sum(counts)

@@ -6,8 +6,9 @@ the others are Mamba-1 mixers with Jamba's three inner RMSNorms; every
 layer ends in the dense gated feed-forward (``num_experts`` is 1).  This
 file is the serving path's: ``forward`` (the tests' oracle),
 ``prefill_request`` and ``decode_step``, all three built from ONE
-attention function and ONE mixer function, each taking optional state in
-and giving state out.  Training it is not supported (the chunked scan has
+attention function (``layers._attention_no_positions``) and ONE mixer
+function, each taking optional state in and giving state out.  Training
+it is not supported (the chunked scan has
 no backward pass written for it).
 
 * Attention: ``num_attention_heads`` query heads over
@@ -51,8 +52,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.layers import (_at, _dense_ffn, _logits, _put,
-                                       _rmsnorm)
+from horovod_tpu.models.layers import (_at, _attention_no_positions,
+                                       _causal_conv, _dense_ffn, _logits,
+                                       _put, _rmsnorm)
 
 Params = Dict[str, Any]
 State = Dict[str, Tuple[jax.Array, jax.Array]]
@@ -190,47 +192,8 @@ def init(rng, cfg: JambaConfig) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# the two mixers: each ONE function, state optional
+# the mixer: ONE function, state optional (the attention is layers.py's)
 # ---------------------------------------------------------------------------
-
-
-def _attention(x, lp, cfg: JambaConfig, cache=None):
-    """Grouped-query causal attention without positions.  x: [B, S, D].
-
-    ``cache`` None: the S positions attend among themselves; returns
-    (out, (k, v)) with k, v [B, KVH, S, HD] for whoever keeps them.
-    ``cache`` = (ks, vs, layer, pos), stacked caches [La, B, KVH, Smax,
-    HD] and per-slot positions [B] of THIS token (S = 1): writes the B
-    new rows at [layer, b, :, pos[b]] in place and attends lane
-    ``layer`` up to ``pos``; returns (out, (ks, vs))."""
-    dtype = cfg.compute_dtype
-    B, S, _ = x.shape
-    KVH, HD = cfg.num_key_value_heads, cfg.head_dim
-    G = cfg.num_attention_heads // KVH
-    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
-    k = jnp.einsum("bsd,dhk->bhsk", x, lp["wk"].astype(dtype))
-    v = jnp.einsum("bsd,dhk->bhsk", x, lp["wv"].astype(dtype))
-    q = q.reshape(B, S, KVH, G, HD)
-    if cache is None:
-        keys, values, kept = k, v, (k, v)
-        valid = jnp.tril(jnp.ones((S, S), jnp.bool_))[None]    # [1, S, T]
-    else:
-        ks, vs, layer, pos = cache
-        rows = jnp.arange(B)
-        ks = ks.at[layer, rows, :, pos].set(k[:, :, 0])
-        vs = vs.at[layer, rows, :, pos].set(v[:, :, 0])
-        keys = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
-        values = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
-        kept = (ks, vs)
-        valid = (jnp.arange(keys.shape[2])[None, :]
-                 <= pos[:, None])[:, None]                     # [B, 1, T]
-    logits = jnp.einsum("bskgd,bktd->bkgst", q, keys
-                        ).astype(jnp.float32) / math.sqrt(HD)
-    logits = jnp.where(valid[:, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    ctx = jnp.einsum("bkgst,bktd->bskgd", probs, values)
-    ctx = ctx.reshape(B, S, KVH * G, HD)
-    return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
 
 
 def _selective_scan(h, delta, c, b_in, c_out, a, chunk: int):
@@ -294,10 +257,7 @@ def _mamba_mixer(u, lp, cfg: JambaConfig, state=None):
     ssm, conv = state
     xz = jnp.einsum("bsd,de->sbe", u, lp["in_proj"].astype(dtype))
     x, z = xz[..., :Di], xz[..., Di:]
-    window = jnp.concatenate([conv, x], axis=0)
-    conv_w = lp["conv_w"].astype(f32)
-    c = lp["conv_b"].astype(f32) + sum(
-        conv_w[j] * window[j:j + S].astype(f32) for j in range(K))
+    c, window = _causal_conv(x, conv, lp["conv_w"], lp["conv_b"])
     c = jax.nn.silu(c)
     dbc = jnp.einsum("sbe,er->sbr", c.astype(dtype),
                      lp["x_proj"].astype(dtype), preferred_element_type=f32)
@@ -359,13 +319,13 @@ def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
         lp = _at(params["attn"], l)
         y = _rmsnorm(h, lp["ln1"])
         if start:
-            y, (k, v) = _attention(y, lp, cfg)
+            y, (k, v) = _attention_no_positions(y, lp, dtype)
             if carries:
                 at = (l, 0, 0, 0, 0)
                 kv = (lax.dynamic_update_slice(kv[0], k[None], at),
                       lax.dynamic_update_slice(kv[1], v[None], at))
         else:
-            y, kv = _attention(y, lp, cfg, (*kv, l, pos))
+            y, kv = _attention_no_positions(y, lp, dtype, (*kv, l, pos))
         h = h + y
         return h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype), kv
 

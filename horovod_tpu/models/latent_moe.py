@@ -124,18 +124,14 @@ from horovod_tpu.ops.pallas_index_select import index_select
 Params = Dict[str, Any]
 State = Dict[str, Any]
 
-# What ``decode_step`` adds to ``state["counters"]`` a step, over the
-# expert layers: (row, expert) pairs routed, experts with at least one
-# row, the fullest expert's rows, and the expert layers stepped; and, over
-# all layers, what the attention read of the lanes (ATTN_COUNTERS).
-MOE_COUNTERS = ("hvd_moe_rows_routed_total", "hvd_moe_experts_touched_total",
-                "hvd_moe_max_expert_rows_total", "hvd_moe_layer_turns_total")
+# What ``decode_step`` adds to ``state["counters"]`` a step: the routing's
+# (``experts.MOE_COUNTERS``, and ``experts.ABSENT_COUNTER`` with a share of
+# the experts) and, over all layers, what the attention read of the lanes
+# (ATTN_COUNTERS).
+MOE_COUNTERS, ABSENT_COUNTER = experts.MOE_COUNTERS, experts.ABSENT_COUNTER
 COUNTERS = MOE_COUNTERS + ATTN_COUNTERS
-# With a share of the experts: the live (row, expert) pairs whose expert
-# lies on another chip.  With an indexer, over all layers: the positions
-# its step scored (what the live slots have written) and the positions its
-# attention then saw.
-ABSENT_COUNTER = "hvd_moe_rows_absent_total"
+# With an indexer, over all layers: the positions its step scored (what the
+# live slots have written) and the positions its attention then saw.
 INDEX_COUNTERS = ("hvd_serve_index_positions_scored_total",
                   "hvd_serve_attn_positions_selected_total")
 # (row, expert) pairs of a prompt gathered for the grouped products at

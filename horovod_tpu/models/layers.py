@@ -6,8 +6,8 @@ model: how a request's state is written over a slot, and how a step moves
 the device counters on.
 
 The model files (``transformer``, ``jamba``, ``latent_moe``,
-``retention``, ``resnet``) import this module and ``experts`` and never
-one another; this module imports none of them.
+``retention``, ``ssd_moe``, ``resnet``) import this module and ``experts``
+and never one another; this module imports none of them.
 """
 
 from __future__ import annotations
@@ -63,6 +63,73 @@ def _dense_ffn(x, lp, dtype):
     return jnp.einsum("bsf,fd->bsd", h, lp["w_out"].astype(dtype))
 
 
+def _attention_no_positions(x, lp, dtype, cache=None,
+                            heads_first: bool = True):
+    """Grouped-query causal attention without positions (the state-space
+    layers beside it carry order).  x: [B, S, D]; ``lp``: ``wq`` [D, H,
+    HD], ``wk``, ``wv`` [D, KVH, HD], ``wo`` [H, HD, D].
+
+    ``cache`` None: the S positions attend among themselves; returns
+    (out, (k, v)) with k, v [B, KVH, S, HD] for whoever keeps them.
+    ``cache`` = (ks, vs, layer, pos), stacked caches [La, B, KVH, Smax,
+    HD] and per-slot positions [B] of THIS token (S = 1): writes the B
+    new rows at [layer, b, :, pos[b]] in place and attends lane
+    ``layer`` up to ``pos``; returns (out, (ks, vs)).
+
+    ``heads_first`` False: k, v and the caches hold the positions ahead of
+    the heads, [B, S, KVH, HD] and [La, B, Smax, KVH, HD].  With more than
+    one key/value head the chip writes a step's rows into such a cache in
+    place, and copies a heads-first one whole, there and back, around the
+    scatter (the step compiled for ``v5e`` and traced: 4 x 0.6 ms a turn
+    for two lanes of 201 MB; PR 46)."""
+    B, S, _ = x.shape
+    KVH, HD = lp["wk"].shape[-2:]
+    G = lp["wq"].shape[-2] // KVH
+    kept_as = "bhsk" if heads_first else "bshk"
+    lane = "bktd" if heads_first else "btkd"
+    q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
+    k = jnp.einsum(f"bsd,dhk->{kept_as}", x, lp["wk"].astype(dtype))
+    v = jnp.einsum(f"bsd,dhk->{kept_as}", x, lp["wv"].astype(dtype))
+    q = q.reshape(B, S, KVH, G, HD)
+    if cache is None:
+        keys, values, kept = k, v, (k, v)
+        valid = jnp.tril(jnp.ones((S, S), jnp.bool_))[None]    # [1, S, T]
+    else:
+        ks, vs, layer, pos = cache
+        rows = jnp.arange(B)
+        if heads_first:
+            ks = ks.at[layer, rows, :, pos].set(k[:, :, 0])
+            vs = vs.at[layer, rows, :, pos].set(v[:, :, 0])
+        else:
+            ks = ks.at[layer, rows, pos].set(k[:, 0])
+            vs = vs.at[layer, rows, pos].set(v[:, 0])
+        keys = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
+        values = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
+        kept = (ks, vs)
+        valid = (jnp.arange(keys.shape[2 if heads_first else 1])[None, :]
+                 <= pos[:, None])[:, None]                     # [B, 1, T]
+    logits = jnp.einsum(f"bskgd,{lane}->bkgst", q, keys
+                        ).astype(jnp.float32) / math.sqrt(HD)
+    logits = jnp.where(valid[:, None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    ctx = jnp.einsum(f"bkgst,{lane}->bskgd", probs, values)
+    ctx = ctx.reshape(B, S, KVH * G, HD)
+    return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
+
+
+def _causal_conv(x, kept, w, b):
+    """A depthwise causal convolution over the leading (time) axis.  x:
+    [S, B, C]; ``kept``: [K - 1, B, C], the inputs before x's first (a
+    sequence's start: zeros); ``w`` [K, C], ``b`` [C].  Returns (the
+    convolution [S, B, C] in float32, before any activation; the window
+    [K - 1 + S, B, C], whose last K - 1 rows the caller keeps)."""
+    S, K = x.shape[0], w.shape[0]
+    window = jnp.concatenate([kept, x], axis=0)
+    w = w.astype(jnp.float32)
+    return b.astype(jnp.float32) + sum(
+        w[j] * window[j:j + S].astype(jnp.float32) for j in range(K)), window
+
+
 def vocab_projection(x, embed):
     """Final [B,S,D] → [B,S,V] projection: compute-dtype inputs on the
     MXU, f32 accumulation (an f32xf32 dot here ran at the MXU's
@@ -98,18 +165,24 @@ def install_request(state, slot, request, axes):
     """Write a request's state over slot ``slot``'s: every leaf of every
     slot kind, whole, so that nothing of the slot's last tenant is left;
     whatever else ``state`` holds at the top (the counters, which no slot
-    owns) passes through.  ``axes``: the model's ``SLOT_AXES``, for each
-    slot kind its state may hold the axis of each leaf that the slots lie
-    along (a request's leaf has one slot there).  ``state`` donated, the
+    owns) passes through, moved on by the request's ``"counted"`` (counter
+    names to increments) where its prefill counted something.  ``axes``:
+    the model's ``SLOT_AXES``, for each slot kind its state may hold the
+    axis of each leaf that the slots lie along (a request's leaf has one
+    slot there).  ``state`` donated, the
     writes are in place: one ``dynamic_update_slice`` a leaf."""
     def over(lane, new, axis):
         at = [0] * lane.ndim
         at[axis] = slot
         return lax.dynamic_update_slice(lane, new, at)
 
-    return {**state, **{
+    out = {**state, **{
         kind: jax.tree.map(over, state[kind], request[kind], axes[kind])
         for kind in axes if kind in state}}
+    if "counted" in request:    # what the request's prefill counted
+        out["counters"] = add_counters(state["counters"],
+                                       request["counted"])
+    return out
 
 
 def add_counters(counters, add):

@@ -24,7 +24,10 @@ builder.  The state's top-level keys are the kinds of state a slot holds:
   install passes them through, and a turn never reads them: the engine
   brings them to the host beside the read an admission makes anyway (the
   prefill's first token) and adds what they grew by to the registry, when
-  the registry is on.  Which two of them make a share on ``GET /stats``
+  the registry is on.  A request's state may hold ``"counted"``
+  (what its prefill counted, by counter name): the install adds it to the
+  batch's counters.
+  Which two of them make a share on ``GET /stats``
   is declared with them (``telemetry/registry.py``), not here.
 
 The install is ONE function for every model (``models/layers.py``:
@@ -109,6 +112,7 @@ from jax.sharding import PartitionSpec
 from horovod_tpu.models import jamba as J
 from horovod_tpu.models import latent_moe as X
 from horovod_tpu.models import retention as R
+from horovod_tpu.models import ssd_moe as S
 from horovod_tpu.models import transformer as T
 from horovod_tpu.models.layers import install_request
 from horovod_tpu.telemetry import registry as _tmx
@@ -169,7 +173,7 @@ class SlotModel(NamedTuple):
 # cfg)`` (the form the engine holds the parameters in: without it, as
 # given).  Its device counters are declared in ``telemetry/registry.py``.
 MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X,
-          R.RetentionConfig: R}
+          R.RetentionConfig: R, S.SsdMoEConfig: S}
 
 
 def slot_model(cfg, cache_len: int, mesh=None) -> SlotModel:

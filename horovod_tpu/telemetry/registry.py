@@ -210,7 +210,8 @@ KNOWN_METRICS: Dict[str, dict] = {
     "hvd_moe_rows_routed_total": _counter(
         "(row, expert) pairs the decode steps routed: live rows x experts "
         "a token x expert layers, a step.  The four hvd_moe_* counters are "
-        "summed ON THE DEVICE inside the step (models/latent_moe.py) and "
+        "summed ON THE DEVICE inside the step (models/latent_moe.py, "
+        "models/ssd_moe.py; the names: models/experts.py) and "
         "read by the engine beside an admission's own read, never on a "
         "turn."),
     "hvd_moe_experts_touched_total": _counter(
@@ -269,6 +270,22 @@ KNOWN_METRICS: Dict[str, dict] = {
         "steps.  hvd_serve_state_rows_live_total over this is "
         "state_live_share on GET /stats: the share of the state pass that "
         "served a request."),
+    "hvd_ssm_state_steps_live_total": _counter(
+        "(slot, Mamba-2 layer) state steps of slots with a request in them "
+        "(position > 0), summed over decode steps on the device like the "
+        "hvd_moe_* counters (models/ssd_moe.py); over "
+        "hvd_ssm_state_steps_total, the share of the state pass that served "
+        "a request."),
+    "hvd_ssm_state_steps_total": _counter(
+        "(slot, Mamba-2 layer) state steps the decode steps made "
+        "(max_batch x Mamba-2 layers a step: a free slot's state is stepped "
+        "too)."),
+    "hvd_ssm_prefill_chunks_total": _counter(
+        "Chunks of chunk_size rows that prompts ran through the chunked "
+        "(state-space duality) form, times the Mamba-2 layers: counted by "
+        "the prefill itself, carried in the request's state and added on "
+        "the device where the install writes it into its slot "
+        "(models/ssd_moe.py, models/layers.py:install_request)."),
     "hvd_serve_token_latency_seconds": _hist(
         "Wall time of one turn of the serving loop: the unread step's "
         "readback, token-agreement allreduce and emit, the frame's "

@@ -163,6 +163,15 @@ SERVE_RETENTION = dict(slots=32, vocab_size=1024, intermediate_size=512,
                        num_hidden_layers=2, max_seq_len=4736)
 
 
+# The nemotron-3-nano-30b-a3b cell's state and weights as it serves them
+# (96 slots x 4096; four Mamba-2 layers' [64, 64, 128] float32 states, one
+# attention layer's two key/value heads of 128, 64 held experts of 2688 x
+# 1856 in stacks padded to whole tiles) with a narrow vocabulary.
+SERVE_SSD = dict(slots=96, vocab_size=1024, num_hidden_layers=9,
+                 hybrid_override_pattern="EMEMEMEM*", experts_held=64,
+                 max_seq_len=4096)
+
+
 # Sequence lengths at which the attention kernel's gradient is compiled
 # alone: the training cell's (four blocks of 512 a row) and one whose block
 # falls under the chip's 128 lanes (2112 = 33 x 64).
@@ -246,7 +255,8 @@ def probe_lower_for_tpu(meshes_json):
     the two programs that write the serving slots' state produce there
     (:func:`serve_cache_programs`), for the dense decoder's cache and for
     models/jamba.py's two kinds of state, for models/latent_moe.py's
-    latent lanes and for models/retention.py's state matrices.  One
+    latent lanes, for models/retention.py's state matrices and for
+    models/ssd_moe.py's states, lanes and padded expert stacks.  One
     process for everything compiled
     for the chip (libtpu's lockfile); the compiles run in threads, XLA
     works outside the interpreter lock."""
@@ -257,7 +267,7 @@ def probe_lower_for_tpu(meshes_json):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.models import jamba, latent_moe, retention
+    from horovod_tpu.models import jamba, latent_moe, retention, ssd_moe
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
@@ -297,6 +307,9 @@ def probe_lower_for_tpu(meshes_json):
     retention_sizes = dict(SERVE_RETENTION)
     retention_slots = retention_sizes.pop("slots")
     rcfg = retention.RetentionConfig(**retention_sizes)
+    ssd_sizes = dict(SERVE_SSD)
+    ssd_slots = ssd_sizes.pop("slots")
+    mcfg = ssd_moe.SsdMoEConfig(**ssd_sizes)
     one_chip = SingleDeviceSharding(topo.devices[0])
     # Fewer threads than submissions: the later compiles take the threads
     # that fall free, so that the probe loads the machine no more than
@@ -324,6 +337,10 @@ def probe_lower_for_tpu(meshes_json):
             # one layer's state matrices: [slots, KVH, head_dim, rows]
             retention_slots * rcfg.num_key_value_heads * rcfg.head_dim
             * rcfg.state_rows, one_chip)
+        serve_ssd = pool.submit(
+            serve_cache_programs, mcfg, ssd_slots,
+            # one layer's recurrent state: [slots, heads, head_dim, state]
+            ssd_slots * mcfg.d_inner * mcfg.ssm_state_size, one_chip)
         flash_alone = [pool.submit(flash_grad_calls, seq_len, one_chip)
                        for seq_len in FLASH_ALONE]
         flash_layout = pool.submit(flash_step_layout_copies, topo.devices[0])
@@ -335,6 +352,7 @@ def probe_lower_for_tpu(meshes_json):
         "serve_latent": serve_latent.result(),
         "serve_sparse": serve_sparse.result(),
         "serve_retention": serve_retention.result(),
+        "serve_ssd": serve_ssd.result(),
         "flash_alone": [calls.result() for calls in flash_alone],
         "flash_layout": flash_layout.result(),
         "tpu_custom_call": [n for n, _ in found],

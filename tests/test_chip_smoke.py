@@ -405,6 +405,37 @@ def test_retention_state_programs_pass_over_the_state_once_on_the_chip(
     assert got["alias_bytes"] == held, got
 
 
+@pytest.mark.parametrize("program,updates", [
+    ("step", {"fusion:dynamic-update-slice", "fusion:scatter"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_ssd_state_programs_update_in_place_on_the_chip(
+        probes, program, updates):
+    """models/ssd_moe.py's state, compiled for ``v5e`` at the benchmark
+    cell's shapes and published widths: the step and the install produce
+    nothing of one layer's recurrent state's size besides the in-place
+    updates of the state they were given (an update-slice a Mamba-2 layer,
+    a row scatter a cache) and the compiler's own asynchronous moves: NO
+    copy of the routed experts' stacks (held unpadded, 1856 columns are
+    laid out the other way round and the grouped product copied all 2.5 GB
+    a turn) and none of a key/value lane (held heads-first, both lanes went
+    there and back around the scatter); the four state arrays and the eight
+    counters are aliased from input to output and the temporaries stay
+    under one layer's state."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_ssd"][program]
+    c = chip_probes.SERVE_SSD
+    layer_bytes = 4 * c["slots"] * 64 * 64 * 128
+    held = (4 * (layer_bytes + 2 * 3 * c["slots"] * 6144)
+            + 2 * 2 * c["slots"] * c["max_seq_len"] * 2 * 128 + 8 * 512)
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done",
+                "custom-call"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    assert got["temp_bytes"] < layer_bytes, got
+    assert got["alias_bytes"] == held, got
+
+
 @pytest.mark.parametrize("probe", ["serve_cache", "serve_state",
                                    "serve_latent", "serve_retention"])
 def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):

@@ -46,9 +46,9 @@ And the names of the three programs an engine compiles (``serve_step``,
 line shows), with the programs otherwise what the unnamed functions lower
 to.
 
-And the seam itself, over one small config of each of the five served
-forms (``FORMS``): the lowered text of four forms' step and prefill as
-sha256 digests taken before the install, the counter merge and the shared
+And the seam itself, over one small config of each of the six served
+forms (``FORMS``): the lowered text of five forms' step and prefill as
+sha256 digests, four of them taken before the install, the counter merge and the shared
 layer code moved to ``models/layers.py``; the ONE install's contract (the
 slot whole, every other slot and the counters untouched, nothing left of
 the last tenant); the device counters' names against the registry and its
@@ -71,7 +71,8 @@ import pytest
 
 from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
                          dims_key, serve_cache_programs, weight_dims)
-from horovod_tpu.models import jamba, latent_moe, layers, retention
+from horovod_tpu.models import (jamba, latent_moe, layers, retention,
+                                ssd_moe)
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import pallas_decode_attention as pda
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
@@ -549,13 +550,14 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
             == jax.tree.map(lambda a: (a.shape, a.dtype), state))
 
 
-# -- the seam over the five served forms ----------------------------------------
+# -- the seam over the six served forms ----------------------------------------
 #
 # One small config a served form, the fixtures' widths of tests/test_jamba.py,
 # test_latent_moe.py, test_sparse_latent_moe.py and test_retention.py in the
 # published types: each takes every branch of its form (attention and Mamba
 # runs; dense and expert layers; the indexer, a share of the experts, grouped
-# routing and YaRN; the retention's prompt blocks).
+# routing and YaRN; the retention's prompt blocks; the three layer kinds of
+# tests/test_ssd_moe.py's pattern with a share of its experts).
 
 YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
         "original_max_position_embeddings": 16, "mscale": 1.0,
@@ -586,11 +588,19 @@ FORMS = {
     "brumby-14b": retention.RetentionConfig(
         vocab_size=96, hidden_size=32, intermediate_size=64,
         num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
-        head_dim=16, rope_theta=10000.0, max_seq_len=64)}
+        head_dim=16, rope_theta=10000.0, max_seq_len=64),
+    "nemotron-3-nano-30b-a3b": ssd_moe.SsdMoEConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=10,
+        hybrid_override_pattern="EMEM*EMEM*", num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, mamba_num_heads=4,
+        mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
+        moe_intermediate_size=24, moe_shared_expert_intermediate_size=48,
+        n_routed_experts=8, num_experts_per_tok=3, max_seq_len=64,
+        experts_held=4, expert_first=4)}
 SLOTS, CACHE_LEN, PROMPT = 4, 64, 24
 
 # sha256 of the lowered text (StableHLO, no locations; jax 0.9.0) of the
-# engine's step and prefill (PROMPT tokens) for four of FORMS through
+# engine's step and prefill (PROMPT tokens) for five of FORMS through
 # ``decode.slot_model``, as tests/test_pallas_attention.py pins the dense
 # decoder's four, taken on the commit before the install, the counter merge,
 # ``_logits`` and the shared layer code moved to models/layers.py
@@ -613,7 +623,12 @@ LOWERED_BEFORE = {
     "brumby-14b:serve_step":
         "045e7d287c5889bb2ef8b6e04e8d45e04fbb2a6d108a3e51d6f8bd7a90c917c5",
     "brumby-14b:serve_prefill":
-        "2fdbfa717b5e079a1127aa9028da2babdb410e8a33da44054df4847d285524e1"}
+        "2fdbfa717b5e079a1127aa9028da2babdb410e8a33da44054df4847d285524e1",
+    # taken on PR 46's finished change, with its cell's numbers (PERF.md)
+    "nemotron-3-nano-30b-a3b:serve_step":
+        "72a93905d2c6c55392842c9e712e36e5e6304f96cb02aaa886d5cab5f2d714d4",
+    "nemotron-3-nano-30b-a3b:serve_prefill":
+        "d0a7aaa3939a5fad29b70127b0c6a4f2314500ca315b74a35a71dc0d05c4d9cd"}
 
 
 @pytest.mark.parametrize("which", list(LOWERED_BEFORE))
@@ -657,8 +672,10 @@ def _slot_leaves(model_axes, state):
 @pytest.mark.parametrize("form", list(FORMS))
 def test_the_one_install_writes_a_slot_whole_and_nothing_else(form):
     """After the shared install every slot-kind leaf of the slot is the
-    request's, every other slot's bytes and the counters are as they were,
-    and an install of zeros leaves nothing of the last tenant."""
+    request's, every other slot's bytes are as they were, the counters are
+    moved on by what the request's prefill counted (nothing, for most
+    forms) and by nothing else, and an install of zeros leaves nothing of
+    the last tenant."""
     cfg = FORMS[form]
     module = decode.MODELS[type(cfg)]
     model = decode.slot_model(cfg, CACHE_LEN)
@@ -679,11 +696,17 @@ def test_the_one_install_writes_a_slot_whole_and_nothing_else(form):
     request = seeded(jax.eval_shape(
         lambda k: model.prefill(model.held(module.init(k, cfg)),
                                 jnp.arange(1, 10))[1], jax.random.PRNGKey(0)))
+    counted = {name: jnp.uint32(3 + i)
+               for i, name in enumerate(request.get("counted", {}))}
+    if counted:
+        request["counted"] = counted
     install = jax.jit(model.install)        # the slot traced, as the engine's
     after = install(before, 2, request)
     assert jax.tree.structure(after) == jax.tree.structure(before)
-    np.testing.assert_equal(jax.device_get(after.get("counters")),
-                            jax.device_get(before.get("counters")))
+    want = jax.device_get(before.get("counters"))
+    for name, n in counted.items():
+        want[name] = want[name] + np.uint32(n)
+    np.testing.assert_equal(jax.device_get(after.get("counters")), want)
     wrote = 0
     for (kind, got, axis), (_, was, _), (_, new, _) in zip(
             _slot_leaves(module.SLOT_AXES, after),
@@ -694,7 +717,7 @@ def test_the_one_install_writes_a_slot_whole_and_nothing_else(form):
         np.testing.assert_array_equal(np.delete(got, 2, axis),
                                       np.delete(was, 2, axis), kind)
         wrote += 1
-    assert wrote == len(jax.tree.leaves(request)) > 0
+    assert wrote == len(jax.tree.leaves(request)) - len(counted) > 0
     emptied = install(after, 2, jax.tree.map(jnp.zeros_like, request))
     for kind, got, axis in _slot_leaves(module.SLOT_AXES, emptied):
         assert not np.take(got, 2, axis).any(), kind
@@ -728,7 +751,8 @@ def test_model_files_import_no_other_and_the_server_names_no_counter():
     from pathlib import Path
 
     pkg = Path(decode.__file__).resolve().parent.parent
-    models = ("transformer", "jamba", "latent_moe", "retention", "resnet")
+    models = ("transformer", "jamba", "latent_moe", "retention", "ssd_moe",
+              "resnet")
     for name in models + ("layers", "experts"):
         tree = ast.parse((pkg / "models" / f"{name}.py").read_text())
         imported = set()
