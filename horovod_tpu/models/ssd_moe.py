@@ -25,8 +25,12 @@ scan nor the grouped product has a backward pass written for it).
       out = RMSNorm_grouped(y silu(z)) W_out     the gate first, then a
                                                  norm a group of d_inner / G
 
-  *One token* against a carried state is that recurrence, one step
-  (``ssd_step``).  *A prompt* runs the **chunked form** of the same
+  *One token* against a carried state is that recurrence, one step: ONE
+  kernel a layer over the slots that hold a request
+  (``ops/pallas_ssd.py:ssd_step``: a slot's state read once, stepped,
+  read out and written back in place; ``_ssd_step`` here is the same in
+  plain JAX over every slot, the tests' oracle).  *A prompt* runs the
+  **chunked form** of the same
   (``ssd_scan``: state-space duality), chunks of ``chunk_size`` rows:
   inside a chunk ``Y = (L o C B^T)(D x)`` with ``L_ts = exp(sum_{s<r<=t}
   D_r A)``, three batched matrix products on the MXU; a chunk's end state
@@ -59,7 +63,10 @@ scan nor the grouped product has a backward pass written for it).
 State of a served batch (``init_state``)::
 
     {"kv": (k, v)              [La, B, cache_len, KVH, HD]  compute_dtype
-     "recurrent": (ssm, conv)  [Lm, B, H, P, N] float32,
+     "recurrent": (ssm, conv)  [Lm, B, G, N, (H / G) P] float32: a
+                               group's heads' channels side by side, the
+                               read-out's sum down the rows
+                               (``pallas_ssd.from_heads`` of [H, P, N]);
                                [Lm, conv_kernel - 1, B, d_inner + 2 G N]
                                compute_dtype
      "counters": {...}         uint32 scalars, summed on the device: see
@@ -69,8 +76,9 @@ A request's state (``prefill_request``) is the slot kinds with B = 1 and
 ``"counted"``: what the prefill itself counted (its chunks), which the
 install adds to the batch's counters.  A slot whose position is 0 is free
 (``DecodeEngine.clear``): its row is kept out of the routing and of the
-live counts; its state is stepped like any other (the pass is over all
-slots).  The module omits what ``serving/decode.py:MODELS`` lets it: no
+live counts, and its recurrent state is neither read nor written (the
+state step's grid is the live slots; the install overwrites the slot at
+admission).  The module omits what ``serving/decode.py:MODELS`` lets it: no
 sharding of this state is written, and the weights come in
 ``param_dtype``, which is for the caller to choose.
 """
@@ -89,14 +97,16 @@ from horovod_tpu.models import experts
 from horovod_tpu.models.layers import (_at, _attention_no_positions,
                                        _causal_conv, _logits, _put, _rmsnorm,
                                        add_counters)
+from horovod_tpu.ops import pallas_ssd
 
 Params = Dict[str, Any]
 State = Dict[str, Any]
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 # What ``decode_step`` adds to ``state["counters"]`` a step besides the
-# routing's: (slot, Mamba-2 layer) state steps, all and of slots with a
-# request in them.  What ``prefill_request`` counts: the chunks its prompt
+# routing's: (slot, Mamba-2 layer) state steps taken, and those of slots
+# with a request in them (the same number: the step's grid is the live
+# slots).  What ``prefill_request`` counts: the chunks its prompt
 # ran through the chunked form, times the Mamba-2 layers.
 STATE_COUNTERS = ("hvd_ssm_state_steps_total",
                   "hvd_ssm_state_steps_live_total")
@@ -260,7 +270,9 @@ def init(rng, cfg: SsdMoEConfig) -> Params:
 
 
 def _ssd_step(ssm, x, dt, a, b_in, c_out):
-    """One step of the recurrence for every slot.  ssm: [B, H, P, N]
+    """One step of the recurrence for every slot, in plain JAX: what
+    ``pallas_ssd.ssd_step`` is held to (tests/test_pallas_ssd.py) and the
+    chunked form's oracle; no program calls it.  ssm: [B, H, P, N]
     float32; x: [B, H, P]; dt: [B, H]; a: [H]; b_in, c_out: [B, G, N]; all
     float32.  Returns (y [B, H, P] without the ``D x`` term, the new
     state)."""
@@ -327,22 +339,27 @@ def _ssd_scan(ssm, x, dt, a, b_in, c_out, chunk: int):
     return (y.reshape((-1, B, H, P))[:S], ssm.reshape(B, H, P, N))
 
 
-def _mamba_mixer(u, lp, cfg: SsdMoEConfig, state=None):
-    """The Mamba-2 mixer.  u: [B, S, D]; ``state`` None (a sequence's
-    start: zeros) or (ssm [B, H, P, N] float32, conv [conv_kernel - 1, B,
-    conv_dim]: the last inputs of the convolution).  Returns (out [B, S,
-    D], the state after the last position).  One token against a carried
-    state (S = 1) takes the recurrence's step, a prompt (S > 1) its chunked
-    form; inside, time is the leading axis."""
+def _mamba_mixer(u, lp, cfg: SsdMoEConfig, carried=None):
+    """The Mamba-2 mixer.  u: [B, S, D].  ``carried`` None: the sequences
+    start here (zero state) and run the recurrence's chunked form; returns
+    (out [B, S, D], (ssm [B, G, N, W] float32, conv [conv_kernel - 1, B,
+    conv_dim]: the last inputs of the convolution) after the last
+    position).  ``carried`` = (ssm, layer, work, conv): one token a slot (S
+    = 1) against layer ``layer`` of the STACKED states ``ssm`` [Lm, B, G,
+    N, W], stepped in place for the slots of ``work``
+    (``pallas_ssd.live_slots``; every other slot's state is left as it
+    is and answers zeros), and that layer's ``conv``; returns (out, (the
+    stack, conv)).  Inside, time is the leading axis."""
     dtype, f32 = cfg.compute_dtype, jnp.float32
     B, S, _ = u.shape
     H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
                   cfg.ssm_state_size)
     Di, C = cfg.d_inner, cfg.conv_dim
-    if state is None:
-        state = (jnp.zeros((B, H, P, N), f32),
-                 jnp.zeros((cfg.conv_kernel - 1, B, C), dtype))
-    ssm, conv = state
+    start = carried is None
+    if start:
+        carried = (jnp.zeros((B, H, P, N), f32), None, None,
+                   jnp.zeros((cfg.conv_kernel - 1, B, C), dtype))
+    ssm, layer, work, conv = carried
     # (Rows first, then time to the front: XLA's CPU runtime has no
     # bfloat16 product into float32 that also transposes its result.)
     zxd = jnp.einsum("bsd,de->bse", u, lp["in_proj"].astype(dtype),
@@ -356,14 +373,16 @@ def _mamba_mixer(u, lp, cfg: SsdMoEConfig, state=None):
     c_out = xbc[..., Di + G * N:].reshape(S, B, G, N)
     dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
     a = -jnp.exp(lp["a_log"].astype(f32))
-    if S == 1:
-        with jax.named_scope("ssd_step"):
-            y, ssm = _ssd_step(ssm, x[0].astype(f32), dt[0], a,
-                               b_in[0].astype(f32), c_out[0].astype(f32))
-            y = y[None]
-    else:
+    if start:
         with jax.named_scope("ssd_scan"):
             y, ssm = _ssd_scan(ssm, x, dt, a, b_in, c_out, cfg.chunk_size)
+            ssm = pallas_ssd.from_heads(ssm, G)
+    else:
+        with jax.named_scope("ssd_step"):
+            ssm, y = pallas_ssd.ssd_step(
+                ssm, layer, work, x[0].astype(f32), dt[0], a,
+                b_in[0].astype(f32), c_out[0].astype(f32))
+            y = y[None]
     y = y + lp["d"].astype(f32)[:, None] * x.astype(f32)
     y = y.reshape(S, B, Di) * jax.nn.silu(z)
     # The gate first, then a norm over each group's channels apart.
@@ -395,8 +414,8 @@ def init_state(cfg: SsdMoEConfig, max_batch: int, cache_len: int) -> State:
         "kv": (jnp.zeros(lane, cfg.compute_dtype),
                jnp.zeros(lane, cfg.compute_dtype)),
         "recurrent": (
-            jnp.zeros((Lm, max_batch, cfg.mamba_num_heads,
-                       cfg.mamba_head_dim, cfg.ssm_state_size), jnp.float32),
+            jnp.zeros((Lm, max_batch, cfg.n_groups, cfg.ssm_state_size,
+                       cfg.d_inner // cfg.n_groups), jnp.float32),
             jnp.zeros((Lm, cfg.conv_kernel - 1, max_batch, cfg.conv_dim),
                       cfg.compute_dtype)),
         "counters": {name: jnp.zeros((), jnp.uint32)
@@ -417,14 +436,16 @@ def _stack(params: Params, x, cfg: SsdMoEConfig, state: Optional[State],
     what they end in (keys and values at rows [0, S), the recurrent state
     after row S - 1).  ``pos`` [B]: one token a slot continuing ``state``,
     which is read and written at its layer; rows at position 0 are free
-    slots and are routed nowhere.  Returns (x, the state's slot kinds, the
-    routing's stats [3] summed over the expert layers)."""
+    slots: routed nowhere, their recurrent state not stepped.  Returns (x,
+    the state's slot kinds, the routing's stats [3] summed over the expert
+    layers)."""
     dtype, eps = cfg.compute_dtype, cfg.layer_norm_epsilon
     start = pos is None
     carries = state is not None
     kv = state["kv"] if carries else ()
     rec = state["recurrent"] if carries else ()
     live = None if start else pos > 0
+    work = None if start else pallas_ssd.live_slots(live)
     B, S, D = x.shape
     small = {k: v for k, v in params["moe"].items() if k not in _EXPERTS}
     routed = {k: params["moe"][k] for k in _EXPERTS}
@@ -436,10 +457,12 @@ def _stack(params: Params, x, cfg: SsdMoEConfig, state: Optional[State],
         seen[kind] += 1
         if kind == "mamba":
             lp = _at(params["mamba"], l)
-            y, new = _mamba_mixer(_rmsnorm(x, lp["ln"], eps), lp, cfg,
-                                  None if start else _at(rec, l))
+            y, new = _mamba_mixer(
+                _rmsnorm(x, lp["ln"], eps), lp, cfg,
+                None if start else (rec[0], l, work, _at(rec[1], l)))
             if carries:
-                rec = (_put(rec[0], l, new[0]), _put(rec[1], l, new[1]))
+                rec = (_put(rec[0], l, new[0]) if start else new[0],
+                       _put(rec[1], l, new[1]))
         elif kind == "attn":
             lp = _at(params["attn"], l)
             y = _rmsnorm(x, lp["ln"], eps)
@@ -515,8 +538,7 @@ def decode_step(params: Params, tok, pos, state: State, cfg: SsdMoEConfig):
     if cfg.experts_held is not None:    # the live pairs no group here took
         add[experts.ABSENT_COUNTER] = (Le * cfg.num_experts_per_tok * live
                                        - stats[0])
-    add[STATE_COUNTERS[0]] = jnp.uint32(Lm * pos.shape[0])
-    add[STATE_COUNTERS[1]] = Lm * live
+    add[STATE_COUNTERS[0]] = add[STATE_COUNTERS[1]] = Lm * live
     return (_logits(x, params["ln_f"], params["head"],
                     cfg.layer_norm_epsilon)[:, 0],
             {**slots, "counters": add_counters(state["counters"], add)})

@@ -274,12 +274,15 @@ KNOWN_METRICS: Dict[str, dict] = {
         "(slot, Mamba-2 layer) state steps of slots with a request in them "
         "(position > 0), summed over decode steps on the device like the "
         "hvd_moe_* counters (models/ssd_moe.py); over "
-        "hvd_ssm_state_steps_total, the share of the state pass that served "
-        "a request."),
+        "hvd_ssm_state_steps_total, the share of the state steps taken that "
+        "served a request: 1 since the step's grid is the live slots "
+        "(ops/pallas_ssd.py), less only for a program that steps free "
+        "slots too."),
     "hvd_ssm_state_steps_total": _counter(
-        "(slot, Mamba-2 layer) state steps the decode steps made "
-        "(max_batch x Mamba-2 layers a step: a free slot's state is stepped "
-        "too)."),
+        "(slot, Mamba-2 layer) state steps the decode steps TOOK: a read "
+        "and a write of a slot's state a layer.  The kernel's grid is the "
+        "slots with a request in them, so a free slot's state is not "
+        "stepped and this equals hvd_ssm_state_steps_live_total."),
     "hvd_ssm_prefill_chunks_total": _counter(
         "Chunks of chunk_size rows that prompts ran through the chunked "
         "(state-space duality) form, times the Mamba-2 layers: counted by "

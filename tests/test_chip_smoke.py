@@ -406,15 +406,18 @@ def test_retention_state_programs_pass_over_the_state_once_on_the_chip(
 
 
 @pytest.mark.parametrize("program,updates", [
-    ("step", {"fusion:dynamic-update-slice", "fusion:scatter"}),
+    ("step", {"custom-call", "fusion:scatter"}),
     ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
 def test_ssd_state_programs_update_in_place_on_the_chip(
         probes, program, updates):
     """models/ssd_moe.py's state, compiled for ``v5e`` at the benchmark
     cell's shapes and published widths: the step and the install produce
     nothing of one layer's recurrent state's size besides the in-place
-    updates of the state they were given (an update-slice a Mamba-2 layer,
-    a row scatter a cache) and the compiler's own asynchronous moves: NO
+    updates of the state they were given (the step ONE Mosaic call
+    ``ssd_step`` a Mamba-2 layer on the whole stacked state, aliased, and a
+    row scatter a cache: no fusion over the state, no layer cut out of the
+    stack or put back; the install an update-slice a leaf) and the
+    compiler's own asynchronous moves: NO
     copy of the routed experts' stacks (held unpadded, 1856 columns are
     laid out the other way round and the grouped product copied all 2.5 GB
     a turn) and none of a key/value lane (held heads-first, both lanes went
@@ -428,12 +431,29 @@ def test_ssd_state_programs_update_in_place_on_the_chip(
     layer_bytes = 4 * c["slots"] * 64 * 64 * 128
     held = (4 * (layer_bytes + 2 * 3 * c["slots"] * 6144)
             + 2 * 2 * c["slots"] * c["max_seq_len"] * 2 * 128 + 8 * 512)
-    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done",
-                "custom-call"}
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
     assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
     assert {op for _, op in got["big_ops"]} & updates, got
     assert got["temp_bytes"] < layer_bytes, got
     assert got["alias_bytes"] == held, got
+
+
+def test_ssd_decode_step_is_one_kernel_a_mamba_layer_on_the_chip(probes):
+    """The decode step compiled for ``v5e`` at the cell's shapes: what it
+    produces of the recurrent state's size is the result of ONE Mosaic
+    call ``ssd_step`` a Mamba-2 layer (ops/pallas_ssd.py: four layers, the
+    whole stacked state in and out, aliased), and nothing else of that
+    size touches it: no ``fusion`` over ``[Lm, B, ...]`` (XLA's update in
+    place and its read-out were two), no copy, no update-slice."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_ssd"]["step"]
+    by_op = {}
+    for name, op in got["big_ops"]:
+        by_op.setdefault(op, []).append(name.split(".")[0])
+    assert by_op.pop("custom-call") == ["ssd_step"] * 4, got
+    # besides: the two key/value lanes' row scatters
+    assert set(by_op) == {"fusion:scatter"}, got
 
 
 @pytest.mark.parametrize("probe", ["serve_cache", "serve_state",

@@ -624,11 +624,13 @@ LOWERED_BEFORE = {
         "045e7d287c5889bb2ef8b6e04e8d45e04fbb2a6d108a3e51d6f8bd7a90c917c5",
     "brumby-14b:serve_prefill":
         "2fdbfa717b5e079a1127aa9028da2babdb410e8a33da44054df4847d285524e1",
-    # taken on PR 46's finished change, with its cell's numbers (PERF.md)
+    # taken on PR 47's finished change, with its cell's numbers (PERF.md):
+    # the state step is the kernel ``ssd_step`` and the state lies ``[Lm,
+    # B, G, N, W]``, so a prefill ends in a transposition of its end state
     "nemotron-3-nano-30b-a3b:serve_step":
-        "72a93905d2c6c55392842c9e712e36e5e6304f96cb02aaa886d5cab5f2d714d4",
+        "3b5dba2c0f16834aa96e299e207748a867a9869ee09142cf4c51b110d11e99a3",
     "nemotron-3-nano-30b-a3b:serve_prefill":
-        "d0a7aaa3939a5fad29b70127b0c6a4f2314500ca315b74a35a71dc0d05c4d9cd"}
+        "ebe7c2a04f1496c883279d7699db55dffcf38d5f82a32bdf60e42989a289f5c2"}
 
 
 @pytest.mark.parametrize("which", list(LOWERED_BEFORE))

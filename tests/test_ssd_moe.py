@@ -143,8 +143,10 @@ def test_slots_decode_what_the_full_forward_pass_gives(params):
     c = {k: int(v) for k, v in state["counters"].items()}
     Lm, Le = CFG.n_layers("mamba"), CFG.n_layers("moe")
     turns = 3 + steps - 1
-    assert c["hvd_ssm_state_steps_total"] == Lm * 4 * turns
-    assert c["hvd_ssm_state_steps_live_total"] == Lm * (3 + 3 * (steps - 1))
+    # the steps taken are the live slots' (the free slot's state is not
+    # stepped): three turns of one request, then three requests a turn
+    assert c["hvd_ssm_state_steps_total"] == c[
+        "hvd_ssm_state_steps_live_total"] == Lm * (3 + 3 * (steps - 1))
     assert c["hvd_moe_layer_turns_total"] == Le * turns
     assert (c["hvd_moe_rows_routed_total"] + c["hvd_moe_rows_absent_total"]
             == Le * 3 * (3 + 3 * (steps - 1)))
@@ -154,8 +156,8 @@ def test_slots_decode_what_the_full_forward_pass_gives(params):
 
 def test_engine_serves_it_and_counts_on_the_device(params):
     """Through ``DecodeEngine`` (the one install, donated state): greedy
-    tokens are the full forward pass's, and a free slot is stepped but not
-    live."""
+    tokens are the full forward pass's, and the two free slots' state is
+    not stepped."""
     engine = DecodeEngine(params, CFG, max_batch=3, cache_len=32)
     prompt = [3, 14, 15, 9, 26, 5, 35]
     got = [engine.prefill(1, prompt)]
@@ -164,8 +166,9 @@ def test_engine_serves_it_and_counts_on_the_device(params):
     logits = forward(params, prompt + got)      # causal: one pass for all
     assert got == np.argmax(logits, -1)[len(prompt) - 1:-1].tolist()
     c = engine.counters()
-    assert c["hvd_ssm_state_steps_total"] == 3 * c[
-        "hvd_ssm_state_steps_live_total"] == 3 * 4 * CFG.n_layers("mamba")
+    assert c["hvd_ssm_state_steps_total"] == c[
+        "hvd_ssm_state_steps_live_total"] == 4 * CFG.n_layers("mamba")
+    assert not np.asarray(engine.state["recurrent"][0])[:, [0, 2]].any()
     assert c["hvd_ssm_prefill_chunks_total"] == CFG.n_layers("mamba")
 
 
@@ -387,6 +390,7 @@ def test_published_defaults_are_the_published_model():
         dataclasses.replace(cfg, num_hidden_layers=9,
                             hybrid_override_pattern="EMEMEMEM*"), 96, 4096))
     ssm, conv = state["recurrent"]
-    assert ssm.shape == (4, 96, 64, 64, 128) and ssm.dtype == jnp.float32
+    # a slot's [64, 64, 128] a layer, a group's 8 heads' channels minor
+    assert ssm.shape == (4, 96, 8, 128, 512) and ssm.dtype == jnp.float32
     assert conv.shape == (4, 3, 96, 6144) and conv.dtype == jnp.bfloat16
     assert state["kv"][0].shape == (1, 96, 4096, 2, 128)
