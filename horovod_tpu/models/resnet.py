@@ -220,11 +220,25 @@ def _bn(x, p, s, train: bool):
     is unusable), but the per-element normalization is a single fused
     multiply-add ``x * inv + shift`` with the fp32 scalars folded and cast
     once — in bf16 this halves the HBM bytes of every BN in the network
-    versus upcasting the whole activation tensor to fp32."""
+    versus upcasting the whole activation tensor to fp32.
+
+    The batch statistics are two sums over ``x`` that do not wait for
+    each other: ``E[x]``, and the biased variance as ``E[x^2] - E[x]^2``.
+    XLA puts both in the epilogue of the convolution that wrote ``x``, so
+    no pass reads the activation for statistics alone; ``jnp.var`` needs
+    the mean first and costs such a pass a layer forward and, for the
+    mean's cotangent, another backward (``tools/resnet_passes.py`` counts
+    them).  The difference cancels where a channel's mean is large
+    against its deviation: float32 sums hold the variance to 1e-3 up to
+    10 deviations and to 1e-2 at 30, ResNet-50's own channels stay under
+    10 (PERF.md section 6, PR 48), and the clamp keeps rounding from
+    handing ``rsqrt`` a negative number (``tests/test_models.py`` holds
+    all of it to a float64 reference)."""
     if train:
         xf = x.astype(jnp.float32)
         mean = jnp.mean(xf, axis=(0, 1, 2))
-        var = jnp.var(xf, axis=(0, 1, 2))
+        var = jnp.maximum(
+            jnp.mean(xf * xf, axis=(0, 1, 2)) - mean * mean, 0.0)
         new_s = {
             "mean": _BN_MOMENTUM * s["mean"] + (1 - _BN_MOMENTUM) * mean,
             "var": _BN_MOMENTUM * s["var"] + (1 - _BN_MOMENTUM) * var,

@@ -98,6 +98,153 @@ def test_resnet_remat_matches_plain():
         g1, g2)
 
 
+def _bn_inputs(shape):
+    c = shape[-1]
+    p = {"scale": jnp.ones((c,), jnp.float32),
+         "bias": jnp.zeros((c,), jnp.float32)}
+    s = {"mean": jnp.zeros((c,), jnp.float32),
+         "var": jnp.ones((c,), jnp.float32)}
+    return p, s
+
+
+def _bn_two_pass(x, p, s, train: bool):
+    """``_bn`` as it stood before the one-pass statistics (the mean, then
+    ``jnp.var`` around it): the reference the new body is held to."""
+    if train:
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=(0, 1, 2))
+        var = jnp.var(xf, axis=(0, 1, 2))
+        m = resnet._BN_MOMENTUM
+        new_s = {"mean": m * s["mean"] + (1 - m) * mean,
+                 "var": m * s["var"] + (1 - m) * var}
+    else:
+        mean, var = s["mean"], s["var"]
+        new_s = s
+    inv = jax.lax.rsqrt(var + resnet._BN_EPS) * p["scale"]
+    shift = p["bias"] - mean * inv
+    return x * inv.astype(x.dtype) + shift.astype(x.dtype), new_s
+
+
+@pytest.mark.parametrize("deviations,var_bound",
+                         [(0, 1e-3), (3, 1e-3), (10, 1e-3), (30, 1e-2)])
+@pytest.mark.parametrize("shape", [(8, 7, 7, 16), (4, 14, 14, 64)])
+def test_bn_train_statistics_match_float64_two_pass(
+        shape, deviations, var_bound, monkeypatch):
+    """The batch statistics are ``E[x]`` and ``E[x^2] - E[x]^2`` in
+    float32, from a fresh state.  Against a float64 two-pass reference
+    over the same bfloat16-rounded inputs: the mean to 1e-5, the variance
+    to 1e-3, never negative.  The difference cancels as a channel's mean
+    grows against its deviation: 1e-3 holds up to 10 deviations, which is
+    past what ResNet-50 shows on the benchmark's data (8.7 at most over
+    its 53 layers, PERF.md section 6, PR 48).  At 30 deviations the
+    variance reads up to 6e-3 off, so that case is held to 1e-2: such a
+    variance moves the scale ``rsqrt`` gives by 3e-3, under one step of
+    the bfloat16 activation it multiplies (3.9e-3), three times beyond
+    any channel the model has.  (The running mean as a pivot does not
+    help here: a fresh state's is 0.)"""
+    monkeypatch.setattr(resnet, "_BN_MOMENTUM", 0.0)  # new_s IS the stat
+    rng = np.random.default_rng(shape[0] * 100 + deviations)
+    std = rng.uniform(0.5, 2.0, shape[-1])
+    x = jnp.asarray(
+        (deviations + rng.standard_normal(shape)) * std, jnp.bfloat16)
+    p, s = _bn_inputs(shape)
+    y, new_s = resnet._bn(x, p, s, True)
+    x64 = np.asarray(x.astype(jnp.float32), np.float64)
+    mean = x64.mean(axis=(0, 1, 2))
+    var = ((x64 - mean) ** 2).mean(axis=(0, 1, 2))
+    got_mean = np.asarray(new_s["mean"], np.float64)
+    got_var = np.asarray(new_s["var"], np.float64)
+    assert np.all(got_var >= 0.0)
+    scale = np.abs(mean) + np.sqrt(var)  # a mean of 0 has no relative error
+    assert np.max(np.abs(got_mean - mean) / scale) < 1e-5
+    assert np.max(np.abs(got_var - var) / var) < var_bound
+    assert np.all(np.isfinite(np.asarray(y, np.float32)))
+
+
+@pytest.mark.parametrize("shape", [(8, 7, 7, 16), (4, 14, 14, 64)])
+def test_bn_constant_channel_has_zero_variance(shape, monkeypatch):
+    """A channel that holds one value everywhere (here values whose
+    squares sum exactly in float32) reads a variance of exactly 0, not the
+    small negative number a difference of two rounded sums can give, and
+    its output is finite: ``rsqrt(0 + eps)``."""
+    monkeypatch.setattr(resnet, "_BN_MOMENTUM", 0.0)
+    consts = np.resize(np.array([0.0, 1.5, -2.25, 30.0]), shape[-1])
+    x = jnp.asarray(np.broadcast_to(consts, shape), jnp.bfloat16)
+    p, s = _bn_inputs(shape)
+    y, new_s = resnet._bn(x, p, s, True)
+    np.testing.assert_array_equal(np.asarray(new_s["var"]), 0.0)
+    np.testing.assert_array_equal(np.asarray(new_s["mean"]), consts)
+    assert np.all(np.isfinite(np.asarray(y, np.float32)))
+
+
+def test_bn_one_pass_gradient_matches_two_pass(monkeypatch):
+    """Autodiff of the one-pass statistics is the two-pass form's
+    gradient: every leaf of a small ResNet's loss gradient, float32
+    throughout, within 1e-4 of its norm."""
+    cfg = small_resnet_cfg()
+    params, stats = resnet.init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 32, 32, 3))
+    y = jax.random.randint(jax.random.PRNGKey(2), (16,), 0, 10)
+
+    def grad():
+        return jax.grad(
+            lambda p: resnet.loss_fn(p, stats, x, y, cfg)[0])(params)
+
+    got = grad()
+    monkeypatch.setattr(resnet, "_bn", _bn_two_pass)
+    want = grad()
+    gaps = jax.tree.map(
+        lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)),
+        got, want)
+    worst = max(jax.tree.leaves(gaps))
+    assert worst < 1e-4, gaps
+
+
+def _activation_reductions(jaxpr):
+    """``(eqn, ancestors)`` of every top-level equation of ``jaxpr`` that
+    is, or holds (a nested ``jit``), a sum over the three leading axes of
+    a rank-4 array; ``ancestors`` are the equations its inputs come from,
+    transitively."""
+    def reduces(eqn):
+        if (eqn.primitive.name == "reduce_sum"
+                and tuple(eqn.params["axes"]) == (0, 1, 2)
+                and eqn.invars[0].aval.ndim == 4):
+            return 1
+        return sum(reduces(e) for sub in jax.core.jaxprs_in_params(
+            eqn.params) for e in sub.eqns)
+
+    made_by, ancestors, found = {}, [], []
+    for i, eqn in enumerate(jaxpr.eqns):
+        mine = set()
+        for v in eqn.invars:
+            j = made_by.get(id(v))
+            if j is not None:
+                mine |= {j} | ancestors[j]
+        ancestors.append(mine)
+        for v in eqn.outvars:
+            made_by[id(v)] = i
+        found += [(i, mine)] * reduces(eqn)
+    return found
+
+
+def test_bn_train_is_two_independent_sums_over_the_activation():
+    """What keeps the two-pass form from coming back: the training-mode
+    norm holds exactly two reductions over the activation and neither
+    waits for the other (``jnp.var`` is a third, behind the mean), so a
+    compiler can put both behind the convolution that wrote it."""
+    shape = (4, 14, 14, 64)
+    p, s = _bn_inputs(shape)
+    x = jnp.ones(shape, jnp.bfloat16)
+    found = _activation_reductions(jax.make_jaxpr(
+        lambda x, p, s: resnet._bn(x, p, s, True))(x, p, s).jaxpr)
+    assert len(found) == 2, found
+    (a, before_a), (b, before_b) = found
+    assert a != b and a not in before_b and b not in before_a
+    old = _activation_reductions(jax.make_jaxpr(
+        lambda x, p, s: _bn_two_pass(x, p, s, True))(x, p, s).jaxpr)
+    assert len(old) == 3, old
+
+
 def test_resnet50_param_count():
     cfg = resnet.resnet50_config()
     shapes = jax.eval_shape(

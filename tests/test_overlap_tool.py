@@ -10,16 +10,16 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "tools"))
 
-from measure_overlap import _ring_bytes, _shape_bytes, measure  # noqa: E402
+from measure_overlap import _ring_bytes, shape_bytes, measure  # noqa: E402
 import device_peaks  # noqa: E402  (measure_overlap put the repo root on sys.path)
 
 V5E = device_peaks.peak("TPU v5 lite")
 
 
 def test_shape_bytes():
-    assert _shape_bytes("f32[128,256]{1,0}") == 128 * 256 * 4
-    assert _shape_bytes("(f32[8]{0}, bf16[4]{0})") == 32 + 8
-    assert _shape_bytes("%name, metadata={}") == 0
+    assert shape_bytes("f32[128,256]{1,0}") == 128 * 256 * 4
+    assert shape_bytes("(f32[8]{0}, bf16[4]{0})") == 32 + 8
+    assert shape_bytes("%name, metadata={}") == 0
 
 
 def test_ring_bytes_start_tuple_halved():

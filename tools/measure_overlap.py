@@ -54,7 +54,7 @@ _F32 = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f64": 8,
         "s8": 1, "u8": 1, "pred": 1}
 
 
-def _shape_bytes(shape: str) -> int:
+def shape_bytes(shape: str) -> int:
     """Bytes of an HLO shape string like ``f32[128,256]{1,0}``."""
     total = 0
     for m in re.finditer(r"(\w+)\[([\d,]*)\]", shape):
@@ -80,7 +80,7 @@ _COMPUTE_OPS = {"fusion", "convolution", "dot", "custom-call", "copy",
                 "slice", "add", "multiply", "subtract", "divide"}
 
 
-def _opcode(rhs: str):
+def opcode(rhs: str):
     m = _OPCODE_RE.search(rhs)
     return m.group(1) if m else None
 
@@ -90,7 +90,7 @@ def _inst_cost(rhs: str, peak: device_peaks.Peak) -> float:
     bandwidth (memory-bound estimate; big matmuls run longer than this,
     so compute windows are *under*-credited — conservative for the
     overlap fraction)."""
-    return _shape_bytes(rhs) / peak.hbm_bytes_per_s
+    return shape_bytes(rhs) / peak.hbm_bytes_per_s
 
 
 # One shared collective-op vocabulary for the entry walk and the
@@ -135,15 +135,15 @@ def _ring_bytes(rhs: str, op: str) -> int:
     base, _ = _coll_base(op)
     if base == "all-reduce":
         after = rhs.split(op + "(", 1)[-1]
-        b = _shape_bytes(after)
+        b = shape_bytes(after)
         if b:
             return b
         before = rhs.split(op + "(", 1)[0]
-        b = _shape_bytes(before)
+        b = shape_bytes(before)
         return b // 2 if op.endswith("-start") else b
     best = 0
     for m in re.finditer(r"\w+\[[\d,]*\]", rhs):
-        best = max(best, _shape_bytes(m.group(0)))
+        best = max(best, shape_bytes(m.group(0)))
     return best
 
 
@@ -154,6 +154,33 @@ def _coll_cost(rhs: str, op: str, n_dev: int,
     base, _ = _coll_base(op)
     return (_wire_factor(base, n_dev) * _ring_bytes(rhs, op)
             / (peak.ici_bytes_per_s / peak.ici_links))
+
+
+def entry_bounds(all_lines):
+    """``(start, end)`` line positions of the entry computation, its
+    ``ENTRY`` line to its closing zero-indent brace; the whole text where
+    there is no ``ENTRY`` line."""
+    entry_start = entry_end = None
+    for i, ln in enumerate(all_lines):
+        if entry_start is None:
+            if "ENTRY" in ln:
+                entry_start = i
+        elif ln.rstrip() == "}":
+            entry_end = i
+            break
+    if entry_start is None:
+        return 0, len(all_lines)
+    return entry_start, len(all_lines) if entry_end is None else entry_end
+
+
+def topology_devices(name: str):
+    """The devices of a TPU topology that is not there (``"v5e:2x2"``):
+    libtpu builds it with no such hardware present, to compile ahead of
+    time for it."""
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=name).devices
 
 
 def measure(hlo: str, n_dev: int, peak: device_peaks.Peak):
@@ -175,19 +202,7 @@ def measure(hlo: str, n_dev: int, peak: device_peaks.Peak):
     # instruction names are only unique per computation, so a body line
     # can be byte-identical to an entry line.
     all_lines = hlo.splitlines()
-    entry_start = entry_end = None
-    for i, ln in enumerate(all_lines):
-        if entry_start is None:
-            if "ENTRY" in ln:
-                entry_start = i
-        elif ln.rstrip() == "}":
-            entry_end = i
-            break
-    if entry_start is None:
-        entry_start = 0
-        entry_end = len(all_lines)
-    elif entry_end is None:
-        entry_end = len(all_lines)
+    entry_start, entry_end = entry_bounds(all_lines)
     lines = [ln.strip()
              for ln in all_lines[entry_start:entry_end] if "=" in ln]
     in_flight: dict = {}   # start-instruction name -> remaining seconds
@@ -195,7 +210,7 @@ def measure(hlo: str, n_dev: int, peak: device_peaks.Peak):
     async_pairs = sync_ars = 0
     for ln in lines:
         lhs, rhs = ln.split("=", 1)
-        op = _opcode(rhs)
+        op = opcode(rhs)
         if op is None:
             continue
         base, kind = _coll_base(op)
@@ -236,7 +251,7 @@ def measure(hlo: str, n_dev: int, peak: device_peaks.Peak):
             continue
         s = ln.strip()
         if "=" in s:
-            op = _opcode(s.split("=", 1)[1])
+            op = opcode(s.split("=", 1)[1])
             if op:
                 base, kind = _coll_base(op)
                 if base in _COLLECTIVE_BASES and kind != "-done":
@@ -273,10 +288,7 @@ def main() -> None:
     if n < 2:
         # One chip: compile ahead of time for an 8-chip topology of the
         # same kind (libtpu builds it with no such hardware present).
-        from jax.experimental import topologies
-
-        devices = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x4").devices
+        devices = topology_devices("v5e:2x4")
         platform, n = devices[0].platform, 8
     peak = device_peaks.peak(
         REHEARSAL_KIND if platform == "cpu" else devices[0].device_kind)
