@@ -6,7 +6,7 @@ the others are Mamba-1 mixers with Jamba's three inner RMSNorms; every
 layer ends in the dense gated feed-forward (``num_experts`` is 1).  This
 file is the serving path's: ``forward`` (the tests' oracle),
 ``prefill_request`` and ``decode_step``, all three built from ONE
-attention function (``layers._attention_no_positions``) and ONE mixer
+attention function (``layers._grouped_attention``) and ONE mixer
 function, each taking optional state in and giving state out.  Training
 it is not supported (the chunked scan has
 no backward pass written for it).
@@ -52,9 +52,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.layers import (_at, _attention_no_positions,
-                                       _causal_conv, _dense_ffn, _logits,
-                                       _put, _rmsnorm)
+from horovod_tpu.models.layers import (_at, _causal_conv, _dense_ffn,
+                                       _grouped_attention, _logits, _put,
+                                       _rmsnorm)
 
 Params = Dict[str, Any]
 State = Dict[str, Tuple[jax.Array, jax.Array]]
@@ -319,13 +319,13 @@ def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
         lp = _at(params["attn"], l)
         y = _rmsnorm(h, lp["ln1"])
         if start:
-            y, (k, v) = _attention_no_positions(y, lp, dtype)
+            y, (k, v) = _grouped_attention(y, lp, dtype)
             if carries:
                 at = (l, 0, 0, 0, 0)
                 kv = (lax.dynamic_update_slice(kv[0], k[None], at),
                       lax.dynamic_update_slice(kv[1], v[None], at))
         else:
-            y, kv = _attention_no_positions(y, lp, dtype, (*kv, l, pos))
+            y, kv = _grouped_attention(y, lp, dtype, (*kv, l, pos))
         h = h + y
         return h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype), kv
 

@@ -6,8 +6,8 @@ model: how a request's state is written over a slot, and how a step moves
 the device counters on.
 
 The model files (``transformer``, ``jamba``, ``latent_moe``,
-``retention``, ``ssd_moe``, ``resnet``) import this module and ``experts``
-and never one another; this module imports none of them.
+``retention``, ``ssd_moe``, ``conv_moe``, ``resnet``) import this module
+and ``experts`` and never one another; this module imports none of them.
 """
 
 from __future__ import annotations
@@ -63,25 +63,49 @@ def _dense_ffn(x, lp, dtype):
     return jnp.einsum("bsf,fd->bsd", h, lp["w_out"].astype(dtype))
 
 
-def _attention_no_positions(x, lp, dtype, cache=None,
-                            heads_first: bool = True):
-    """Grouped-query causal attention without positions (the state-space
-    layers beside it carry order).  x: [B, S, D]; ``lp``: ``wq`` [D, H,
-    HD], ``wk``, ``wv`` [D, KVH, HD], ``wo`` [H, HD, D].
+LAYOUTS = ("heads_first", "positions_first", "merged")
+
+
+def _grouped_attention(x, lp, dtype, cache=None, layout: str = "heads_first",
+                       qk_norm: Optional[float] = None,
+                       rope: Optional[float] = None):
+    """Grouped-query causal attention.  x: [B, S, D]; ``lp``: ``wq`` [D, H,
+    HD], ``wk``, ``wv`` [D, KVH, HD], ``wo`` [H, HD, D].  With neither
+    ``qk_norm`` nor ``rope`` it applies no positions (state-space layers
+    beside it carry order).
 
     ``cache`` None: the S positions attend among themselves; returns
-    (out, (k, v)) with k, v [B, KVH, S, HD] for whoever keeps them.
-    ``cache`` = (ks, vs, layer, pos), stacked caches [La, B, KVH, Smax,
-    HD] and per-slot positions [B] of THIS token (S = 1): writes the B
-    new rows at [layer, b, :, pos[b]] in place and attends lane
-    ``layer`` up to ``pos``; returns (out, (ks, vs)).
+    (out, (k, v)) with k, v as ``layout`` holds a request's rows, for
+    whoever keeps them.  ``cache`` = (ks, vs, layer, pos), stacked caches
+    and per-slot positions [B] of THIS token (S = 1): writes the B new
+    rows at ``pos[b]`` of lane [layer, b] in place and attends that lane
+    up to ``pos``; returns (out, (ks, vs)).
 
-    ``heads_first`` False: k, v and the caches hold the positions ahead of
-    the heads, [B, S, KVH, HD] and [La, B, Smax, KVH, HD].  With more than
-    one key/value head the chip writes a step's rows into such a cache in
-    place, and copies a heads-first one whole, there and back, around the
-    scatter (the step compiled for ``v5e`` and traced: 4 x 0.6 ms a turn
-    for two lanes of 201 MB; PR 46)."""
+    ``layout``, how k, v and the caches are held (one of ``LAYOUTS``):
+
+    * ``heads_first``: [B, KVH, S, HD] and [La, B, KVH, Smax, HD].
+    * ``positions_first``: [B, S, KVH, HD] and [La, B, Smax, KVH, HD].
+      With more than one key/value head the chip writes a step's rows into
+      such a cache in place, and copies a heads-first one whole, there and
+      back, around the scatter (the step compiled for ``v5e`` and traced:
+      4 x 0.6 ms a turn for two lanes of 201 MB; PR 46).
+    * ``merged``: a position's key/value heads side by side, [B, S, KVH
+      HD] and [La, B, Smax, KVH HD].  The chip pads a last axis under its
+      128 lanes: caches [.., 8, 64] compiled for ``v5e`` take twice their
+      bytes and the step copies them whole (PR 49).  A step multiplies a
+      lane as it lies: each query row holds its values in its own head's
+      HD of the KVH HD and zeros in the others', and of the product's KVH
+      HD it keeps those.
+
+    ``qk_norm``: the epsilon of an RMSNorm over each head's HD values of q
+    and of k, gains ``lp["q_norm"]`` and ``lp["k_norm"]`` [HD] shared by
+    the heads, between the projection and the scores.  ``rope``: the base
+    of a rotation (:func:`_rope`, the halves' pairing) of q and k after
+    that norm, at positions 0..S-1 or, against a cache, at each slot's
+    ``pos``; the keys are kept rotated."""
+    if layout not in LAYOUTS:
+        raise ValueError(f"layout {layout!r}: one of {LAYOUTS}")
+    heads_first, merged = layout == "heads_first", layout == "merged"
     B, S, _ = x.shape
     KVH, HD = lp["wk"].shape[-2:]
     G = lp["wq"].shape[-2] // KVH
@@ -90,14 +114,31 @@ def _attention_no_positions(x, lp, dtype, cache=None,
     q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dtype))
     k = jnp.einsum(f"bsd,dhk->{kept_as}", x, lp["wk"].astype(dtype))
     v = jnp.einsum(f"bsd,dhk->{kept_as}", x, lp["wv"].astype(dtype))
+    if qk_norm is not None:
+        q = _rmsnorm(q, lp["q_norm"], qk_norm)
+        k = _rmsnorm(k, lp["k_norm"], qk_norm)
+    if rope is not None:
+        at = None if cache is None else cache[3][:, None]
+        q = _rope(q, rope, at)
+        k = _rope(k.swapaxes(1, 2), rope, at).swapaxes(1, 2) \
+            if heads_first else _rope(k, rope, at)
     q = q.reshape(B, S, KVH, G, HD)
+    own = None
     if cache is None:
         keys, values, kept = k, v, (k, v)
+        if merged:
+            kept = (k.reshape(B, S, KVH * HD), v.reshape(B, S, KVH * HD))
         valid = jnp.tril(jnp.ones((S, S), jnp.bool_))[None]    # [1, S, T]
     else:
         ks, vs, layer, pos = cache
         rows = jnp.arange(B)
-        if heads_first:
+        if merged:
+            ks = ks.at[layer, rows, pos].set(k[:, 0].reshape(B, KVH * HD))
+            vs = vs.at[layer, rows, pos].set(v[:, 0].reshape(B, KVH * HD))
+            lane = "btd"
+            own = jnp.eye(KVH, dtype=dtype)[:, None, :, None]
+            q = (q[..., None, :] * own).reshape(B, S, KVH, G, KVH * HD)
+        elif heads_first:
             ks = ks.at[layer, rows, :, pos].set(k[:, :, 0])
             vs = vs.at[layer, rows, :, pos].set(v[:, :, 0])
         else:
@@ -113,6 +154,8 @@ def _attention_no_positions(x, lp, dtype, cache=None,
     logits = jnp.where(valid[:, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
     ctx = jnp.einsum(f"bkgst,{lane}->bskgd", probs, values)
+    if own is not None:
+        ctx = jnp.sum(ctx.reshape(B, S, KVH, G, KVH, HD) * own, axis=4)
     ctx = ctx.reshape(B, S, KVH * G, HD)
     return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
 

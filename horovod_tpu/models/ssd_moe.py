@@ -40,7 +40,7 @@ scan nor the grouped product has a backward pass written for it).
   state (tests/test_ssd_moe.py).  The decays, ``softplus``, the state and
   every accumulation are float32; the products' operands are
   ``compute_dtype``.
-* ``*``: ``layers._attention_no_positions`` (``num_attention_heads`` on
+* ``*``: ``layers._grouped_attention`` (``num_attention_heads`` on
   ``num_key_value_heads`` of ``head_dim``: wider together than
   ``hidden_size``), its caches with the positions ahead of the two
   key/value heads.
@@ -94,9 +94,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.models import experts
-from horovod_tpu.models.layers import (_at, _attention_no_positions,
-                                       _causal_conv, _logits, _put, _rmsnorm,
-                                       add_counters)
+from horovod_tpu.models.layers import (_at, _causal_conv,
+                                       _grouped_attention, _logits, _put,
+                                       _rmsnorm, add_counters)
 from horovod_tpu.ops import pallas_ssd
 
 Params = Dict[str, Any]
@@ -467,15 +467,15 @@ def _stack(params: Params, x, cfg: SsdMoEConfig, state: Optional[State],
             lp = _at(params["attn"], l)
             y = _rmsnorm(x, lp["ln"], eps)
             if start:
-                y, (k, v) = _attention_no_positions(y, lp, dtype,
-                                                    heads_first=False)
+                y, (k, v) = _grouped_attention(y, lp, dtype,
+                                               layout="positions_first")
                 if carries:
                     at = (l, 0, 0, 0, 0)
                     kv = (lax.dynamic_update_slice(kv[0], k[None], at),
                           lax.dynamic_update_slice(kv[1], v[None], at))
             else:
-                y, kv = _attention_no_positions(
-                    y, lp, dtype, (*kv, l, pos), heads_first=False)
+                y, kv = _grouped_attention(
+                    y, lp, dtype, (*kv, l, pos), layout="positions_first")
         else:
             lp = _at(small, l)
             u = _rmsnorm(x, lp["ln"], eps)

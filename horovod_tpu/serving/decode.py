@@ -109,6 +109,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from horovod_tpu.models import conv_moe as C
 from horovod_tpu.models import jamba as J
 from horovod_tpu.models import latent_moe as X
 from horovod_tpu.models import retention as R
@@ -173,7 +174,7 @@ class SlotModel(NamedTuple):
 # cfg)`` (the form the engine holds the parameters in: without it, as
 # given).  Its device counters are declared in ``telemetry/registry.py``.
 MODELS = {T.TransformerConfig: T, J.JambaConfig: J, X.LatentMoEConfig: X,
-          R.RetentionConfig: R, S.SsdMoEConfig: S}
+          R.RetentionConfig: R, S.SsdMoEConfig: S, C.ConvMoEConfig: C}
 
 
 def slot_model(cfg, cache_len: int, mesh=None) -> SlotModel:

@@ -172,6 +172,17 @@ SERVE_SSD = dict(slots=96, vocab_size=1024, num_hidden_layers=9,
                  max_seq_len=4096)
 
 
+# The lfm2-8b-a1b cell's state as it serves it (192 slots x 2048; key/value
+# lanes of 8 heads x 64 held side by side, 512 a position; a window of two
+# rows of 2048 a short-convolution layer) at the published operator widths,
+# with seven layers (one dense, two of them attention), eight experts and a
+# narrow vocabulary, so that the probe compiles in seconds.
+SERVE_CONV = dict(slots=192, vocab_size=1024, num_hidden_layers=7,
+                  layer_types=("conv", "conv", "full_attention", "conv",
+                               "conv", "conv", "full_attention"),
+                  num_dense_layers=1, num_experts=8, max_seq_len=2048)
+
+
 # Sequence lengths at which the attention kernel's gradient is compiled
 # alone: the training cell's (four blocks of 512 a row) and one whose block
 # falls under the chip's 128 lanes (2112 = 33 x 64).
@@ -256,7 +267,8 @@ def probe_lower_for_tpu(meshes_json):
     (:func:`serve_cache_programs`), for the dense decoder's cache and for
     models/jamba.py's two kinds of state, for models/latent_moe.py's
     latent lanes, for models/retention.py's state matrices and for
-    models/ssd_moe.py's states, lanes and padded expert stacks.  One
+    models/ssd_moe.py's states, lanes and padded expert stacks, and for
+    models/conv_moe.py's merged lanes and windows.  One
     process for everything compiled
     for the chip (libtpu's lockfile); the compiles run in threads, XLA
     works outside the interpreter lock."""
@@ -267,7 +279,8 @@ def probe_lower_for_tpu(meshes_json):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.models import jamba, latent_moe, retention, ssd_moe
+    from horovod_tpu.models import (conv_moe, jamba, latent_moe, retention,
+                                    ssd_moe)
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import mesh as mesh_mod
     from horovod_tpu.parallel import train as train_mod
@@ -310,6 +323,9 @@ def probe_lower_for_tpu(meshes_json):
     ssd_sizes = dict(SERVE_SSD)
     ssd_slots = ssd_sizes.pop("slots")
     mcfg = ssd_moe.SsdMoEConfig(**ssd_sizes)
+    conv_sizes = dict(SERVE_CONV)
+    conv_slots = conv_sizes.pop("slots")
+    ccfg = conv_moe.ConvMoEConfig(**conv_sizes)
     one_chip = SingleDeviceSharding(topo.devices[0])
     # Fewer threads than submissions: the later compiles take the threads
     # that fall free, so that the probe loads the machine no more than
@@ -341,6 +357,11 @@ def probe_lower_for_tpu(meshes_json):
             serve_cache_programs, mcfg, ssd_slots,
             # one layer's recurrent state: [slots, heads, head_dim, state]
             ssd_slots * mcfg.d_inner * mcfg.ssm_state_size, one_chip)
+        serve_conv = pool.submit(
+            serve_cache_programs, ccfg, conv_slots,
+            # one layer's lane: [slots, cache_len, KVH x head_dim]
+            conv_slots * ccfg.max_seq_len * ccfg.num_key_value_heads
+            * ccfg.head_dim, one_chip)
         flash_alone = [pool.submit(flash_grad_calls, seq_len, one_chip)
                        for seq_len in FLASH_ALONE]
         flash_layout = pool.submit(flash_step_layout_copies, topo.devices[0])
@@ -353,6 +374,7 @@ def probe_lower_for_tpu(meshes_json):
         "serve_sparse": serve_sparse.result(),
         "serve_retention": serve_retention.result(),
         "serve_ssd": serve_ssd.result(),
+        "serve_conv": serve_conv.result(),
         "flash_alone": [calls.result() for calls in flash_alone],
         "flash_layout": flash_layout.result(),
         "tpu_custom_call": [n for n, _ in found],

@@ -456,6 +456,35 @@ def test_ssd_decode_step_is_one_kernel_a_mamba_layer_on_the_chip(probes):
     assert set(by_op) == {"fusion:scatter"}, got
 
 
+@pytest.mark.parametrize("program,updates", [
+    ("step", {"fusion:scatter"}),
+    ("install", {"fusion:dynamic-update-slice", "dynamic-update-slice"})])
+def test_conv_moe_lanes_update_in_place_on_the_chip(probes, program, updates):
+    """models/conv_moe.py's state, compiled for ``v5e`` at the benchmark
+    cell's lanes (192 slots x 2048 positions x 8 heads of 64 held side by
+    side, 512 a position): the step and the install produce nothing of one
+    layer's lane's size besides the in-place updates of the lanes they
+    were given (a row scatter a cache; an update-slice a leaf) and the
+    compiler's own asynchronous moves.  Held ``[.., 8, 64]`` the last axis
+    is padded to the chip's 128 lanes, the lanes take twice their bytes
+    and the step copies both whole (2.25 GB each at the cell's depth: it
+    does not fit the chip; PR 49).  Every state array and the six counters
+    are aliased from input to output and the temporaries stay under one
+    layer's lane: the attention's two products read a lane where it
+    lies."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    got = json.loads(out.split("RESULT", 1)[1])["serve_conv"][program]
+    c = chip_probes.SERVE_CONV
+    lane_bytes = 2 * c["slots"] * c["max_seq_len"] * 8 * 64
+    held = (2 * 2 * lane_bytes + 5 * 2 * 2 * c["slots"] * 2048 + 6 * 512)
+    prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
+    assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
+    assert {op for _, op in got["big_ops"]} & updates, got
+    assert got["temp_bytes"] < lane_bytes, got
+    assert got["alias_bytes"] == held, got
+
+
 @pytest.mark.parametrize("probe", ["serve_cache", "serve_state",
                                    "serve_latent", "serve_retention"])
 def test_serving_step_compiled_for_the_chip_converts_no_weight(probes, probe):

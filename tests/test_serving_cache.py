@@ -46,8 +46,8 @@ And the names of the three programs an engine compiles (``serve_step``,
 line shows), with the programs otherwise what the unnamed functions lower
 to.
 
-And the seam itself, over one small config of each of the six served
-forms (``FORMS``): the lowered text of five forms' step and prefill as
+And the seam itself, over one small config of each of the seven served
+forms (``FORMS``): the lowered text of six forms' step and prefill as
 sha256 digests, four of them taken before the install, the counter merge and the shared
 layer code moved to ``models/layers.py``; the ONE install's contract (the
 slot whole, every other slot and the counters untouched, nothing left of
@@ -71,8 +71,8 @@ import pytest
 
 from chip_probes import (DENSE_CAST_LEAVES, JAMBA_CAST_LEAVES, converts_to,
                          dims_key, serve_cache_programs, weight_dims)
-from horovod_tpu.models import (jamba, latent_moe, layers, retention,
-                                ssd_moe)
+from horovod_tpu.models import (conv_moe, jamba, latent_moe, layers,
+                                retention, ssd_moe)
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import pallas_decode_attention as pda
 from horovod_tpu.parallel.mesh import make_mesh, sharding_for
@@ -550,14 +550,16 @@ def test_model_module_presents_the_seam_the_one_builder_takes(make):
             == jax.tree.map(lambda a: (a.shape, a.dtype), state))
 
 
-# -- the seam over the six served forms ----------------------------------------
+# -- the seam over the seven served forms --------------------------------------
 #
 # One small config a served form, the fixtures' widths of tests/test_jamba.py,
 # test_latent_moe.py, test_sparse_latent_moe.py and test_retention.py in the
 # published types: each takes every branch of its form (attention and Mamba
 # runs; dense and expert layers; the indexer, a share of the experts, grouped
 # routing and YaRN; the retention's prompt blocks; the three layer kinds of
-# tests/test_ssd_moe.py's pattern with a share of its experts).
+# tests/test_ssd_moe.py's pattern with a share of its experts; both operator
+# kinds over a dense layer then expert layers of tests/test_conv_moe.py, the
+# experts' stacks held wider than published).
 
 YARN = {"type": "yarn", "factor": 40, "beta_fast": 32, "beta_slow": 1,
         "original_max_position_embeddings": 16, "mscale": 1.0,
@@ -596,11 +598,17 @@ FORMS = {
         mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
         moe_intermediate_size=24, moe_shared_expert_intermediate_size=48,
         n_routed_experts=8, num_experts_per_tok=3, max_seq_len=64,
-        experts_held=4, expert_first=4)}
+        experts_held=4, expert_first=4),
+    "lfm2-8b-a1b": conv_moe.ConvMoEConfig(
+        vocab_size=96, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=24, num_hidden_layers=9,
+        layer_types=("conv",) + ("conv", "conv", "full_attention", "conv") * 2,
+        num_dense_layers=1, num_attention_heads=4, num_key_value_heads=2,
+        num_experts=8, num_experts_per_tok=3, max_seq_len=64)}
 SLOTS, CACHE_LEN, PROMPT = 4, 64, 24
 
 # sha256 of the lowered text (StableHLO, no locations; jax 0.9.0) of the
-# engine's step and prefill (PROMPT tokens) for five of FORMS through
+# engine's step and prefill (PROMPT tokens) for six of FORMS through
 # ``decode.slot_model``, as tests/test_pallas_attention.py pins the dense
 # decoder's four, taken on the commit before the install, the counter merge,
 # ``_logits`` and the shared layer code moved to models/layers.py
@@ -630,7 +638,12 @@ LOWERED_BEFORE = {
     "nemotron-3-nano-30b-a3b:serve_step":
         "3b5dba2c0f16834aa96e299e207748a867a9869ee09142cf4c51b110d11e99a3",
     "nemotron-3-nano-30b-a3b:serve_prefill":
-        "ebe7c2a04f1496c883279d7699db55dffcf38d5f82a32bdf60e42989a289f5c2"}
+        "ebe7c2a04f1496c883279d7699db55dffcf38d5f82a32bdf60e42989a289f5c2",
+    # taken on PR 49's finished change, with its cell's numbers (PERF.md)
+    "lfm2-8b-a1b:serve_step":
+        "da0e896ffb23a98f263e5c3861af7c54ec6879ae1f2572c5e7a0dce97d9dedac",
+    "lfm2-8b-a1b:serve_prefill":
+        "aa8c19b546520481d1730e69701e083286d56b2d80ef4536b33ebf0df9c66ea4"}
 
 
 @pytest.mark.parametrize("which", list(LOWERED_BEFORE))
@@ -754,7 +767,7 @@ def test_model_files_import_no_other_and_the_server_names_no_counter():
 
     pkg = Path(decode.__file__).resolve().parent.parent
     models = ("transformer", "jamba", "latent_moe", "retention", "ssd_moe",
-              "resnet")
+              "conv_moe", "resnet")
     for name in models + ("layers", "experts"):
         tree = ast.parse((pkg / "models" / f"{name}.py").read_text())
         imported = set()
