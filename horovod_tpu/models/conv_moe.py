@@ -38,7 +38,11 @@ product has no backward pass written for it).
   selects only, normalised weights) and ``experts.routed_ffn`` in its
   gated form over ALL ``num_experts`` experts, no shared one.  The stacks
   are held ``experts.padded_width(moe_intermediate_size)`` wide (zeros
-  past the published width: whole tiles of the grouped product).
+  past the published width: whole tiles of the grouped product); with 8
+  (row, expert) pairs an expert or more, up to 1024 pairs (a served
+  batch's step, a short prompt), the three products of a layer run as one
+  kernel that stops at the published width
+  (``ops/pallas_routed_ffn.py``; ``experts.one_kernel``).
 * The stack: layers of a kind are stacked on a leading axis
   (``params["conv"]``, ``params["attn"]``, ``params["dense"]``,
   ``params["moe"]``; each holds the gain of the norm ahead of it) and
@@ -139,7 +143,7 @@ class ConvMoEConfig:
 
 def counter_names(cfg: ConvMoEConfig) -> Tuple[str, ...]:
     """The device counters ``cfg``'s state holds."""
-    return experts.MOE_COUNTERS + ATTN_COUNTERS
+    return experts.MOE_COUNTERS + (experts.FUSED_COUNTER,) + ATTN_COUNTERS
 
 
 # ---------------------------------------------------------------------------
@@ -310,8 +314,9 @@ def _stack(params: Params, x, cfg: ConvMoEConfig, state: Optional[State],
                 rows, lp["router"], lp["router_bias"],
                 cfg.num_experts_per_tok, cfg.routed_scaling_factor)
             with jax.named_scope("routed_ffn"):
-                y, new = experts.routed_ffn(rows, routed, l, chosen, weights,
-                                            dtype, live)
+                y, new = experts.routed_ffn(
+                    rows, routed, l, chosen, weights, dtype, live,
+                    width=cfg.moe_intermediate_size)
             stats = stats + new
             y = y.reshape(B, S, D)
         x = x + y
@@ -351,9 +356,13 @@ def decode_step(params: Params, tok, pos, state: State, cfg: ConvMoEConfig):
     depends on its own state alone."""
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
     x, slots, stats = _stack(params, x, cfg, state, pos)
-    counters = add_counters(state["counters"], dict(zip(
-        experts.MOE_COUNTERS,
-        (*stats.astype(jnp.uint32), jnp.uint32(cfg.n_layers("moe"))))))
+    turns = cfg.n_layers("moe")
+    fused = experts.one_kernel(
+        params["moe"], tok.shape[0] * cfg.num_experts_per_tok)
+    counters = add_counters(state["counters"], {
+        **dict(zip(experts.MOE_COUNTERS,
+                   (*stats.astype(jnp.uint32), jnp.uint32(turns)))),
+        experts.FUSED_COUNTER: jnp.uint32(turns if fused else 0)})
     counters = count_attention_reads(
         counters, pos, state["kv"][0].shape[2], cfg.n_layers("attn"), None)
     return (_logits(x, params["ln_f"], params["embed"], cfg.norm_eps)[:, 0],

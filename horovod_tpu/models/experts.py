@@ -12,13 +12,29 @@ for a serving turn's few dozen:
    size, a group's score is the sum of its two largest ``s + b``, and
    only the ``topk_group`` best groups' experts stand for the top-k;
 2. the ``rows x k`` (row, expert) pairs are sorted by expert, the rows
-   gathered in that order, and ``jax.lax.ragged_dot`` products run over
-   the groups, in the expert's own form: with a ``w_gate`` stack three
+   gathered in that order, and each expert's rows go through its
+   matrices, in the expert's own form: with a ``w_gate`` stack three
    (``w_out (w_in x * silu(w_gate x))``), without one two
    (``w_out relu(w_in x)^2``).  An expert with no row costs nothing, one
    with many rows gets them all.  **No capacity, no dropped row, no
    auxiliary loss**, so a row's output depends on that row alone: what a
-   served slot returns never depends on its neighbours;
+   served slot returns never depends on its neighbours.  Two forms of
+   the same products, chosen from shapes alone (:func:`one_kernel`):
+
+   * ``jax.lax.ragged_dot`` over the groups, a call a matrix, the
+     product between them through main memory: a few rows an expert (a
+     walk over ALL experts would fetch blocks for the many that have no
+     row), a prompt's thousands of rows, a share of the experts, experts
+     without a gate;
+   * ONE kernel a layer, ``ops/pallas_routed_ffn.py:routed_ffn_rows``:
+     gated experts all held (``first`` None), ``MANY_ROWS`` (8) pairs an
+     expert or more, so that a turn touches every expert, and at most
+     ``RESIDENT_ROWS`` (1024) pairs, which stay in fast memory with their
+     float32 result while each expert's three matrices stream past once,
+     up to the published ``width``.  ``lfm2-8b-a1b``'s step (192 slots x 4
+     over 32 experts) and its prompts of 128 and 256 tokens; not
+     ``glm-4.7-flash`` (4 pairs an expert), ``deepseek-v3.2`` (a share) or
+     ``nemotron-3-nano-30b-a3b`` (a share, no gate);
 3. the results go back to their rows with their weights.
 
 The experts' weights arrive stacked over LAYERS as well, ``[L, E, D, F]``,
@@ -26,7 +42,8 @@ with the layer a traced index: the groups are laid over the ``L x E``
 leading axis (a free reshape) and only layer ``l``'s are non-empty, so a
 layer loop with a dynamic index never cuts a layer's 1.2 GB of experts
 out of their stack (a grouped product is a custom call and a slice in
-front of it is a copy).
+front of it is a copy); the kernel addresses layer and expert in its
+index maps.
 
 Rows marked not ``live`` (a serving batch's free slots) are routed
 nowhere: they sort behind the last group, no expert's weights are read
@@ -59,6 +76,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.ops.pallas_routed_ffn import routed_ffn_rows
+
 
 # What a model's decode step adds to its state's ``"counters"`` from
 # ``routed_ffn``'s stats, over its expert layers: (row, expert) pairs
@@ -68,6 +87,9 @@ from jax import lax
 MOE_COUNTERS = ("hvd_moe_rows_routed_total", "hvd_moe_experts_touched_total",
                 "hvd_moe_max_expert_rows_total", "hvd_moe_layer_turns_total")
 ABSENT_COUNTER = "hvd_moe_rows_absent_total"
+# Of the expert layers stepped, those whose products ran as the one kernel
+# (:func:`one_kernel`): a model that can take it counts them.
+FUSED_COUNTER = "hvd_moe_fused_layer_turns_total"
 
 
 # The grouped product's tiles: a stack axis that is not whole tiles makes
@@ -75,6 +97,14 @@ ABSENT_COUNTER = "hvd_moe_rows_absent_total"
 # 63 experts, bytes of the touched experts a second: [2688, 1856] 118 GB/s,
 # [3072, 2048] 561; 6144 rows: 179 and 269; PR 46, TPU v5 lite).
 TILE = 512
+
+# Step 2 runs as the one kernel (``ops/pallas_routed_ffn.py``) from
+# ``MANY_ROWS`` pairs an expert of the stack on, where a turn touches every
+# expert, up to the ``RESIDENT_ROWS`` pairs whose rows [pairs, D] and
+# float32 result the kernel keeps in fast memory beside its weight tiles
+# (at D 2048: 4 + 8 of v5e's 128 MiB).
+MANY_ROWS = 8
+RESIDENT_ROWS = 1024
 
 
 def padded_width(n: int) -> int:
@@ -107,9 +137,18 @@ def route(x, router, bias, top_k: int, scale: float, n_group: int = 1,
     return chosen, weights
 
 
+def one_kernel(experts, pairs: int, first: Optional[int] = None) -> bool:
+    """Whether :func:`routed_ffn` runs step 2 of ``pairs`` (row, expert)
+    pairs as the one kernel: from shapes alone (see the module
+    docstring)."""
+    return ("w_gate" in experts and first is None
+            and MANY_ROWS * experts["w_in"].shape[1] <= pairs
+            <= RESIDENT_ROWS)
+
+
 def routed_ffn(x, experts, layer, chosen, weights, dtype,
                live: Optional[jax.Array] = None,
-               first: Optional[int] = None):
+               first: Optional[int] = None, width: Optional[int] = None):
     """x: [T, D]; ``experts``: ``w_in`` [L, E, D, F], ``w_out`` [L, E, F,
     D] and, for gated experts, ``w_gate`` [L, E, D, F] (without it an
     expert is ``w_out relu(w_in x)^2``), ``D`` and ``F`` as published or
@@ -117,7 +156,9 @@ def routed_ffn(x, experts, layer, chosen, weights, dtype,
     (may be traced); ``chosen``, ``weights``: [T, k] from :func:`route`;
     ``live``: [T] bool or None (all); ``first``: None where the stack holds
     every expert the router chooses among, else the router's output that the
-    stack's expert 0 answers to (a share: ``first`` to ``first + E``).
+    stack's expert 0 answers to (a share: ``first`` to ``first + E``);
+    ``width`` (static): the published ``F`` where the stacks are held
+    wider, which the kernel does not read past (None: as held).
     Returns (y [T, D] in ``dtype``, stats [3])."""
     T, k = chosen.shape
     L, E = experts["w_in"].shape[:2]
@@ -129,7 +170,10 @@ def routed_ffn(x, experts, layer, chosen, weights, dtype,
         flat = jnp.where(jnp.repeat(live, k), flat, E)     # behind every group
     order = jnp.argsort(flat, stable=True)                 # pairs by expert
     counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
-    groups = lax.dynamic_update_slice(
+    fused = one_kernel(experts, T * k, first)
+    # (made here, ahead of the gather, as before the kernel: the order is
+    # part of the lowered text that the other callers' digests pin)
+    groups = None if fused else lax.dynamic_update_slice(
         jnp.zeros((L * E,), jnp.int32), counts, (layer * E,))
     xs = x.astype(dtype)[order // k]                       # [T k, D]
     # A stack may be held with zero rows and columns up to whole tiles of
@@ -140,16 +184,22 @@ def routed_ffn(x, experts, layer, chosen, weights, dtype,
     if held != D:
         xs = jnp.pad(xs, ((0, 0), (0, held - D)))
 
-    def grouped(rows, w):
-        return lax.ragged_dot(
-            rows, w.reshape((L * E,) + w.shape[2:]).astype(dtype), groups)
-
-    if "w_gate" in experts:
-        h = grouped(xs, experts["w_in"]) * jax.nn.silu(
-            grouped(xs, experts["w_gate"]))
+    if fused:
+        ys = routed_ffn_rows(xs, counts, layer, *(
+            experts[w].astype(dtype) for w in ("w_in", "w_gate", "w_out")),
+            width=width)
     else:
-        h = jnp.square(jax.nn.relu(grouped(xs, experts["w_in"])))
-    ys = grouped(h, experts["w_out"])
+        def grouped(rows, w):
+            return lax.ragged_dot(
+                rows, w.reshape((L * E,) + w.shape[2:]).astype(dtype),
+                groups)
+
+        if "w_gate" in experts:
+            h = grouped(xs, experts["w_in"]) * jax.nn.silu(
+                grouped(xs, experts["w_gate"]))
+        else:
+            h = jnp.square(jax.nn.relu(grouped(xs, experts["w_in"])))
+        ys = grouped(h, experts["w_out"])
     if held != D:
         ys = ys[:, :D]
     # Back to (row, choice) order.  Pairs behind the last group were in

@@ -224,6 +224,12 @@ KNOWN_METRICS: Dict[str, dict] = {
         "touched, the straggler a grouped product waits for."),
     "hvd_moe_layer_turns_total": _counter(
         "Expert layers stepped: expert layers x decode steps."),
+    "hvd_moe_fused_layer_turns_total": _counter(
+        "Of the expert layers stepped, those whose three products ran as "
+        "the one kernel (ops/pallas_routed_ffn.py; models/experts.py: "
+        "one_kernel, by shapes alone).  Over hvd_moe_layer_turns_total: "
+        "1.0 where the served batch has 8 pairs an expert or more, and "
+        "not held by a model whose experts never take the kernel."),
     "hvd_moe_rows_absent_total": _counter(
         "(row, expert) pairs of live rows whose expert this chip does not "
         "hold (models/latent_moe.py with experts_held: a share of the "

@@ -468,7 +468,7 @@ def test_conv_moe_lanes_update_in_place_on_the_chip(probes, program, updates):
     compiler's own asynchronous moves.  Held ``[.., 8, 64]`` the last axis
     is padded to the chip's 128 lanes, the lanes take twice their bytes
     and the step copies both whole (2.25 GB each at the cell's depth: it
-    does not fit the chip; PR 49).  Every state array and the six counters
+    does not fit the chip; PR 49).  Every state array and the seven counters
     are aliased from input to output and the temporaries stay under one
     layer's lane: the attention's two products read a lane where it
     lies."""
@@ -477,12 +477,35 @@ def test_conv_moe_lanes_update_in_place_on_the_chip(probes, program, updates):
     got = json.loads(out.split("RESULT", 1)[1])["serve_conv"][program]
     c = chip_probes.SERVE_CONV
     lane_bytes = 2 * c["slots"] * c["max_seq_len"] * 8 * 64
-    held = (2 * 2 * lane_bytes + 5 * 2 * 2 * c["slots"] * 2048 + 6 * 512)
+    held = (2 * 2 * lane_bytes + 5 * 2 * 2 * c["slots"] * 2048 + 7 * 512)
     prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
     assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
     assert {op for _, op in got["big_ops"]} & updates, got
     assert got["temp_bytes"] < lane_bytes, got
     assert got["alias_bytes"] == held, got
+
+
+@pytest.mark.parametrize("probe,fused", [
+    ("serve_conv", True), ("serve_latent", True), ("serve_sparse", False),
+    ("serve_ssd", False)])
+def test_routed_experts_take_the_one_kernel_by_shape_on_the_chip(
+        probes, probe, fused):
+    """The decode steps that call ``experts.routed_ffn``, compiled for
+    ``v5e``: at the ``lfm2-8b-a1b`` cell's 192 slots x 4 pairs over 8
+    gated experts all held (24 an expert and more: ``experts.one_kernel``)
+    Mosaic takes ``routed_ffn_rows`` at the published widths (2048 x 1792
+    of stacks held 2048 wide) and no grouped product is left.  The rule
+    reads shapes, not models: ``latent_moe``'s probe has the
+    ``glm-4.7-flash`` cell's 64 slots x 4 pairs over EIGHT experts where
+    the cell has 64 (32 an expert, not 4), so it takes the kernel too, 256
+    columns wide; a share of the experts and relu2 experts keep their
+    grouped products and have no such call."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    kernels = json.loads(out.split("RESULT", 1)[1])[probe]["step"]["kernels"]
+    assert ("routed_ffn_rows" in kernels) is fused, kernels
+    assert any(k.startswith("ragged-dot") for k in kernels) is not fused, \
+        kernels
 
 
 @pytest.mark.parametrize("probe", ["serve_cache", "serve_state",

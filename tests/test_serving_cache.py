@@ -639,11 +639,14 @@ LOWERED_BEFORE = {
         "3b5dba2c0f16834aa96e299e207748a867a9869ee09142cf4c51b110d11e99a3",
     "nemotron-3-nano-30b-a3b:serve_prefill":
         "ebe7c2a04f1496c883279d7699db55dffcf38d5f82a32bdf60e42989a289f5c2",
-    # taken on PR 49's finished change, with its cell's numbers (PERF.md)
+    # taken on PR 50's finished change, with its cell's numbers (PERF.md):
+    # the step holds ``hvd_moe_fused_layer_turns_total`` (its 12 pairs over
+    # 8 experts keep the grouped products), the 24-token prompt's 72 pairs
+    # take the one kernel ``routed_ffn_rows``
     "lfm2-8b-a1b:serve_step":
-        "da0e896ffb23a98f263e5c3861af7c54ec6879ae1f2572c5e7a0dce97d9dedac",
+        "75c6bb3dbe6c582dd3c61a7fd1c694c3f3fb7b7867bdde1ef2496d0b419acf67",
     "lfm2-8b-a1b:serve_prefill":
-        "aa8c19b546520481d1730e69701e083286d56b2d80ef4536b33ebf0df9c66ea4"}
+        "21241a0a51988e5be6fca07097ed110c6015e702addd4789e68a97de5f2d5f76"}
 
 
 @pytest.mark.parametrize("which", list(LOWERED_BEFORE))
