@@ -234,21 +234,22 @@ def _bn(x, p, s, train: bool):
     10 (PERF.md section 6, PR 48), and the clamp keeps rounding from
     handing ``rsqrt`` a negative number (``tests/test_models.py`` holds
     all of it to a float64 reference)."""
-    if train:
-        xf = x.astype(jnp.float32)
-        mean = jnp.mean(xf, axis=(0, 1, 2))
-        var = jnp.maximum(
-            jnp.mean(xf * xf, axis=(0, 1, 2)) - mean * mean, 0.0)
-        new_s = {
-            "mean": _BN_MOMENTUM * s["mean"] + (1 - _BN_MOMENTUM) * mean,
-            "var": _BN_MOMENTUM * s["var"] + (1 - _BN_MOMENTUM) * var,
-        }
-    else:
-        mean, var = s["mean"], s["var"]
-        new_s = s
-    inv = lax.rsqrt(var + _BN_EPS) * p["scale"]
-    shift = p["bias"] - mean * inv
-    y = x * inv.astype(x.dtype) + shift.astype(x.dtype)
+    with jax.named_scope("norm"):
+        if train:
+            xf = x.astype(jnp.float32)
+            mean = jnp.mean(xf, axis=(0, 1, 2))
+            var = jnp.maximum(
+                jnp.mean(xf * xf, axis=(0, 1, 2)) - mean * mean, 0.0)
+            new_s = {
+                "mean": _BN_MOMENTUM * s["mean"] + (1 - _BN_MOMENTUM) * mean,
+                "var": _BN_MOMENTUM * s["var"] + (1 - _BN_MOMENTUM) * var,
+            }
+        else:
+            mean, var = s["mean"], s["var"]
+            new_s = s
+        inv = lax.rsqrt(var + _BN_EPS) * p["scale"]
+        shift = p["bias"] - mean * inv
+        y = x * inv.astype(x.dtype) + shift.astype(x.dtype)
     return y, new_s
 
 
@@ -286,17 +287,18 @@ def apply(params: Params, batch_stats: Params, images,
     basic = _is_basic(config)
     new_stats: Params = {}
 
-    if (config.stem_s2d and images.shape[1] % 2 == 0
-            and images.shape[2] % 2 == 0):
-        x = _stem_s2d_conv(images, params["stem_conv"], dtype)
-    else:
-        x = _conv(images, params["stem_conv"], 2, dtype)
-    x, new_stats["stem_bn"] = _bn(
-        x, params["stem_bn"], batch_stats["stem_bn"], train)
-    x = jax.nn.relu(x)
-    x = lax.reduce_window(
-        x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-        [(0, 0), (1, 1), (1, 1), (0, 0)])
+    with jax.named_scope("stem"):
+        if (config.stem_s2d and images.shape[1] % 2 == 0
+                and images.shape[2] % 2 == 0):
+            x = _stem_s2d_conv(images, params["stem_conv"], dtype)
+        else:
+            x = _conv(images, params["stem_conv"], 2, dtype)
+        x, new_stats["stem_bn"] = _bn(
+            x, params["stem_bn"], batch_stats["stem_bn"], train)
+        x = jax.nn.relu(x)
+        x = lax.reduce_window(
+            x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
+            [(0, 0), (1, 1), (1, 1), (0, 0)])
 
     block_fn = _block
     if config.remat:
@@ -310,12 +312,15 @@ def apply(params: Params, batch_stats: Params, images,
         for bi in range(nblocks):
             name = f"stage{si}_block{bi}"
             stride = 2 if (bi == 0 and si > 0) else 1
-            x, new_stats[name] = block_fn(
-                x, params[name], batch_stats[name], stride, basic,
-                train, dtype)
+            # The parameters count stages from 0, the paper from 1.
+            with jax.named_scope(f"stage{si + 1}"):
+                x, new_stats[name] = block_fn(
+                    x, params[name], batch_stats[name], stride, basic,
+                    train, dtype)
 
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
-    logits = x @ params["head_w"] + params["head_b"]
+    with jax.named_scope("head_loss"):
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
+        logits = x @ params["head_w"] + params["head_b"]
     return logits, new_stats
 
 
@@ -323,6 +328,7 @@ def loss_fn(params, batch_stats, images, labels, config: ResNetConfig):
     """Softmax cross-entropy; the synthetic-benchmark objective."""
     logits, new_stats = apply(params, batch_stats, images, config,
                               train=True)
-    logp = jax.nn.log_softmax(logits)
-    loss = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
+    with jax.named_scope("head_loss"):
+        logp = jax.nn.log_softmax(logits)
+        loss = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
     return loss, new_stats

@@ -440,14 +440,16 @@ def _layer(x, lp, cfg: TransformerConfig, mesh=None, cache=None):
     feed-forward, residual.  ``cache`` as :func:`_attention` takes it.
     Returns (x, the experts' auxiliary loss, the keys and values
     :func:`_attention` returns)."""
-    y, kept = _attention(_rmsnorm(x, lp["ln1"]), lp, cfg, mesh, cache)
-    x = _constrain(x + y, ACT_SPEC, mesh)
-    h = _rmsnorm(x, lp["ln2"])
-    if cfg.n_experts:
-        y, aux = _moe_ffn(h, lp, cfg)
-    else:
-        y, aux = _dense_ffn(h, lp, cfg.compute_dtype), 0.0
-    x = _constrain(x + y, ACT_SPEC, mesh)
+    with jax.named_scope("attention"):
+        y, kept = _attention(_rmsnorm(x, lp["ln1"]), lp, cfg, mesh, cache)
+        x = _constrain(x + y, ACT_SPEC, mesh)
+    with jax.named_scope("ffn"):
+        h = _rmsnorm(x, lp["ln2"])
+        if cfg.n_experts:
+            y, aux = _moe_ffn(h, lp, cfg)
+        else:
+            y, aux = _dense_ffn(h, lp, cfg.compute_dtype), 0.0
+        x = _constrain(x + y, ACT_SPEC, mesh)
     return x, aux, kept
 
 
@@ -474,8 +476,9 @@ def apply(params: Params, tokens, cfg: TransformerConfig,
     if remat is None:
         remat = cfg.remat
     dtype = cfg.compute_dtype
-    x = params["embed"].astype(dtype)[tokens]
-    x = _constrain(x, ACT_SPEC, mesh)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
+        x = _constrain(x, ACT_SPEC, mesh)
 
     layer_fn = remat_layer() if remat else _layer
 
@@ -486,7 +489,8 @@ def apply(params: Params, tokens, cfg: TransformerConfig,
 
     (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                            params["layers"])
-    return _logits(x, params["ln_f"], params["embed"]), aux
+    with jax.named_scope("head_loss"):
+        return _logits(x, params["ln_f"], params["embed"]), aux
 
 
 def softmax_xent(logits, targets):
@@ -503,7 +507,8 @@ def softmax_xent(logits, targets):
 def loss_fn(params, tokens, targets, cfg: TransformerConfig,
             *, mesh=None, aux_weight: float = 0.01):
     logits, aux = apply(params, tokens, cfg, mesh=mesh)
-    return softmax_xent(logits, targets) + aux_weight * aux
+    with jax.named_scope("head_loss"):
+        return softmax_xent(logits, targets) + aux_weight * aux
 
 
 # ---------------------------------------------------------------------------

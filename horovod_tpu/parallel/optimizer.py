@@ -38,14 +38,16 @@ from horovod_tpu.ops.compression import Compression
 def _allreduce_grads_ingraph(grads, op, axis, compression,
                              hierarchical=False, outer_axis="dcn"):
     # Fuse across leaves: compress first, group by dtype inside
-    # grouped_allreduce, decompress after.
+    # grouped_allreduce, decompress after.  All of it carries the name
+    # ``grad_reduce`` (telemetry/programs.py: the phase ``reduce``).
     leaves, treedef = jax.tree.flatten(grads)
-    comp = [compression.compress(g) for g in leaves]
-    reduced = C.grouped_allreduce([c for c, _ in comp], op=op, axis=axis,
-                                  hierarchical=hierarchical,
-                                  outer_axis=outer_axis)
-    out = [compression.decompress(r, ctx)
-           for r, (_, ctx) in zip(reduced, comp)]
+    with jax.named_scope("grad_reduce"):
+        comp = [compression.compress(g) for g in leaves]
+        reduced = C.grouped_allreduce([c for c, _ in comp], op=op,
+                                      axis=axis, hierarchical=hierarchical,
+                                      outer_axis=outer_axis)
+        out = [compression.decompress(r, ctx)
+               for r, (_, ctx) in zip(reduced, comp)]
     return jax.tree.unflatten(treedef, out)
 
 

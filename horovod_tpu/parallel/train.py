@@ -32,6 +32,7 @@ from horovod_tpu.models import mnist as mnist_model
 from horovod_tpu.models import resnet as resnet_model
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel.mesh import filter_spec
+from horovod_tpu.telemetry import programs
 
 
 def _sharding(mesh, spec: P) -> NamedSharding:
@@ -136,9 +137,10 @@ def make_transformer_train_step(
     def _step(state: TrainState, tokens, targets):
         loss, grads = jax.value_and_grad(tfm.loss_fn)(
             state.params, tokens, targets, cfg, mesh=mesh)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
     if zero_axis is not None:
@@ -147,15 +149,15 @@ def make_transformer_train_step(
         # propagation would otherwise be free to choose).
         rep = NamedSharding(mesh, P())
         state_shardings = TrainState(param_shardings, opt_shardings, rep)
-        step_fn = jax.jit(
-            _step,
+        step_fn = programs.named_jit(
+            _step, "train_step_lm",
             in_shardings=(state_shardings, data_sharding, data_sharding),
             out_shardings=(state_shardings, rep),
             donate_argnums=(0,),
         )
     else:
-        step_fn = jax.jit(
-            _step,
+        step_fn = programs.named_jit(
+            _step, "train_step_lm",
             in_shardings=(None, data_sharding, data_sharding),
             donate_argnums=(0,),
         )
@@ -249,14 +251,15 @@ def make_resnet_train_step(
         (loss, new_stats), grads = jax.value_and_grad(
             resnet_model.loss_fn, has_aux=True)(
                 state.params, state.batch_stats, images, labels, cfg)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return ResNetState(params, new_stats, opt_state,
                            state.step + 1), loss
 
-    step_fn = jax.jit(
-        _step,
+    step_fn = programs.named_jit(
+        _step, "train_step_resnet",
         in_shardings=(None, data_sharding, data_sharding),
         donate_argnums=(0,),
     )
@@ -302,13 +305,15 @@ def make_resnet_train_step_hvd(
         (loss, new_stats), grads = jax.value_and_grad(
             resnet_model.loss_fn, has_aux=True)(
                 state.params, state.batch_stats, images, labels, cfg)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         if axes:
-            new_stats = jax.tree.map(
-                lambda s: C.allreduce(s, axis=axes), new_stats)
-            loss = C.allreduce(loss, axis=axes)
+            with jax.named_scope("stats_reduce"):
+                new_stats = jax.tree.map(
+                    lambda s: C.allreduce(s, axis=axes), new_stats)
+                loss = C.allreduce(loss, axis=axes)
         return ResNetState(params, new_stats, opt_state,
                            state.step + 1), loss
 
@@ -317,7 +322,8 @@ def make_resnet_train_step_hvd(
         in_specs=(P(), batch_p, batch_p),
         out_specs=(P(), P()),
     )
-    step_fn = jax.jit(sharded, donate_argnums=(0,))
+    step_fn = programs.named_jit(sharded, "train_step_resnet_hvd",
+                                 donate_argnums=(0,))
     return step_fn, init_fn
 
 
@@ -337,13 +343,14 @@ def make_mnist_train_step(mesh, optimizer=None):
     def _step(state: TrainState, images, labels):
         loss, grads = jax.value_and_grad(mnist_model.loss_fn)(
             state.params, images, labels)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
-    step_fn = jax.jit(
-        _step,
+    step_fn = programs.named_jit(
+        _step, "train_step_mnist",
         in_shardings=(None, data_sharding, data_sharding),
         donate_argnums=(0,),
     )
