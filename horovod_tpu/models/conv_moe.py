@@ -30,9 +30,12 @@ product has no backward pass written for it).
   on q and k and its rotation, ``num_attention_heads`` on
   ``num_key_value_heads`` of ``hidden_size / num_attention_heads``, the
   caches with a position's key/value heads side by side (a head of 64
-  alone would be padded to the chip's 128 lanes).  A step reads every
-  lane whole under a mask (``ops/pallas_decode_attention.py`` has no
-  form for grouped heads).
+  alone would be padded to the chip's 128 lanes).  A step reads each
+  slot's lanes as far as the slot has written them: ``layers.lane_reader``
+  makes the list of blocks once a step and every attention layer's read is
+  the kernel ``ops/pallas_decode_attention.py:decode_attention`` on the
+  stacked caches as they lie, each query row in its own head's place of a
+  position's ``KVH HD``.
 * Dense feed-forward: ``layers._dense_ffn``, width ``intermediate_size``.
 * Routed feed-forward: ``experts.route`` (sigmoid scores, a bias that
   selects only, normalised weights) and ``experts.routed_ffn`` in its
@@ -79,7 +82,8 @@ from horovod_tpu.models import experts
 from horovod_tpu.models.layers import (ATTN_COUNTERS, _at, _causal_conv,
                                        _dense_ffn, _grouped_attention,
                                        _logits, _put, _rmsnorm,
-                                       add_counters, count_attention_reads)
+                                       add_counters, count_lane_reads,
+                                       lane_reader)
 
 Params = Dict[str, Any]
 State = Dict[str, Any]
@@ -274,6 +278,7 @@ def _stack(params: Params, x, cfg: ConvMoEConfig, state: Optional[State],
     kv = state["kv"] if carries else ()
     (kept,) = state["recurrent"] if carries else (None,)
     live = None if start else pos > 0
+    read = None if start else lane_reader("merged", kv[0], pos)
     B, S, D = x.shape
     small = {k: v for k, v in params["moe"].items() if k not in _EXPERTS}
     routed = {k: params["moe"][k] for k in _EXPERTS}
@@ -293,7 +298,7 @@ def _stack(params: Params, x, cfg: ConvMoEConfig, state: Optional[State],
         else:
             with jax.named_scope("grouped_attention"):
                 y, new = _grouped_attention(
-                    y, lp, dtype, None if start else (*kv, l, pos),
+                    y, lp, dtype, None if start else (*kv, l, pos, read),
                     layout="merged", qk_norm=eps, rope=cfg.rope_theta)
             if not start:
                 kv = new
@@ -363,7 +368,6 @@ def decode_step(params: Params, tok, pos, state: State, cfg: ConvMoEConfig):
         **dict(zip(experts.MOE_COUNTERS,
                    (*stats.astype(jnp.uint32), jnp.uint32(turns)))),
         experts.FUSED_COUNTER: jnp.uint32(turns if fused else 0)})
-    counters = count_attention_reads(
-        counters, pos, state["kv"][0].shape[2], cfg.n_layers("attn"), None)
+    counters = count_lane_reads(counters, pos, "merged", state["kv"][0])
     return (_logits(x, params["ln_f"], params["embed"], cfg.norm_eps)[:, 0],
             {**slots, "counters": counters})

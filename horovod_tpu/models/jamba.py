@@ -13,7 +13,9 @@ no backward pass written for it).
 
 * Attention: ``num_attention_heads`` query heads over
   ``num_key_value_heads`` key/value heads, causal softmax, **no positional
-  encoding** (the Mamba layers carry order).
+  encoding** (the Mamba layers carry order).  A step reads a slot's lanes
+  as far as the slot has written them (``layers.lane_reader``: with ONE
+  key/value head, the decode kernel's shared-key form).
 * Mixer: ``(x, z) = split(u W_in)``; a causal depthwise convolution of
   width ``mamba_d_conv`` and SiLU give ``c``; ``(delta, B, C) =
   split(c W_x)``, each RMS-normed; ``Delta = softplus(delta W_dt + b)``;
@@ -34,9 +36,11 @@ chip's (8, 128) tiles hold no padding::
 
     {"kv": (k, v)                [La, B, KVH, cache_len, HD]  compute_dtype
      "recurrent": (ssm, conv)    [Lm, B, N, d_inner] float32,
-                                 [Lm, d_conv - 1, B, d_inner] compute_dtype}
+                                 [Lm, d_conv - 1, B, d_inner] compute_dtype
+     "counters": {...}           uint32 scalars, summed on the device:
+                                 ``layers.ATTN_COUNTERS``}
 
-A request's state (``prefill_request``) is the same pytree with B = 1.
+A request's state (``prefill_request``) is the slot kinds with B = 1.
 The module omits what ``serving/decode.py:MODELS`` lets it: no sharding
 of this state is written (recurrent state under tp), and the weights come
 in ``param_dtype``, which is for the caller to choose.
@@ -52,12 +56,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.models.layers import (_at, _causal_conv, _dense_ffn,
-                                       _grouped_attention, _logits, _put,
-                                       _rmsnorm)
+from horovod_tpu.models.layers import (ATTN_COUNTERS, _at, _causal_conv,
+                                       _dense_ffn, _grouped_attention,
+                                       _logits, _put, _rmsnorm,
+                                       count_lane_reads, lane_reader)
 
 Params = Dict[str, Any]
-State = Dict[str, Tuple[jax.Array, jax.Array]]
+State = Dict[str, Any]
 
 
 @dataclass(frozen=True)
@@ -292,7 +297,9 @@ def init_state(cfg: JambaConfig, max_batch: int, cache_len: int) -> State:
             jnp.zeros((Lm, max_batch, cfg.mamba_d_state, cfg.d_inner),
                       jnp.float32),
             jnp.zeros((Lm, cfg.mamba_d_conv - 1, max_batch, cfg.d_inner),
-                      cfg.compute_dtype))}
+                      cfg.compute_dtype)),
+        "counters": {name: jnp.zeros((), jnp.uint32)
+                     for name in ATTN_COUNTERS}}
 
 
 # The axis of each slot-kind leaf that the slots lie along: the convolution
@@ -307,12 +314,13 @@ def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
     what they end in (keys and values at rows [0, S), the recurrent
     state after row S - 1).  ``pos`` [B]: one token a slot continuing
     ``state``, which is read and written at its layer.  Returns (x,
-    state)."""
+    the state's slot kinds)."""
     dtype = cfg.compute_dtype
     start = pos is None
     carries = state is not None
     kv = state["kv"] if carries else ()
     rec = state["recurrent"] if carries else ()
+    read = None if start else lane_reader("heads_first", kv[0], pos)
 
     def attn_layer(l, carry):
         h, kv = carry
@@ -325,7 +333,7 @@ def _stack(params: Params, x, cfg: JambaConfig, state: Optional[State],
                 kv = (lax.dynamic_update_slice(kv[0], k[None], at),
                       lax.dynamic_update_slice(kv[1], v[None], at))
         else:
-            y, kv = _grouped_attention(y, lp, dtype, (*kv, l, pos))
+            y, kv = _grouped_attention(y, lp, dtype, (*kv, l, pos, read))
         h = h + y
         return h + _dense_ffn(_rmsnorm(h, lp["ln2"]), lp, dtype), kv
 
@@ -377,5 +385,8 @@ def decode_step(params: Params, tok, pos, state: State, cfg: JambaConfig):
     donated).  Rows never mix: a slot's output depends on its own state
     alone."""
     x = params["embed"].astype(cfg.compute_dtype)[tok[:, None]]
-    x, state = _stack(params, x, cfg, state, pos)
-    return _logits(x, params["ln_f"], params["embed"])[:, 0], state
+    x, slots = _stack(params, x, cfg, state, pos)
+    counters = count_lane_reads(state["counters"], pos, "heads_first",
+                                state["kv"][0])
+    return (_logits(x, params["ln_f"], params["embed"])[:, 0],
+            {**slots, "counters": counters})

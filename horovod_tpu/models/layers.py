@@ -76,14 +76,19 @@ def _grouped_attention(x, lp, dtype, cache=None, layout: str = "heads_first",
 
     ``cache`` None: the S positions attend among themselves; returns
     (out, (k, v)) with k, v as ``layout`` holds a request's rows, for
-    whoever keeps them.  ``cache`` = (ks, vs, layer, pos), stacked caches
-    and per-slot positions [B] of THIS token (S = 1): writes the B new
-    rows at ``pos[b]`` of lane [layer, b] in place and attends that lane
-    up to ``pos``; returns (out, (ks, vs)).
+    whoever keeps them.  ``cache`` = (ks, vs, layer, pos, read), stacked
+    caches and per-slot positions [B] of THIS token (S = 1): writes the B
+    new rows at ``pos[b]`` of lane [layer, b] in place and attends that
+    lane up to ``pos``, by ``read`` (:func:`lane_reader`, made once a step
+    for all its layers: the kernel, each lane as far as its slot has
+    written it) or, where that is None, by a masked product over the
+    whole lane; returns (out, (ks, vs)).
 
     ``layout``, how k, v and the caches are held (one of ``LAYOUTS``):
 
-    * ``heads_first``: [B, KVH, S, HD] and [La, B, KVH, Smax, HD].
+    * ``heads_first``: [B, KVH, S, HD] and [La, B, KVH, Smax, HD].  With
+      several key/value heads (no model here) a step keeps the masked
+      read: a head's lane is an array of its own there.
     * ``positions_first``: [B, S, KVH, HD] and [La, B, Smax, KVH, HD].
       With more than one key/value head the chip writes a step's rows into
       such a cache in place, and copies a heads-first one whole, there and
@@ -93,9 +98,9 @@ def _grouped_attention(x, lp, dtype, cache=None, layout: str = "heads_first",
       HD] and [La, B, Smax, KVH HD].  The chip pads a last axis under its
       128 lanes: caches [.., 8, 64] compiled for ``v5e`` take twice their
       bytes and the step copies them whole (PR 49).  A step multiplies a
-      lane as it lies: each query row holds its values in its own head's
-      HD of the KVH HD and zeros in the others', and of the product's KVH
-      HD it keeps those.
+      lane as it lies, in the kernel and under the mask alike: each query
+      row holds its values in its own head's HD of the KVH HD and zeros in
+      the others', and of the product's KVH HD it keeps those.
 
     ``qk_norm``: the epsilon of an RMSNorm over each head's HD values of q
     and of k, gains ``lp["q_norm"]`` and ``lp["k_norm"]`` [HD] shared by
@@ -123,41 +128,132 @@ def _grouped_attention(x, lp, dtype, cache=None, layout: str = "heads_first",
         k = _rope(k.swapaxes(1, 2), rope, at).swapaxes(1, 2) \
             if heads_first else _rope(k, rope, at)
     q = q.reshape(B, S, KVH, G, HD)
-    own = None
+    own = read = None
     if cache is None:
         keys, values, kept = k, v, (k, v)
         if merged:
             kept = (k.reshape(B, S, KVH * HD), v.reshape(B, S, KVH * HD))
         valid = jnp.tril(jnp.ones((S, S), jnp.bool_))[None]    # [1, S, T]
     else:
-        ks, vs, layer, pos = cache
+        ks, vs, layer, pos, read = cache
         rows = jnp.arange(B)
         if merged:
             ks = ks.at[layer, rows, pos].set(k[:, 0].reshape(B, KVH * HD))
             vs = vs.at[layer, rows, pos].set(v[:, 0].reshape(B, KVH * HD))
-            lane = "btd"
-            own = jnp.eye(KVH, dtype=dtype)[:, None, :, None]
-            q = (q[..., None, :] * own).reshape(B, S, KVH, G, KVH * HD)
         elif heads_first:
             ks = ks.at[layer, rows, :, pos].set(k[:, :, 0])
             vs = vs.at[layer, rows, :, pos].set(v[:, :, 0])
         else:
             ks = ks.at[layer, rows, pos].set(k[:, 0])
             vs = vs.at[layer, rows, pos].set(v[:, 0])
-        keys = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
-        values = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
         kept = (ks, vs)
-        valid = (jnp.arange(keys.shape[2 if heads_first else 1])[None, :]
-                 <= pos[:, None])[:, None]                     # [B, 1, T]
-    logits = jnp.einsum(f"bskgd,{lane}->bkgst", q, keys
-                        ).astype(jnp.float32) / math.sqrt(HD)
-    logits = jnp.where(valid[:, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
-    ctx = jnp.einsum(f"bkgst,{lane}->bskgd", probs, values)
-    if own is not None:
-        ctx = jnp.sum(ctx.reshape(B, S, KVH, G, KVH, HD) * own, axis=4)
-    ctx = ctx.reshape(B, S, KVH * G, HD)
+    if read is not None:
+        ctx = read(q[:, 0], ks, vs, layer)[:, None]
+    else:
+        if cache is not None:
+            keys = lax.dynamic_index_in_dim(ks, layer, 0, keepdims=False)
+            values = lax.dynamic_index_in_dim(vs, layer, 0, keepdims=False)
+            valid = (jnp.arange(keys.shape[2 if heads_first else 1])[None, :]
+                     <= pos[:, None])[:, None]                 # [B, 1, T]
+            if merged:
+                lane = "btd"
+                q, own = _in_own_heads_place(q)
+        logits = jnp.einsum(f"bskgd,{lane}->bkgst", q, keys
+                            ).astype(jnp.float32) / math.sqrt(HD)
+        logits = jnp.where(valid[:, None, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
+        ctx = jnp.einsum(f"bkgst,{lane}->bskgd", probs, values)
+        if own is not None:
+            ctx = _of_own_head(ctx, own)
+        ctx = ctx.reshape(B, S, KVH * G, HD)
     return jnp.einsum("bshk,hkd->bsd", ctx, lp["wo"].astype(dtype)), kept
+
+
+def _in_own_heads_place(q):
+    """q [..., KVH, G, HD] against a position's KVH heads side by side:
+    ([..., KVH, G, KVH HD], each row's HD values in its own head's place
+    and zeros in the others'; the mask that put them there)."""
+    KVH, _, HD = q.shape[-3:]
+    own = jnp.eye(KVH, dtype=q.dtype)[:, None, :, None]
+    return (q[..., None, :] * own).reshape(*q.shape[:-1], KVH * HD), own
+
+
+def _of_own_head(ctx, own):
+    """Of ctx [..., KVH, G, KVH HD], a product with a position's heads
+    side by side, each row's own head's HD: [..., KVH, G, HD]."""
+    KVH = own.shape[0]
+    return jnp.sum(ctx.reshape(*ctx.shape[:-1], KVH, -1) * own, axis=-2)
+
+
+# Positions a fetch of the decode kernel holds, at most, for lanes held in
+# each layout (a position is 1 KB of keys in ``lfm2-8b-a1b``'s merged
+# lanes, 512 bytes in ``nemotron-3-nano-30b-a3b``'s two heads, 256 in
+# ``jamba2-3b``'s one): chosen once, from ``tools/decode_attn_probe.py`` on
+# a TPU v5e at each cell's lanes and load (PERF.md section 6, PR 52: 256
+# unless another block is 3 % faster there; 1024 positions of two heads of
+# 128 are the 512 KB of 512 merged ones).
+LANE_BLOCKS = {"merged": 256, "positions_first": 1024, "heads_first": 256}
+
+
+def _lane_len(layout: str, cache) -> int:
+    """Positions a lane of a stacked cache held as ``layout`` holds."""
+    return cache.shape[3 if layout == "heads_first" else 2]
+
+
+def lane_block(layout: str, cache) -> Optional[int]:
+    """Positions a fetch of the decode kernel
+    (``ops/pallas_decode_attention.py``) holds for a key or value cache
+    held as ``layout``, from its shape; None where a step reads the lanes
+    whole under a mask: heads-first lanes of several key/value heads, each
+    head's an array of its own (no model here holds such)."""
+    from horovod_tpu.ops.pallas_attention import _pick_block
+
+    if layout == "heads_first" and cache.shape[2] != 1:
+        return None
+    return _pick_block(_lane_len(layout, cache), LANE_BLOCKS[layout])
+
+
+def lane_reader(layout: str, cache, pos):
+    """How a decode step reads its slots' lanes of the stacked caches
+    shaped like ``cache`` and held as ``layout``, slots at ``pos`` [B]: the
+    kernel over (q [B, KVH, G, HD], ks, vs, layer) -> [B, KVH G, HD], each
+    lane as far as its slot has written it, the list of blocks made HERE,
+    once for all the layers of the step; or None, the masked read of the
+    whole lane (:func:`lane_block`).  One algorithm at three shapes, told
+    apart by what is held and how:
+
+    * ``merged``: the query rows each in its own head's place of the KVH
+      HD (what the masked read multiplies too), the lanes as they lie, a
+      value array of its own; of the product each row keeps its head's HD.
+    * ``heads_first`` with one key/value head: [La, B, 1, Smax, HD] is
+      [La, B, Smax, HD], a key every head shares.
+    * ``positions_first``: the kernel's head axis, query row h on head
+      h // G.
+
+    The models that call this hold their state on one device (no
+    ``STATE_SPEC``: ``serving/decode.py`` refuses them a mesh)."""
+    block = lane_block(layout, cache)
+    if block is None:
+        return None
+    from horovod_tpu.ops import pallas_decode_attention as pda
+
+    merged = layout == "merged"
+    work = pda.work_list(pos, _lane_len(layout, cache), block)
+
+    def read(q, ks, vs, layer):
+        B, KVH, G, HD = q.shape
+        if merged:
+            q, own = _in_own_heads_place(q)
+        elif layout == "heads_first":
+            ks, vs = (a.reshape(a.shape[:2] + a.shape[3:]) for a in (ks, vs))
+        ctx = pda.decode_attention(
+            (q.reshape(B, KVH * G, -1),), (ks,), vs, layer, pos,
+            scale=1.0 / math.sqrt(HD), block=block, work=work)
+        if merged:
+            ctx = _of_own_head(ctx.reshape(B, KVH, G, -1), own)
+        return ctx.reshape(B, KVH * G, HD)
+
+    return read
 
 
 def _causal_conv(x, kept, w, b):
@@ -259,3 +355,12 @@ def count_attention_reads(counters, pos, cache_len: int, n_layers: int,
 
         read = (pairs_run(pos, block) * (n_layers * block)).astype(jnp.uint32)
     return add_counters(counters, dict(zip(ATTN_COUNTERS, (read, held))))
+
+
+def count_lane_reads(counters, pos, layout: str, cache):
+    """:func:`count_attention_reads` for one decode step of
+    :func:`_grouped_attention`'s layers over the stacked caches shaped
+    like ``cache`` [La, ...] and held as ``layout``: read as
+    :func:`lane_reader` reads them."""
+    return count_attention_reads(counters, pos, _lane_len(layout, cache),
+                                 cache.shape[0], lane_block(layout, cache))

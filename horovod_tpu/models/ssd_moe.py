@@ -43,7 +43,9 @@ scan nor the grouped product has a backward pass written for it).
 * ``*``: ``layers._grouped_attention`` (``num_attention_heads`` on
   ``num_key_value_heads`` of ``head_dim``: wider together than
   ``hidden_size``), its caches with the positions ahead of the two
-  key/value heads.
+  key/value heads; a step reads a slot's lane as far as the slot has
+  written it (``layers.lane_reader``: the decode kernel's head axis, 16
+  query rows a key/value head).
 * ``E``: ``y = sum_{i in top-k} w_i E_i(x) + E_shared(x)``, ``E(x) =
   relu(x W_up)^2 W_down``: ``experts.route`` and ``experts.routed_ffn`` in
   its two-product form, the routed stacks held with zeros up to whole
@@ -94,9 +96,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.models import experts
-from horovod_tpu.models.layers import (_at, _causal_conv,
+from horovod_tpu.models.layers import (ATTN_COUNTERS, _at, _causal_conv,
                                        _grouped_attention, _logits, _put,
-                                       _rmsnorm, add_counters)
+                                       _rmsnorm, add_counters,
+                                       count_lane_reads, lane_reader)
 from horovod_tpu.ops import pallas_ssd
 
 Params = Dict[str, Any]
@@ -186,7 +189,7 @@ def counter_names(cfg: SsdMoEConfig) -> Tuple[str, ...]:
     return (experts.MOE_COUNTERS
             + ((experts.ABSENT_COUNTER,) if cfg.experts_held is not None
                else ())
-            + STATE_COUNTERS + (CHUNK_COUNTER,))
+            + STATE_COUNTERS + (CHUNK_COUNTER,) + ATTN_COUNTERS)
 
 
 # ---------------------------------------------------------------------------
@@ -446,6 +449,7 @@ def _stack(params: Params, x, cfg: SsdMoEConfig, state: Optional[State],
     rec = state["recurrent"] if carries else ()
     live = None if start else pos > 0
     work = None if start else pallas_ssd.live_slots(live)
+    read = None if start else lane_reader("positions_first", kv[0], pos)
     B, S, D = x.shape
     small = {k: v for k, v in params["moe"].items() if k not in _EXPERTS}
     routed = {k: params["moe"][k] for k in _EXPERTS}
@@ -475,7 +479,8 @@ def _stack(params: Params, x, cfg: SsdMoEConfig, state: Optional[State],
                           lax.dynamic_update_slice(kv[1], v[None], at))
             else:
                 y, kv = _grouped_attention(
-                    y, lp, dtype, (*kv, l, pos), layout="positions_first")
+                    y, lp, dtype, (*kv, l, pos, read),
+                    layout="positions_first")
         else:
             lp = _at(small, l)
             u = _rmsnorm(x, lp["ln"], eps)
@@ -539,6 +544,8 @@ def decode_step(params: Params, tok, pos, state: State, cfg: SsdMoEConfig):
         add[experts.ABSENT_COUNTER] = (Le * cfg.num_experts_per_tok * live
                                        - stats[0])
     add[STATE_COUNTERS[0]] = add[STATE_COUNTERS[1]] = Lm * live
+    counters = count_lane_reads(add_counters(state["counters"], add), pos,
+                                "positions_first", state["kv"][0])
     return (_logits(x, params["ln_f"], params["head"],
                     cfg.layer_norm_epsilon)[:, 0],
-            {**slots, "counters": add_counters(state["counters"], add)})
+            {**slots, "counters": counters})

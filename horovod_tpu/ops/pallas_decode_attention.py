@@ -39,7 +39,12 @@ slots have written and not what the table could hold.
   width HD (a view: a position's heads are one tile), and query row h
   attends the keys of head h alone, by the mask; the MXU then does the
   per-head sums and the context comes out ``[H, HD]`` with nothing to
-  transpose.
+  transpose.  Grouped queries are the same two shapes
+  (``models/layers.py:lane_reader``): G x H query rows on the head axis,
+  row h on head h // G; with one key/value head, rows that share a key;
+  heads narrower than the chip's lanes held side by side,
+  ``[L, B, Smax, H HD]``, as a shared key of H HD with each query row in
+  its own head's place of it and a value array of its own.
 * **A selection.**  With ``select`` (what ``ops/pallas_index_select.py``
   returns: every position's index score, and each slot's cut) a block's
   positions that are not among the slot's selected are masked like those
@@ -164,11 +169,14 @@ def _kernel(layer_ref, slot_ref, blk_ref, pos_ref, *refs,
         s = part if s is None else s + part
     s = s * scale
     # Key c of the block is position j * block + c // group, head
-    # c % group; row h of the queries attends its own head's keys.
+    # c % group; row h of the queries attends its own head's keys, of
+    # hq / group rows a head those of head h // (hq / group).
     col = lax.broadcasted_iota(jnp.int32, (hq, keys), 1)
     ok = j * block + col // group <= at
     if group > 1:
         row = lax.broadcasted_iota(jnp.int32, (hq, keys), 0)
+        if hq != group:
+            row = row // (hq // group)
         ok = jnp.logical_and(ok, col % group == row)
     if selects:
         score_ref, cut_ref, tie_ref = refs[-7:-4]
@@ -205,11 +213,13 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
     matching parts of the stacked cache, each ``[L, B, Smax, Dk_i]``, or
     ``[L, B, Dk_i, Smax]`` where ``positions_last[i]`` (heads share a
     position's key; the first part lies positions-first), or ONE part
-    ``[L, B, Smax, H, HD]`` with H = Hq (a head has its own).  ``value``: a cache shaped like a
-    key part with its own last dimension, or ``None`` for the first key
-    part.  ``layer``: the lane's index on the leading axis, a traced
-    scalar.  ``pos`` ``[B]``: slot b attends positions 0 to ``pos[b]``;
-    whatever its lane holds past them never reaches the output.
+    ``[L, B, Smax, H, HD]`` (a head has its own): Hq is H, or G x H with
+    query rows h G to h G + G - 1 on head h (grouped queries).
+    ``value``: a cache shaped like a key part with its own last
+    dimension, or ``None`` for the first key part.  ``layer``: the lane's
+    index on the leading axis, a traced scalar.  ``pos`` ``[B]``: slot b
+    attends positions 0 to ``pos[b]``; whatever its lane holds past them
+    never reaches the output.
 
     Returns ``[B, Hq, Dv]`` in the cache's type: softmax(q k / scale) v
     with float32 scores and sums.  ``block`` (positions a fetch, a divisor
@@ -225,9 +235,9 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
     last = tuple(positions_last or (False,) * len(keys))
     heads_own = keys[0].ndim == 5
     group = keys[0].shape[3] if heads_own else 1
-    if heads_own and (len(keys) != 1 or group != hq):
-        raise ValueError("a cache with a head axis is one key part of "
-                         "as many heads as the query has")
+    if heads_own and (len(keys) != 1 or hq % group):
+        raise ValueError("a cache with a head axis is one key part whose "
+                         "heads divide the query's")
     smax = keys[0].shape[2]
     if block is None:
         block = block_for(smax, shared=value is None)
@@ -286,5 +296,7 @@ def decode_attention(q: Sequence[jax.Array], keys: Sequence[jax.Array],
     # whatever the buffer held.
     first = lax.dynamic_slice(values, (layer, 0, 0, 0),
                               (1, B, group, dv))[0]        # [B, group, Dv]
+    if 1 < group < hq:                  # a head's row for each of its queries
+        first = jnp.repeat(first, hq // group, axis=1)
     return jnp.where((pos == 0)[:, None, None],
                      jnp.broadcast_to(first, out.shape), out)

@@ -94,15 +94,17 @@ def serve_cache_programs(cfg, slots, min_elems, sharding=None,
         compiled = program.compile()
         mem = compiled.memory_analysis()
         text = compiled.as_text()
+        # the Mosaic calls' instruction names, less their number
+        calls = [n.rsplit(".", 1)[0] for n in re.findall(
+            r"^\s*%?([\w.\-]+) = .*custom_call_target="
+            r"\"tpu_custom_call\"", text, re.M)]
         out[name] = {"big_ops": big_ops(text, min_elems),
                      "converts": converts_to(
                          text, jnp.dtype(cfg.compute_dtype).name),
                      "temp_bytes": mem.temp_size_in_bytes,
                      "alias_bytes": mem.alias_size_in_bytes,
-                     # the Mosaic calls' instruction names, less their number
-                     "kernels": sorted({n.rsplit(".", 1)[0] for n in re.findall(
-                         r"^\s*%?([\w.\-]+) = .*custom_call_target="
-                         r"\"tpu_custom_call\"", text, re.M)})}
+                     "kernels": sorted(set(calls)),
+                     "kernel_calls": {k: calls.count(k) for k in set(calls)}}
         if weight_elems is not None:
             out[name]["weight_ops"] = big_ops(text, weight_elems)
     return out

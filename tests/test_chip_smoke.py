@@ -298,8 +298,9 @@ def test_serving_state_programs_update_in_place_on_the_chip(
     given (an update-slice a run of Mamba layers, a row scatter a cache an
     attention layer) and the compiler's own asynchronous moves of weights
     into fast memory (``copy-start``, ``slice-start``, the buffers it
-    allocates for them); all four state arrays are aliased from input to
-    output, unpadded, and the temporaries stay under one layer's state."""
+    allocates for them); all four state arrays and the two counters are
+    aliased from input to output, unpadded, and the temporaries stay under
+    one layer's state."""
     rc, out, err = probes.result("lower_for_tpu")
     assert rc == 0, err[-3000:]
     got = json.loads(out.split("RESULT", 1)[1])["serve_state"][program]
@@ -307,7 +308,7 @@ def test_serving_state_programs_update_in_place_on_the_chip(
     d_inner, n_mamba, n_attn = 2 * c["hidden_size"], 6, 2
     layer_bytes = 4 * c["slots"] * 16 * d_inner
     held = (n_mamba * (layer_bytes + 2 * 3 * c["slots"] * d_inner)
-            + 2 * n_attn * 2 * c["slots"] * c["max_seq_len"] * 128)
+            + 2 * n_attn * 2 * c["slots"] * c["max_seq_len"] * 128 + 2 * 512)
     prefetch = {"copy-start", "copy-done", "slice-start", "slice-done",
                 "custom-call"}
     assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
@@ -421,7 +422,7 @@ def test_ssd_state_programs_update_in_place_on_the_chip(
     copy of the routed experts' stacks (held unpadded, 1856 columns are
     laid out the other way round and the grouped product copied all 2.5 GB
     a turn) and none of a key/value lane (held heads-first, both lanes went
-    there and back around the scatter); the four state arrays and the eight
+    there and back around the scatter); the four state arrays and the ten
     counters are aliased from input to output and the temporaries stay
     under one layer's state."""
     rc, out, err = probes.result("lower_for_tpu")
@@ -430,7 +431,7 @@ def test_ssd_state_programs_update_in_place_on_the_chip(
     c = chip_probes.SERVE_SSD
     layer_bytes = 4 * c["slots"] * 64 * 64 * 128
     held = (4 * (layer_bytes + 2 * 3 * c["slots"] * 6144)
-            + 2 * 2 * c["slots"] * c["max_seq_len"] * 2 * 128 + 8 * 512)
+            + 2 * 2 * c["slots"] * c["max_seq_len"] * 2 * 128 + 10 * 512)
     prefetch = {"copy-start", "copy-done", "slice-start", "slice-done"}
     assert {op for _, op in got["big_ops"]} <= updates | prefetch, got
     assert {op for _, op in got["big_ops"]} & updates, got
@@ -483,6 +484,32 @@ def test_conv_moe_lanes_update_in_place_on_the_chip(probes, program, updates):
     assert {op for _, op in got["big_ops"]} & updates, got
     assert got["temp_bytes"] < lane_bytes, got
     assert got["alias_bytes"] == held, got
+
+
+@pytest.mark.parametrize("probe,calls", [
+    ("serve_conv", 2), ("serve_ssd", 1), ("serve_state", 2)])
+def test_grouped_lanes_take_the_decode_kernel_in_place_on_the_chip(
+        probes, probe, calls):
+    """The three steps that call ``layers._grouped_attention``, compiled for
+    ``v5e`` at their cells' lanes: every attention layer (in
+    models/jamba.py's loops, every run of them) is ONE Mosaic call
+    ``decode_attn`` over the stacked caches as they are held: the
+    ``lfm2-8b-a1b`` cell's ``[La, 192, 2048, 8 x 64]`` as they lie (two
+    attention layers in this probe, three in the cell), the
+    ``nemotron-3-nano-30b-a3b`` cell's ``[1, 96, 4096, 2, 128]`` as 8192
+    keys of 128 a slot, the ``jamba2-3b`` cell's ``[2, 64, 1, 1536, 128]``
+    without its axis of one.  Each view is free: the step produces NO
+    copy, transposition or other fusion of a lane's size besides the rows'
+    scatters (the in-place tests above hold the aliased bytes to the state
+    alone), so each layout takes the kernel (``layers.lane_block``)."""
+    rc, out, err = probes.result("lower_for_tpu")
+    assert rc == 0, err[-3000:]
+    step = json.loads(out.split("RESULT", 1)[1])[probe]["step"]
+    assert step["kernel_calls"].get("decode_attn") == calls, step
+    moved = [[name, op] for name, op in step["big_ops"]
+             if op in ("copy", "transpose") or op.startswith("fusion:")
+             and op not in ("fusion:scatter", "fusion:dynamic-update-slice")]
+    assert moved == [], step
 
 
 @pytest.mark.parametrize("probe,fused", [

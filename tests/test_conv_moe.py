@@ -29,6 +29,8 @@ from horovod_tpu.models import conv_moe, experts, layers
 from horovod_tpu.models.layers import install_request
 from perfbench.reference import conv_moe_lm as ref
 from perfbench.reference import moe_lm as ref_moe
+from test_pallas_decode_attention import \
+    step_reads_blocks_and_equals_the_masked_step
 
 V = 96
 PERIOD = ("conv", "conv", "full_attention", "conv")
@@ -164,12 +166,23 @@ def test_slots_decode_what_the_full_forward_pass_gives(params):
     grew = {k: int(v) - before[k] for k, v in state["counters"].items()}
     turns = steps - 1
     # four live rows x top-3 x 8 expert layers a turn; the free slot's row
-    # is routed nowhere; the two lanes are read whole by every slot
+    # is routed nowhere; of the two layers' lanes the four live slots' are
+    # read, one block each (a lane of 64 is one block), the free slot's not
     assert grew["hvd_moe_rows_routed_total"] == turns * 4 * 3 * 8
     assert grew["hvd_moe_layer_turns_total"] == turns * 8
     assert grew["hvd_serve_attn_positions_held_total"] \
-        == grew["hvd_serve_attn_positions_read_total"] \
         == turns * 2 * 5 * cache_len
+    assert grew["hvd_serve_attn_positions_read_total"] \
+        == turns * 2 * 4 * cache_len
+
+
+def test_step_reads_its_lanes_by_blocks_and_equals_the_masked_read(
+        params, monkeypatch):
+    """The merged lanes through ``layers.lane_reader`` (each query row in
+    its own head's place of the two heads' 16) against the masked read of
+    the whole lane, rotation and norms included."""
+    step_reads_blocks_and_equals_the_masked_step(monkeypatch, conv_moe,
+                                                 params, CFG)
 
 
 def test_the_engine_serves_the_configuration_through_the_one_seam(params):
@@ -274,7 +287,8 @@ def test_attention_with_neither_argument_is_what_it_was(params, layout,
         shape = (2, 3, 2, 16, 8) if heads_first else (2, 3, 16, 2, 8)
         cache = (*jax.random.normal(jax.random.PRNGKey(4), (2,) + shape),
                  1, jnp.asarray([0, 5, 15]))
-    got = layers._grouped_attention(x, lp, jnp.float32, cache, layout)
+    got = layers._grouped_attention(
+        x, lp, jnp.float32, cache and (*cache, None), layout)
     want = _attention_no_positions(x, lp, jnp.float32, cache, heads_first)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_array_equal(a, b)
@@ -321,7 +335,7 @@ def test_attention_with_norms_and_rotation_is_the_references(params, layout):
         (1,) + (0,) * (len(shape) - 1)) for t in (k, v)]
     step, (ks, _) = layers._grouped_attention(
         unit[:, -1:], lp, jnp.float32,
-        (*lanes, 1, jnp.asarray([S - 1, S - 1])), **kw)
+        (*lanes, 1, jnp.asarray([S - 1, S - 1]), None), **kw)
     np.testing.assert_allclose(step[:, 0], want[:, -1], atol=2e-6)
     np.testing.assert_allclose(
         lax.index_in_dim(ks[1], S - 1, axis, keepdims=False),

@@ -30,9 +30,11 @@ import numpy as np
 import pytest
 
 from chip_probes import serve_cache_programs
-from horovod_tpu.models import jamba
+from horovod_tpu.models import jamba, layers
 from horovod_tpu.serving import DecodeEngine, JambaConfig, ServingLoop
 from perfbench.reference import ssm_lm as ref
+from test_pallas_decode_attention import \
+    step_reads_blocks_and_equals_the_masked_step
 
 SIZES = dict(vocab_size=96, hidden_size=32, intermediate_size=64,
              num_hidden_layers=8, num_attention_heads=4,
@@ -70,7 +72,8 @@ def test_layer_order_is_the_published_rule():
         lambda a: a.shape, jax.eval_shape(
             lambda: jamba.init_state(full, 64, 1536))) == {
         "kv": ((2, 64, 1, 1536, 128),) * 2,
-        "recurrent": ((26, 64, 16, 5120), (26, 3, 64, 5120))}
+        "recurrent": ((26, 64, 16, 5120), (26, 3, 64, 5120)),
+        "counters": dict.fromkeys(layers.ATTN_COUNTERS, ())}
 
 
 # -- (a) the mixer's two forms -------------------------------------------------
@@ -284,20 +287,30 @@ KV_ELEMS = 2 * B_PIN * 1 * S_PIN * 16    # [La, B, KVH, S, HD]
 
 @pytest.mark.parametrize("program", ["step", "install"])
 def test_compiled_program_aliases_all_the_state_it_was_given(program):
-    """All four state arrays are aliased from input to output of both
-    programs, and the install produces nothing of a key/value stack's
-    size besides its in-place writes.  (Inside the step's layer loop the
+    """All four state arrays and the two counters are aliased from input
+    to output of both programs, and the install produces nothing of a
+    key/value stack's size besides its in-place writes.  (Inside the step's layer loop the
     CPU backend copies the recurrent state it reads and writes; the chip's
     compiler does not: tests/test_chip_smoke.py pins the step compiled for
     ``v5e``, at the benchmark's shapes, to in-place updates alone.)"""
     assert KV_ELEMS < SSM_ELEMS
     got = serve_cache_programs(PIN, B_PIN, KV_ELEMS)[program]
     conv_elems = 6 * 3 * B_PIN * 256
-    assert got["alias_bytes"] == 4 * (2 * KV_ELEMS + SSM_ELEMS + conv_elems)
+    assert got["alias_bytes"] == 4 * (2 * KV_ELEMS + SSM_ELEMS + conv_elems
+                                      + len(layers.ATTN_COUNTERS))
     if program == "install":
         assert {op for _, op in got["big_ops"]} <= {
             "fusion:dynamic-update-slice", "dynamic-update-slice"}, got
         assert got["temp_bytes"] < 4 * KV_ELEMS, got
+
+
+def test_step_reads_its_lanes_by_blocks_and_equals_the_masked_read(
+        params, monkeypatch):
+    """The heads-first lanes of ONE key/value head through
+    ``layers.lane_reader`` (a key every query head shares) against the
+    masked read of the whole lane."""
+    step_reads_blocks_and_equals_the_masked_step(monkeypatch, jamba, params,
+                                                 CFG)
 
 
 def test_prefill_and_step_donate_the_state_they_were_given(params):

@@ -4,7 +4,10 @@ masked dense read it replaces, interpreted on the CPU.
 Both shapes of the one body: a cache with a head axis (16 heads on 16, K
 and V arrays of their own) and a shared latent (20 heads on one latent a
 position, the value too, and a 64-wide rotary key as a second part, held
-with its positions last).  Every case
+with its positions last); and the three forms grouped heads take them in
+(``layers.lane_reader``): 32 heads on 8 of 64 held side by side, each query
+row in its own head's place of the 512; the head axis with 32 rows on 2
+heads of 128; 20 rows on one shared key of 128.  Every case
 runs a ragged batch, a non-zero layer of a stack of three, and lanes
 filled with NaN past each slot's position: a finite output equal to the
 reference's proves that the kernel is bounded by the position and not
@@ -18,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.models import layers
 from horovod_tpu.ops import pallas_decode_attention as pda
 
 L, SMAX, BLOCK = 3, 64, 16
@@ -66,11 +70,58 @@ def uneven_steps(prefill, install, step, state, lengths, vocab, steps=40):
     return np.stack(out), state
 
 
+def step_reads_blocks_and_equals_the_masked_step(
+        monkeypatch, module, params, cfg, lengths=(3, 0, 15, 80),
+        cache_len=128, block=16, steps=40, tol=2e-5):
+    """A model of grouped heads (``module``: conv_moe, ssd_moe, jamba)
+    stepped from prompts of ``lengths`` in lanes of ``cache_len`` with the
+    kernel at blocks of ``block``: over the steps the live slots cross
+    block ends beside a free slot, the counters say what the blocks held,
+    and every step's logits are those of the step that reads the lanes
+    whole under a mask (``lane_reader`` None)."""
+    from horovod_tpu.serving import decode
+
+    monkeypatch.setattr(layers, "LANE_BLOCKS",
+                        dict.fromkeys(layers.LAYOUTS, block))
+
+    def logits():
+        return uneven_steps(
+            jax.jit(lambda p: module.prefill_request(params, p, cfg,
+                                                     cache_len)),
+            decode.slot_model(cfg, cache_len).install,
+            jax.jit(lambda tok, pos, state: module.decode_step(
+                params, tok, pos, state, cfg)),
+            module.init_state(cfg, len(lengths), cache_len), lengths,
+            cfg.vocab_size, steps)
+
+    got, state = logits()
+    layers_attn = cfg.n_layers("attn")
+    read, held = (int(state["counters"][name])
+                  for name in layers.ATTN_COUNTERS)
+    assert held == steps * layers_attn * len(lengths) * cache_len
+    assert read == layers_attn * block * sum(
+        n // block + 1 for length in lengths if length
+        for n in range(length, length + steps))
+    monkeypatch.setattr(module, "lane_reader", lambda *a: None)
+    want, _ = logits()
+    live = [b for b, n in enumerate(lengths) if n]
+    np.testing.assert_allclose(got[:, live], want[:, live], rtol=10 * tol,
+                               atol=tol)
+    assert np.isfinite(got).all()
+
+
 def _poison(cache, pos):
     """NaN at every position past each slot's, in every layer."""
     past = jnp.arange(SMAX)[None, :] > pos[:, None]             # [B, T]
     past = past.reshape((1,) + past.shape + (1,) * (cache.ndim - 3))
     return jnp.where(past, jnp.nan, cache)
+
+
+# Grouped heads: (query heads, key/value heads, head_dim, the layout of
+# ``layers._grouped_attention`` that holds them).
+GROUPED = {"merged_32on8of64": (32, 8, 64, "merged"),
+           "head_axis_32on2of128": (32, 2, 128, "positions_first"),
+           "shared_20on1of128": (20, 1, 128, "heads_first")}
 
 
 CASES = {
@@ -87,8 +138,8 @@ CASES = {
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", list(CASES))
-@pytest.mark.parametrize("shape", ["heads", "latent"])
-def test_kernel_equals_masked_dense_read(shape, case, dtype):
+@pytest.mark.parametrize("shape", ["heads", "latent", *GROUPED])
+def test_kernel_equals_masked_dense_read(shape, case, dtype, monkeypatch):
     pos = jnp.asarray(CASES[case], jnp.int32)
     B = pos.shape[0]
     rng = iter(jax.random.split(jax.random.PRNGKey(len(case)), 8))
@@ -96,7 +147,23 @@ def test_kernel_equals_masked_dense_read(shape, case, dtype):
     def normal(*dims):
         return jax.random.normal(next(rng), dims, jnp.float32).astype(dtype)
 
-    if shape == "heads":
+    if shape in GROUPED:
+        # keys and values [L, B, T, KVH, HD]; the reference is the dense
+        # kernel-free read with a head of its own a query row
+        H, KVH, HD, layout = GROUPED[shape]
+        q = normal(B, KVH, H // KVH, HD)
+        keys, value = normal(L, B, SMAX, KVH, HD), normal(L, B, SMAX, KVH, HD)
+        scale = 1.0 / math.sqrt(HD)
+        want = masked_read(
+            (q.reshape(B, H, HD),), (jnp.repeat(keys, H // KVH, axis=3),),
+            jnp.repeat(value, H // KVH, axis=3), LAYER, pos, scale=scale)
+        held = {"merged": lambda a: a.reshape(L, B, SMAX, KVH * HD),
+                "positions_first": lambda a: a,
+                "heads_first": lambda a: a.swapaxes(2, 3)}[layout]
+        monkeypatch.setitem(layers.LANE_BLOCKS, layout, BLOCK)
+        ks, vs = held(_poison(keys, pos)), held(_poison(value, pos))
+        got = layers.lane_reader(layout, ks, pos)(q, ks, vs, jnp.int32(LAYER))
+    elif shape == "heads":
         H, HD = 16, 32
         q = normal(B, H, HD)
         keys, value = normal(L, B, SMAX, H, HD), normal(L, B, SMAX, H, HD)

@@ -305,13 +305,19 @@ def test_conv_moe_serves_the_same_tokens_with_the_kernel(monkeypatch):
 # engine's step for the cells' rehearsal configurations and slots, taken on
 # the parent commit (223e031, PR 49): the three other callers of
 # ``routed_ffn`` lower to the programs they lowered to before the kernel.
+# ``nemotron-3-nano-30b-a3b``'s was taken again on PR 52's finished change,
+# with its cell's numbers (PERF.md section 6): its attention layer reads its
+# lane through ``layers.lane_reader`` and the step holds the two
+# ``hvd_serve_attn_positions_*`` counters; its grouped products are as they
+# were (``tests/test_chip_smoke.py`` holds the step compiled for ``v5e`` to
+# them).
 STEP_ON_THE_PARENT = {
     "glm-4.7-flash_serve_context":
         "713cb58c4ed3744905e362767ee40ac6afa30ce92d9df8c5f873f4f8079e1905",
     "deepseek-v3.2_serve_resident":
         "ae685b36811ca1fff3c47ca6c37874b1b15cf97cd24849d64804722abdf4c8c5",
     "nemotron-3-nano-30b-a3b_serve_agents":
-        "d6eccc5f051e8398660952a7d211abe689c7616070aaaa4e95f5a7f755989654"}
+        "a4c25654c4e1d369a97ac12c0fd80adad7ad5ee4525631b7248ce4aa954789b9"}
 
 
 @pytest.mark.parametrize("cell_name", list(STEP_ON_THE_PARENT))

@@ -616,8 +616,15 @@ SLOTS, CACHE_LEN, PROMPT = 4, 64, 24
 # computes, or the order it computes it in, lands here: change a digest only
 # with that form's cells' numbers in hand.
 LOWERED_BEFORE = {
+    # the three steps through ``layers._grouped_attention`` taken on PR 52's
+    # finished change, with their cells' numbers (PERF.md section 6): each
+    # attention layer reads its lanes through ``layers.lane_reader`` (the
+    # kernel ``decode_attn``, the list of blocks made once a step), and
+    # ``jamba2-3b``'s and ``nemotron-3-nano-30b-a3b``'s states hold the two
+    # ``hvd_serve_attn_positions_*`` counters; the six prefills are as they
+    # were
     "jamba2-3b:serve_step":
-        "37725ddcebea758b7805f597b1f410711e05bd7c0766498f0a056910327f6cee",
+        "f6b5e05bfdc4c223d7bee67ba8146fd86c9c445255daf6b27fb3b5b3614fe00d",
     "jamba2-3b:serve_prefill":
         "9864fa04f07c36dbfae741723850d462e76e301c1abd5422a8d0617a7aaba7ca",
     "glm-4.7-flash:serve_step":
@@ -636,7 +643,7 @@ LOWERED_BEFORE = {
     # the state step is the kernel ``ssd_step`` and the state lies ``[Lm,
     # B, G, N, W]``, so a prefill ends in a transposition of its end state
     "nemotron-3-nano-30b-a3b:serve_step":
-        "3b5dba2c0f16834aa96e299e207748a867a9869ee09142cf4c51b110d11e99a3",
+        "88f6d0458f8af09aae0cfb1b3e9dafcacd2c2db5d45ef45df18250b148076df8",
     "nemotron-3-nano-30b-a3b:serve_prefill":
         "ebe7c2a04f1496c883279d7699db55dffcf38d5f82a32bdf60e42989a289f5c2",
     # taken on PR 50's finished change, with its cell's numbers (PERF.md):
@@ -644,7 +651,7 @@ LOWERED_BEFORE = {
     # 8 experts keep the grouped products), the 24-token prompt's 72 pairs
     # take the one kernel ``routed_ffn_rows``
     "lfm2-8b-a1b:serve_step":
-        "75c6bb3dbe6c582dd3c61a7fd1c694c3f3fb7b7867bdde1ef2496d0b419acf67",
+        "5364d7cae9b54df32b6f3f4f7362f549c43cf58d700e74d2109f738a79297363",
     "lfm2-8b-a1b:serve_prefill":
         "21241a0a51988e5be6fca07097ed110c6015e702addd4789e68a97de5f2d5f76"}
 

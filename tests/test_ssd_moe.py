@@ -25,6 +25,8 @@ import pytest
 from horovod_tpu.models import experts, ssd_moe
 from horovod_tpu.serving.decode import DecodeEngine
 from perfbench.reference import ssd_moe_lm as ref
+from test_pallas_decode_attention import \
+    step_reads_blocks_and_equals_the_masked_step
 
 V = 96
 PATTERN = "EMEM*" * 2
@@ -152,6 +154,15 @@ def test_slots_decode_what_the_full_forward_pass_gives(params):
             == Le * 3 * (3 + 3 * (steps - 1)))
     # four prefills' chunks of 8: 19, 11, 19 and 11 rows
     assert c["hvd_ssm_prefill_chunks_total"] == Lm * (3 + 2 + 3 + 2)
+
+
+def test_step_reads_its_lane_by_blocks_and_equals_the_masked_read(
+        params, monkeypatch):
+    """The positions-first lanes through ``layers.lane_reader`` (the
+    kernel's head axis: four query rows on two heads) against the masked
+    read of the whole lane."""
+    step_reads_blocks_and_equals_the_masked_step(monkeypatch, ssd_moe,
+                                                 params, CFG)
 
 
 def test_engine_serves_it_and_counts_on_the_device(params):
